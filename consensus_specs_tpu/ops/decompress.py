@@ -4,7 +4,7 @@ The remaining py_ecc-shaped cost in the verify path was host staging:
 `decompress_g1` does a 381-bit modular square root in Python bignums PER
 PUBKEY (crypto/bls12_381.py:368-386) — at a 4,096-member committee that is
 seconds of host time per attestation, exactly the cost this framework
-exists to remove (VERDICT r2 weakness #8). Here the byte-parse is
+exists to remove. Here the byte-parse is
 vectorized numpy and the field math — Montgomery lift, y^2 = x^3 + 4, the
 (q+1)/4 square-root exponentiation, the sign select — runs batched on the
 TPU: one program, N points, ~570 field multiplies of depth regardless of N.

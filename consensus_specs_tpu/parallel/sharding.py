@@ -284,7 +284,7 @@ class ServingMesh:
 
         Dispatch goes through the resilience guard: with nothing armed
         it degenerates to the watchdog-wrapped call; under a deadline
-        budget / fault schedule it gains retry + the typed taxonomy, and
+        budget / fault schedule it gains retry + the typed classification, and
         `check` (resilience/integrity.py) tripwires the output before it
         can chain (the caller decides how to degrade — ResidentCore
         walks the ladder)."""

@@ -8,7 +8,7 @@ The serving loop's failure-mode contract, in one sentence per module:
                     zero-overhead no-op when unset.
   * dispatch.py   — `guarded_dispatch` wraps every ResidentCore /
                     ServingMesh launch: wall-clock deadline, typed error
-                    taxonomy, bounded retry + backoff, and the
+                    classification, bounded retry + backoff, and the
                     degradation ladder over the committed oracle knobs.
   * integrity.py  — output tripwires against the hulls the value-range
                     tier proved (`RANGE_CONTRACTS`): poisoned buffers
@@ -16,7 +16,7 @@ The serving loop's failure-mode contract, in one sentence per module:
   * checkpoint.py — CRC-framed, atomic-rename, generational checkpoints
                     with fallback to the previous good generation and
                     restore across a changed serving-mesh shape.
-  * errors.py     — the typed taxonomy everything above raises.
+  * errors.py     — the typed classification everything above raises.
 
 `tools/chaos_drill.py` (`make chaos`, CI) drives the whole stack under a
 seeded fault schedule and asserts bit-identical recovery;

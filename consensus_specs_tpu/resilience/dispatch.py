@@ -9,7 +9,7 @@ ResidentCore / ServingMesh program launch:
     cache-size read around the call) inside one try-frame: NO
     `block_until_ready`, so async dispatch is undisturbed, and the cost
     is the <3% bench bound (`bench.py resilience` stage) / the <20 µs
-    no-op test bound. The error taxonomy + retry still apply when the
+    no-op test bound. The error classification + retry still apply when the
     dispatch itself raises — real weather does not wait for a schedule.
   * **deadline** — when a budget is armed (`deadline_ms` argument or
     `CSTPU_DEADLINE_MS`), the guard measures wall clock around the
@@ -23,9 +23,9 @@ ResidentCore / ServingMesh program launch:
     impossible, so discarding correct work would only convert lateness
     into unavailability; the miss (and a `deadline_salvaged` counter)
     stays on /healthz.
-  * **taxonomy + retry** — failures classify into the typed errors of
+  * **classification + retry** — failures classify into the typed errors of
     resilience/errors.py. Transients (RESOURCE_EXHAUSTED / UNAVAILABLE /
-    INTERNAL / ABORTED — flaky relay, preemption, injected faults) and
+    INTERNAL / ABORTED — a flaky device link, preemption, injected faults) and
     deadline misses retry with exponential backoff; corrupt outputs
     (integrity tripwires) re-dispatch; everything else is fatal
     immediately. The clock and sleeper are injectable, so the retry
@@ -108,7 +108,7 @@ def guarded_dispatch(key, fn: Callable, *args,
                      clock: Callable[[], float] = time.perf_counter,
                      sleep: Callable[[float], None] = time.sleep):
     """Call `fn(*args)` through the retrace watchdog under `key`, with
-    the guard rails above. Raises the typed DispatchError taxonomy after
+    the guard rails above. Raises the typed DispatchError classification after
     `retries` extra attempts; returns the (verified) output otherwise.
 
     `check(out) -> bool` is the integrity tripwire (resilience/
@@ -121,7 +121,7 @@ def guarded_dispatch(key, fn: Callable, *args,
     # include the device work); a tripwire alone syncs exactly the
     # leaves it reads through its own jitted reduction, and unarmed
     # dispatch never fences at all — async dispatch stays async and the
-    # guard is one try-frame + two env reads. The taxonomy/retry still
+    # guard is one try-frame + two env reads. The classification/retry still
     # applies if the dispatch itself raises.
     armed = bool(deadline_ms)
     last_error: Optional[DispatchError] = None

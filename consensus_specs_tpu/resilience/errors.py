@@ -1,4 +1,4 @@
-"""Error taxonomy of the resilience subsystem (ISSUE 13).
+"""Error classification of the resilience subsystem (ISSUE 13).
 
 Every failure the serving loop can survive gets a TYPED class, so the
 recovery policy (resilience/dispatch.py's retry/degradation machinery,
@@ -6,12 +6,12 @@ resilience/checkpoint.py's generation fallback) branches on type, never
 on string matching — and so callers that want to die loudly still can:
 everything here derives from `ResilienceError`.
 
-The dispatch taxonomy mirrors the gRPC-ish status classes real XLA
+The dispatch classification mirrors the gRPC-ish status classes real XLA
 runtimes raise (RESOURCE_EXHAUSTED / UNAVAILABLE / INTERNAL are
 transient infrastructure weather; INVALID_ARGUMENT is a bug):
 
   * `TransientDispatchError` — worth retrying with backoff (a flaky
-    relay, a preempted device, an injected `raise` fault);
+    device link, a preempted device, an injected `raise` fault);
   * `DeadlineExceeded`      — the dispatch + `block_until_ready` wall
     clock blew the armed budget (the fork-choice deadline: the result
     may be correct but arrived too late to matter);
@@ -33,7 +33,7 @@ class ResilienceError(Exception):
 
 
 class DispatchError(ResilienceError):
-    """Base class of the guarded-dispatch taxonomy. `key` names the
+    """Base class of the guarded-dispatch classification. `key` names the
     logical program (the watchdog/telemetry dispatch key); `attempts`
     counts how many tries the guard spent before giving up;
     `consumed_inputs` records whether the failing attempt ever entered
@@ -50,7 +50,7 @@ class DispatchError(ResilienceError):
 
 
 class TransientDispatchError(DispatchError):
-    """Retryable infrastructure failure (flaky relay, preemption)."""
+    """Retryable infrastructure failure (flaky device link, preemption)."""
 
 
 class DeadlineExceeded(DispatchError):

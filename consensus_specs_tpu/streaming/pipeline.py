@@ -140,7 +140,7 @@ class FirehosePipeline:
             self._harvested.update(self._drain())
         with _span("firehose.dispatch", groups=len(members), pairs=count,
                    padded=g):
-            # unarmed guard: async launch in a try-frame — taxonomy and
+            # unarmed guard: async launch in a try-frame — classification and
             # transient retry apply (host-staged inputs are re-usable),
             # the deadline only ever arms the flush
             out = guarded_dispatch(

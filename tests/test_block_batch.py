@@ -1,5 +1,5 @@
 """End-to-end config-3: a block of real attestations verified on device
-through ONE batched pipeline (VERDICT r3 #4).
+through ONE batched pipeline.
 
 process_operations collapses the attestation family's signature checks into
 JaxBackend.verify_indexed_batch (grouped G1 decompress+aggregate, batched
@@ -88,7 +88,7 @@ def test_wrong_participants_fail_batched():
 
 
 def test_mainnet_preset_batched_block():
-    """always_bls, mainnet preset, jax backend: the VERDICT r3 #4 gate."""
+    """always_bls, mainnet preset, jax backend: the batched-block gate."""
     spec = phase0.get_spec("mainnet")
     state, block = _build(spec, 4 * spec.SLOTS_PER_EPOCH, 4)
     bls.set_backend("jax")

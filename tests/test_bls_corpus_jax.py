@@ -1,6 +1,6 @@
 """Signature-bearing spec scenarios under the JAX BLS backend.
 
-The e2e gate VERDICT r2 asked for: rows from the scenario corpus that
+The e2e gate: rows from the scenario corpus that
 actually exercise signatures (the @always_bls rejection rows plus the
 success rows re-run with BLS ON) execute under BOTH crypto backends, and
 their generator-mode artifacts — encoded pre/post states and operations —

@@ -4,7 +4,7 @@ Every op in ops/fq.py and ops/fq_tower.py is checked bit-for-bit against
 crypto/bls12_381.py on random values and the edge cases 0, 1, q-1. These are
 the building blocks of the TPU pairing (ops/bls_jax.py); a subtle Montgomery
 or Frobenius bug here corrupts every signature check above, so the tower gets
-its own oracle suite (the gap VERDICT/ADVICE round 1 flagged).
+its own oracle suite (the gap ADVICE round 1 flagged).
 """
 import random
 

@@ -8,7 +8,8 @@ recursive object-model Merkleizer in tests/test_bulk_htr.py). Patterns:
 single leaf, dense stripes, repeated updates to the same leaf, append-grow
 crossing a power-of-two boundary, and the all-dirty epoch-boundary shape —
 on both pair-hash backends (CSTPU_MERKLE_BACKEND=xla|pallas; the Pallas
-form runs the eager interpreter on CPU, so its scenario is compact).
+kernel lowers for TPUs only, so the fixture swaps in its interpreter form
+and the scenario stays compact).
 
 The work bound is asserted by counting hashed pairs per level, not by
 wall-clock: a ≤k-leaf update on an n-leaf tree must dispatch at most
@@ -27,7 +28,15 @@ from consensus_specs_tpu.utils.ssz.incremental import (
 
 
 @pytest.fixture(params=["xla", "pallas"])
-def backend(request):
+def backend(request, monkeypatch):
+    if request.param == "pallas":
+        # the backend switch resolves the kernel at call time; on the CPU
+        # the test picks the interpreter itself (the default is Mosaic)
+        from functools import partial
+        from consensus_specs_tpu.ops import sha256_pallas
+        monkeypatch.setattr(
+            sha256_pallas, "sha256_pairs_pallas",
+            partial(sha256_pallas.sha256_pairs_pallas, interpret=True))
     S.set_merkle_pair_backend(request.param)
     yield request.param
     S.set_merkle_pair_backend(None)
@@ -179,7 +188,7 @@ def test_update_work_is_dirty_log_v():
 
 
 # ---------------------------------------------------------------------------
-# Both backends (the Pallas form interprets eagerly off-TPU: keep it compact)
+# Both backends (the Pallas form interprets eagerly here: keep it compact)
 # ---------------------------------------------------------------------------
 
 def test_backend_scenario_bit_exact(backend):

@@ -373,7 +373,7 @@ def test_shard_block_wrong_beacon_root_rejected(spec, state):
 
 
 # ---------------------------------------------------------------------------
-# Device epoch path with insert hooks (VERDICT r3 #6)
+# Device epoch path with insert hooks
 # ---------------------------------------------------------------------------
 
 def _diff_epoch_paths(spec, state):

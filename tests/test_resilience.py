@@ -1,5 +1,5 @@
 """Resilience subsystem tests (ISSUE 13): fault-schedule grammar,
-guarded dispatch (fake-clock retry/backoff, deadline, taxonomy,
+guarded dispatch (fake-clock retry/backoff, deadline, classification,
 tripwires), the degradation ladder over the committed oracle knobs, and
 the CSTPU_FAULTS-off no-op bound.
 
@@ -110,7 +110,7 @@ def test_faults_env_driven(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# Guarded dispatch: retry / backoff / deadline / taxonomy (fake clock)
+# Guarded dispatch: retry / backoff / deadline / classification (fake clock)
 # ---------------------------------------------------------------------------
 
 def test_transient_retries_with_backoff_fake_clock():
@@ -120,7 +120,7 @@ def test_transient_retries_with_backoff_fake_clock():
     def flaky():
         calls.append(1)
         if len(calls) < 3:
-            raise RuntimeError("UNAVAILABLE: relay flaked")
+            raise RuntimeError("UNAVAILABLE: device link flaked")
         return 7
 
     out = rdispatch.guarded_dispatch(

@@ -1,6 +1,6 @@
 """The bulk-Merkleizer state-root hook vs the recursive oracle.
 
-VERDICT r3 #3: process_slot's full-state hash_tree_root (the reference's
+process_slot's full-state hash_tree_root (the reference's
 hottest loop, 0_beacon-chain.md:1232-1245) must actually route through
 utils/ssz/bulk.py when installed. These tests install the hook and drive
 real transitions, requiring bit-identical states against the un-hooked

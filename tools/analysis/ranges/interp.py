@@ -357,7 +357,7 @@ def _def_of(self, atom):
     if hasattr(atom, "val"):              # Literal: no def, unhashable
         return None
     d = self._defs.get(atom)
-    if d is None or d.primitive.name != "pjit":
+    if d is None or d.primitive.name not in ("jit", "pjit"):
         return d
     inner = d.params.get("jaxpr")
     if inner is None:
@@ -1369,7 +1369,7 @@ SUMMARIES = {
 }
 
 
-@handler("pjit", "closed_call", "core_call", "xla_call", "remat",
+@handler("jit", "pjit", "closed_call", "core_call", "xla_call", "remat",
          "remat_call", "checkpoint", "custom_jvp_call", "custom_vjp_call",
          "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr")
 def _call(self, eqn, vals):

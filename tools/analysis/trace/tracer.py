@@ -22,6 +22,7 @@ from typing import Dict, Iterable, Optional, Tuple
 import numpy as np
 
 import jax
+from jax.extend import core as jex_core
 
 
 # ---------------------------------------------------------------------------
@@ -35,9 +36,9 @@ def iter_subjaxprs(params) -> Iterable[Tuple[object, list]]:
         stack = [v]
         while stack:
             x = stack.pop()
-            if isinstance(x, jax.core.ClosedJaxpr):
+            if isinstance(x, jex_core.ClosedJaxpr):
                 yield x.jaxpr, x.consts
-            elif isinstance(x, jax.core.Jaxpr):
+            elif isinstance(x, jex_core.Jaxpr):
                 yield x, []
             elif isinstance(x, (list, tuple)):
                 stack.extend(x)
@@ -67,7 +68,7 @@ def walk_eqns(closed):
 
 
 def _scalar_const_of(invar, env) -> Optional[int]:
-    if isinstance(invar, jax.core.Literal):
+    if isinstance(invar, jex_core.Literal):
         val = invar.val
     elif invar in env:
         val = env[invar]
@@ -201,42 +202,45 @@ def _split_top_level(s: str) -> list:
     return out
 
 
-_SHARDING_ATTR_RE = re.compile(r'mhlo\.sharding\s*=\s*"([^"]*)"')
+_SDY_ATTR = "sdy.sharding = #sdy.sharding<"
+_SDY_MESH_RE = re.compile(r"sdy\.mesh\s+(@[\w.$-]+)\s*=\s*(<[^\n]*>)")
+
+
+def _balanced(text: str, i: int, open_ch: str, close_ch: str) -> int:
+    """Index of the `close_ch` matching the `open_ch` at text[i]."""
+    depth = 0
+    for j in range(i, len(text)):
+        if text[j] == open_ch:
+            depth += 1
+        elif text[j] == close_ch:
+            depth -= 1
+            if depth == 0:
+                return j
+    raise ValueError(f"unbalanced {open_ch}{close_ch} from offset {i}")
 
 
 def signature_shardings(text: str):
     """(arg_shardings, result_shardings) of the @main function of a
-    lowered StableHLO module: per flattened arg/result, the
-    mhlo.sharding attribute string or None when unannotated."""
+    lowered StableHLO module: per flattened arg/result, the Shardy
+    `sdy.sharding` annotation with the mesh it names spelled out
+    (`<["v"=4]>, [{"v"}, {}]`), or None when unannotated. Two operands
+    compare equal only when axes AND mesh shape agree."""
+    meshes = dict(_SDY_MESH_RE.findall(text))
     anchor = text.index("func.func public @main(")
     i = text.index("(", anchor)
-    depth, j = 0, i
-    while True:
-        if text[j] == "(":
-            depth += 1
-        elif text[j] == ")":
-            depth -= 1
-            if depth == 0:
-                break
-        j += 1
+    j = _balanced(text, i, "(", ")")
     args_src = text[i + 1:j]
-    rest = text[j:]
-    arrow = rest.index("->")
-    k = rest.index("(", arrow)
-    depth, m = 0, k
-    while True:
-        if rest[m] == "(":
-            depth += 1
-        elif rest[m] == ")":
-            depth -= 1
-            if depth == 0:
-                break
-        m += 1
-    results_src = rest[k + 1:m]
+    k = text.index("(", text.index("->", j))
+    results_src = text[k + 1:_balanced(text, k, "(", ")")]
 
     def shard_of(entry: str):
-        m2 = _SHARDING_ATTR_RE.search(entry)
-        return m2.group(1) if m2 else None
+        at = entry.find(_SDY_ATTR)
+        if at < 0:
+            return None
+        lt = at + len(_SDY_ATTR) - 1
+        mesh, _, dims = entry[lt + 1:_balanced(entry, lt, "<", ">")] \
+            .partition(",")
+        return f"{meshes.get(mesh.strip(), mesh.strip())},{dims}"
 
     return ([shard_of(e) for e in _split_top_level(args_src)],
             [shard_of(e) for e in _split_top_level(results_src)])
