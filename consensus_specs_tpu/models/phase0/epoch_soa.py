@@ -149,138 +149,143 @@ def _stage_a_traced(cfg: EpochConfig, cols: ValidatorColumns,
     total_balance = _total_balance(eff, active_curr)
     active_count = jnp.sum(active_curr.astype(jnp.uint64))
 
-    # -- Justification and finalization (:1326-1373) ------------------------
-    justification_active = current_epoch > u64(cfg.GENESIS_EPOCH + 1)
-    unslashed = ~cols.slashed
-    prev_tgt_balance = _total_balance(eff, inp.prev_tgt & unslashed)
-    curr_tgt_balance = _total_balance(eff, inp.curr_tgt & unslashed)
+    with jax.named_scope("justification"):
+        # -- Justification and finalization (:1326-1373) ------------------------
+        justification_active = current_epoch > u64(cfg.GENESIS_EPOCH + 1)
+        unslashed = ~cols.slashed
+        prev_tgt_balance = _total_balance(eff, inp.prev_tgt & unslashed)
+        curr_tgt_balance = _total_balance(eff, inp.curr_tgt & unslashed)
 
-    old_prev_just = scal.previous_justified_epoch
-    old_curr_just = scal.current_justified_epoch
-    new_prev_just = old_curr_just
-    bitfield = (scal.justification_bitfield << u64(1))  # uint64 wraps = % 2**64
-    just_prev = prev_tgt_balance * u64(3) >= total_balance * u64(2)
-    just_curr = curr_tgt_balance * u64(3) >= total_balance * u64(2)
-    new_curr_just = jnp.where(just_prev, previous_epoch, old_curr_just)
-    bitfield = jnp.where(just_prev, bitfield | u64(2), bitfield)
-    new_curr_just = jnp.where(just_curr, current_epoch, new_curr_just)
-    bitfield = jnp.where(just_curr, bitfield | u64(1), bitfield)
+        old_prev_just = scal.previous_justified_epoch
+        old_curr_just = scal.current_justified_epoch
+        new_prev_just = old_curr_just
+        bitfield = (scal.justification_bitfield << u64(1))  # uint64 wraps = % 2**64
+        just_prev = prev_tgt_balance * u64(3) >= total_balance * u64(2)
+        just_curr = curr_tgt_balance * u64(3) >= total_balance * u64(2)
+        new_curr_just = jnp.where(just_prev, previous_epoch, old_curr_just)
+        bitfield = jnp.where(just_prev, bitfield | u64(2), bitfield)
+        new_curr_just = jnp.where(just_curr, current_epoch, new_curr_just)
+        bitfield = jnp.where(just_curr, bitfield | u64(1), bitfield)
 
-    new_finalized = scal.finalized_epoch
-    fin_fired = jnp.asarray(False)
-    # The 2nd/3rd/4th most recent epochs justified, 2nd using 4th as source
-    c1 = ((bitfield >> u64(1)) % u64(8) == u64(0b111)) & (old_prev_just + u64(3) == current_epoch)
-    new_finalized = jnp.where(c1, old_prev_just, new_finalized)
-    # The 2nd/3rd most recent epochs justified, 2nd using 3rd as source
-    c2 = ((bitfield >> u64(1)) % u64(4) == u64(0b11)) & (old_prev_just + u64(2) == current_epoch)
-    new_finalized = jnp.where(c2, old_prev_just, new_finalized)
-    # The 1st/2nd/3rd most recent epochs justified, 1st using 3rd as source
-    c3 = ((bitfield >> u64(0)) % u64(8) == u64(0b111)) & (old_curr_just + u64(2) == current_epoch)
-    new_finalized = jnp.where(c3, old_curr_just, new_finalized)
-    # The 1st/2nd most recent epochs justified, 1st using 2nd as source
-    c4 = ((bitfield >> u64(0)) % u64(4) == u64(0b11)) & (old_curr_just + u64(1) == current_epoch)
-    new_finalized = jnp.where(c4, old_curr_just, new_finalized)
-    fin_fired = c1 | c2 | c3 | c4
+        new_finalized = scal.finalized_epoch
+        fin_fired = jnp.asarray(False)
+        # The 2nd/3rd/4th most recent epochs justified, 2nd using 4th as source
+        c1 = ((bitfield >> u64(1)) % u64(8) == u64(0b111)) & (old_prev_just + u64(3) == current_epoch)
+        new_finalized = jnp.where(c1, old_prev_just, new_finalized)
+        # The 2nd/3rd most recent epochs justified, 2nd using 3rd as source
+        c2 = ((bitfield >> u64(1)) % u64(4) == u64(0b11)) & (old_prev_just + u64(2) == current_epoch)
+        new_finalized = jnp.where(c2, old_prev_just, new_finalized)
+        # The 1st/2nd/3rd most recent epochs justified, 1st using 3rd as source
+        c3 = ((bitfield >> u64(0)) % u64(8) == u64(0b111)) & (old_curr_just + u64(2) == current_epoch)
+        new_finalized = jnp.where(c3, old_curr_just, new_finalized)
+        # The 1st/2nd most recent epochs justified, 1st using 2nd as source
+        c4 = ((bitfield >> u64(0)) % u64(4) == u64(0b11)) & (old_curr_just + u64(1) == current_epoch)
+        new_finalized = jnp.where(c4, old_curr_just, new_finalized)
+        fin_fired = c1 | c2 | c3 | c4
 
-    prev_just = jnp.where(justification_active, new_prev_just, old_prev_just)
-    curr_just = jnp.where(justification_active, new_curr_just, old_curr_just)
-    bitfield = jnp.where(justification_active, bitfield, scal.justification_bitfield)
-    finalized = jnp.where(justification_active, new_finalized, scal.finalized_epoch)
-    fin_fired = fin_fired & justification_active
+        prev_just = jnp.where(justification_active, new_prev_just, old_prev_just)
+        curr_just = jnp.where(justification_active, new_curr_just, old_curr_just)
+        bitfield = jnp.where(justification_active, bitfield, scal.justification_bitfield)
+        finalized = jnp.where(justification_active, new_finalized, scal.finalized_epoch)
+        fin_fired = fin_fired & justification_active
 
-    # -- Rewards and penalties (:1391-1475) ---------------------------------
-    rewards_active = current_epoch != u64(cfg.GENESIS_EPOCH)
-    sqrt_total = intmath.isqrt_u64(total_balance)
-    base_reward = eff * u64(cfg.BASE_REWARD_FACTOR) // sqrt_total // u64(cfg.BASE_REWARDS_PER_EPOCH)
+    with jax.named_scope("rewards_and_penalties"):
+        # -- Rewards and penalties (:1391-1475) ---------------------------------
+        rewards_active = current_epoch != u64(cfg.GENESIS_EPOCH)
+        sqrt_total = intmath.isqrt_u64(total_balance)
+        base_reward = eff * u64(cfg.BASE_REWARD_FACTOR) // sqrt_total // u64(cfg.BASE_REWARDS_PER_EPOCH)
 
-    eligible = active_prev | (cols.slashed & (previous_epoch + u64(1) < cols.withdrawable_epoch))
-    rewards = jnp.zeros(V, dtype=jnp.uint64)
-    penalties = jnp.zeros(V, dtype=jnp.uint64)
+        eligible = active_prev | (cols.slashed & (previous_epoch + u64(1) < cols.withdrawable_epoch))
+        rewards = jnp.zeros(V, dtype=jnp.uint64)
+        penalties = jnp.zeros(V, dtype=jnp.uint64)
 
-    # Micro-incentives for matching source / target / head (:1398-1414)
-    for flag in (inp.prev_src, inp.prev_tgt, inp.prev_head):
-        in_set = flag & unslashed
-        att_balance = _total_balance(eff, in_set)
-        match_reward = intmath.muldiv_u64(base_reward, att_balance, total_balance)
-        rewards = rewards + jnp.where(eligible & in_set, match_reward, u64(0))
-        penalties = penalties + jnp.where(eligible & ~in_set, base_reward, u64(0))
+        # Micro-incentives for matching source / target / head (:1398-1414)
+        for flag in (inp.prev_src, inp.prev_tgt, inp.prev_head):
+            in_set = flag & unslashed
+            att_balance = _total_balance(eff, in_set)
+            match_reward = intmath.muldiv_u64(base_reward, att_balance, total_balance)
+            rewards = rewards + jnp.where(eligible & in_set, match_reward, u64(0))
+            penalties = penalties + jnp.where(eligible & ~in_set, base_reward, u64(0))
 
-    # Proposer + inclusion-delay micro-rewards for source attesters (:1416-1429)
-    src_set = inp.prev_src & unslashed
-    proposer_gain = jnp.where(src_set, base_reward // u64(cfg.PROPOSER_REWARD_QUOTIENT), u64(0))
-    rewards = rewards.at[inp.att_proposer].add(proposer_gain)
-    delay = jnp.maximum(inp.incl_delay, u64(1))
-    rewards = rewards + jnp.where(
-        src_set, base_reward * u64(cfg.MIN_ATTESTATION_INCLUSION_DELAY) // delay, u64(0))
+        # Proposer + inclusion-delay micro-rewards for source attesters (:1416-1429)
+        src_set = inp.prev_src & unslashed
+        proposer_gain = jnp.where(src_set, base_reward // u64(cfg.PROPOSER_REWARD_QUOTIENT), u64(0))
+        rewards = rewards.at[inp.att_proposer].add(proposer_gain)
+        delay = jnp.maximum(inp.incl_delay, u64(1))
+        rewards = rewards + jnp.where(
+            src_set, base_reward * u64(cfg.MIN_ATTESTATION_INCLUSION_DELAY) // delay, u64(0))
 
-    # Inactivity penalty (:1431-1440)
-    # saturating: finalized <= previous_epoch is a chain invariant (an
-    # epoch finalizes only after it was previous), so the min() changes
-    # nothing on reachable states — it makes the inactivity product
-    # eff * finality_delay provably wrap-free (make ranges) instead of
-    # multiplying by a wrapped ~2^64 delay on a corrupt state
-    finality_delay = previous_epoch - jnp.minimum(finalized, previous_epoch)
-    inactivity = finality_delay > u64(cfg.MIN_EPOCHS_TO_INACTIVITY_PENALTY)
-    tgt_set = inp.prev_tgt & unslashed
-    penalties = penalties + jnp.where(
-        inactivity & eligible, u64(cfg.BASE_REWARDS_PER_EPOCH) * base_reward, u64(0))
-    penalties = penalties + jnp.where(
-        inactivity & eligible & ~tgt_set,
-        eff * finality_delay // u64(cfg.INACTIVITY_PENALTY_QUOTIENT), u64(0))
+        # Inactivity penalty (:1431-1440)
+        # saturating: finalized <= previous_epoch is a chain invariant (an
+        # epoch finalizes only after it was previous), so the min() changes
+        # nothing on reachable states — it makes the inactivity product
+        # eff * finality_delay provably wrap-free (make ranges) instead of
+        # multiplying by a wrapped ~2^64 delay on a corrupt state
+        finality_delay = previous_epoch - jnp.minimum(finalized, previous_epoch)
+        inactivity = finality_delay > u64(cfg.MIN_EPOCHS_TO_INACTIVITY_PENALTY)
+        tgt_set = inp.prev_tgt & unslashed
+        penalties = penalties + jnp.where(
+            inactivity & eligible, u64(cfg.BASE_REWARDS_PER_EPOCH) * base_reward, u64(0))
+        penalties = penalties + jnp.where(
+            inactivity & eligible & ~tgt_set,
+            eff * finality_delay // u64(cfg.INACTIVITY_PENALTY_QUOTIENT), u64(0))
 
-    # Crosslink deltas (:1445-1463): per-shard tables gathered per validator
-    in_committee = inp.v_shard >= 0
-    shard_idx = jnp.maximum(inp.v_shard, 0)
-    cl_att = inp.shard_att_balance[shard_idx]
-    cl_comm = jnp.maximum(inp.shard_comm_balance[shard_idx], u64(1))
-    cl_reward = intmath.muldiv_u64(base_reward, cl_att, cl_comm)
-    rewards = rewards + jnp.where(in_committee & inp.in_winning, cl_reward, u64(0))
-    penalties = penalties + jnp.where(in_committee & ~inp.in_winning, base_reward, u64(0))
+    with jax.named_scope("crosslink_deltas"):
+        # Crosslink deltas (:1445-1463): per-shard tables gathered per validator
+        in_committee = inp.v_shard >= 0
+        shard_idx = jnp.maximum(inp.v_shard, 0)
+        cl_att = inp.shard_att_balance[shard_idx]
+        cl_comm = jnp.maximum(inp.shard_comm_balance[shard_idx], u64(1))
+        cl_reward = intmath.muldiv_u64(base_reward, cl_att, cl_comm)
+        rewards = rewards + jnp.where(in_committee & inp.in_winning, cl_reward, u64(0))
+        penalties = penalties + jnp.where(in_committee & ~inp.in_winning, base_reward, u64(0))
 
-    # Apply: increase then saturating decrease (:687-705, :1465-1475)
-    balance = cols.balance + jnp.where(rewards_active, rewards, u64(0))
-    pen = jnp.where(rewards_active, penalties, u64(0))
-    balance = jnp.where(pen > balance, u64(0), balance - pen)
+    with jax.named_scope("rewards_and_penalties"):
+        # Apply: increase then saturating decrease (:687-705, :1465-1475)
+        balance = cols.balance + jnp.where(rewards_active, rewards, u64(0))
+        pen = jnp.where(rewards_active, penalties, u64(0))
+        balance = jnp.where(pen > balance, u64(0), balance - pen)
 
-    # -- Registry updates (:1479-1503) --------------------------------------
-    churn = jnp.maximum(u64(cfg.MIN_PER_EPOCH_CHURN_LIMIT),
-                        active_count // u64(cfg.CHURN_LIMIT_QUOTIENT))
+    with jax.named_scope("registry_updates"):
+        # -- Registry updates (:1479-1503) --------------------------------------
+        churn = jnp.maximum(u64(cfg.MIN_PER_EPOCH_CHURN_LIMIT),
+                            active_count // u64(cfg.CHURN_LIMIT_QUOTIENT))
 
-    # Activation eligibility
-    elig = jnp.where(
-        (cols.activation_eligibility_epoch == FAR) & (eff >= u64(cfg.MAX_EFFECTIVE_BALANCE)),
-        current_epoch, cols.activation_eligibility_epoch)
+        # Activation eligibility
+        elig = jnp.where(
+            (cols.activation_eligibility_epoch == FAR) & (eff >= u64(cfg.MAX_EFFECTIVE_BALANCE)),
+            current_epoch, cols.activation_eligibility_epoch)
 
-    # Ejections -> closed-form exit queue (initiate_validator_exit :1103-1118)
-    ejected = active_curr & (eff <= u64(cfg.EJECTION_BALANCE)) & (cols.exit_epoch == FAR)
-    delayed_exit = current_epoch + u64(1) + u64(cfg.ACTIVATION_EXIT_DELAY)
-    has_exit = cols.exit_epoch != FAR
-    base_epoch = jnp.maximum(
-        jnp.max(jnp.where(has_exit, cols.exit_epoch, u64(0))), delayed_exit)
-    count_at_base = jnp.sum((cols.exit_epoch == base_epoch).astype(jnp.uint64))
-    c0 = jnp.minimum(count_at_base, churn)
-    rank = jnp.cumsum(ejected.astype(jnp.uint64)) - ejected.astype(jnp.uint64)
-    # the has_exit select above already strips the FAR_FUTURE_EPOCH
-    # sentinel (2^64-1) from real states, but the interval domain keeps
-    # the sentinel in exit_epoch's hull, so the range tier cannot
-    # exclude base_epoch ~ 2^64 here; real base_epoch is bounded by the
-    # largest genuine exit epoch and the add cannot wrap
-    # csa: ignore[CSA1401] -- FAR sentinel lanes are select-masked
-    assigned = base_epoch + (c0 + rank) // churn
-    exit_epoch = jnp.where(ejected, assigned, cols.exit_epoch)
-    withdrawable = jnp.where(
-        ejected, assigned + u64(cfg.MIN_VALIDATOR_WITHDRAWABILITY_DELAY), cols.withdrawable_epoch)
+        # Ejections -> closed-form exit queue (initiate_validator_exit :1103-1118)
+        ejected = active_curr & (eff <= u64(cfg.EJECTION_BALANCE)) & (cols.exit_epoch == FAR)
+        delayed_exit = current_epoch + u64(1) + u64(cfg.ACTIVATION_EXIT_DELAY)
+        has_exit = cols.exit_epoch != FAR
+        base_epoch = jnp.maximum(
+            jnp.max(jnp.where(has_exit, cols.exit_epoch, u64(0))), delayed_exit)
+        count_at_base = jnp.sum((cols.exit_epoch == base_epoch).astype(jnp.uint64))
+        c0 = jnp.minimum(count_at_base, churn)
+        rank = jnp.cumsum(ejected.astype(jnp.uint64)) - ejected.astype(jnp.uint64)
+        # the has_exit select above already strips the FAR_FUTURE_EPOCH
+        # sentinel (2^64-1) from real states, but the interval domain keeps
+        # the sentinel in exit_epoch's hull, so the range tier cannot
+        # exclude base_epoch ~ 2^64 here; real base_epoch is bounded by the
+        # largest genuine exit epoch and the add cannot wrap
+        # csa: ignore[CSA1401] -- FAR sentinel lanes are select-masked
+        assigned = base_epoch + (c0 + rank) // churn
+        exit_epoch = jnp.where(ejected, assigned, cols.exit_epoch)
+        withdrawable = jnp.where(
+            ejected, assigned + u64(cfg.MIN_VALIDATOR_WITHDRAWABILITY_DELAY), cols.withdrawable_epoch)
 
-    # Activation queue: stable sort by eligibility epoch, dequeue churn-many
-    delayed_fin = finalized + u64(1) + u64(cfg.ACTIVATION_EXIT_DELAY)
-    queued = (elig != FAR) & (cols.activation_epoch >= delayed_fin)
-    sort_key = jnp.where(queued, elig, FAR)
-    order = jnp.argsort(sort_key, stable=True)
-    pos = jnp.zeros(V, dtype=jnp.uint64).at[order].set(jnp.arange(V, dtype=jnp.uint64))
-    dequeued = queued & (pos < churn)
-    activation = jnp.where(
-        dequeued & (cols.activation_epoch == FAR),
-        current_epoch + u64(1) + u64(cfg.ACTIVATION_EXIT_DELAY), cols.activation_epoch)
+        # Activation queue: stable sort by eligibility epoch, dequeue churn-many
+        delayed_fin = finalized + u64(1) + u64(cfg.ACTIVATION_EXIT_DELAY)
+        queued = (elig != FAR) & (cols.activation_epoch >= delayed_fin)
+        sort_key = jnp.where(queued, elig, FAR)
+        order = jnp.argsort(sort_key, stable=True)
+        pos = jnp.zeros(V, dtype=jnp.uint64).at[order].set(jnp.arange(V, dtype=jnp.uint64))
+        dequeued = queued & (pos < churn)
+        activation = jnp.where(
+            dequeued & (cols.activation_epoch == FAR),
+            current_epoch + u64(1) + u64(cfg.ACTIVATION_EXIT_DELAY), cols.activation_epoch)
 
     mid_cols = ValidatorColumns(
         activation_eligibility_epoch=elig,
@@ -328,41 +333,43 @@ def _stage_b_traced(cfg: EpochConfig, cols: ValidatorColumns,
     total_balance = _total_balance(eff, active_curr)
     active_count = jnp.sum(active_curr.astype(jnp.uint64))
 
-    # -- Slashings (:1507-1524) ---------------------------------------------
-    L = cfg.LATEST_SLASHED_EXIT_LENGTH
-    lsb = scal.latest_slashed_balances
-    at_start = lsb[(current_epoch + u64(1)) % u64(L)]
-    at_end = lsb[current_epoch % u64(L)]
-    tp3 = (at_end.astype(jnp.int64) - at_start.astype(jnp.int64)) * 3
-    m = jnp.minimum(tp3, total_balance.astype(jnp.int64))
-    scaled = jnp.where(m < 0, u64(0),
-                       intmath.muldiv_u64(eff, jnp.maximum(m, 0).astype(jnp.uint64), total_balance))
-    slash_penalty = jnp.maximum(scaled, eff // u64(cfg.MIN_SLASHING_PENALTY_QUOTIENT))
-    slash_now = cols.slashed & (current_epoch == cols.withdrawable_epoch - u64(L // 2))
-    slash_penalty = jnp.where(slash_now, slash_penalty, u64(0))
-    balance = jnp.where(slash_penalty > balance, u64(0), balance - slash_penalty)
+    with jax.named_scope("slashings"):
+        # -- Slashings (:1507-1524) ---------------------------------------------
+        L = cfg.LATEST_SLASHED_EXIT_LENGTH
+        lsb = scal.latest_slashed_balances
+        at_start = lsb[(current_epoch + u64(1)) % u64(L)]
+        at_end = lsb[current_epoch % u64(L)]
+        tp3 = (at_end.astype(jnp.int64) - at_start.astype(jnp.int64)) * 3
+        m = jnp.minimum(tp3, total_balance.astype(jnp.int64))
+        scaled = jnp.where(m < 0, u64(0),
+                           intmath.muldiv_u64(eff, jnp.maximum(m, 0).astype(jnp.uint64), total_balance))
+        slash_penalty = jnp.maximum(scaled, eff // u64(cfg.MIN_SLASHING_PENALTY_QUOTIENT))
+        slash_now = cols.slashed & (current_epoch == cols.withdrawable_epoch - u64(L // 2))
+        slash_penalty = jnp.where(slash_now, slash_penalty, u64(0))
+        balance = jnp.where(slash_penalty > balance, u64(0), balance - slash_penalty)
 
-    # -- Final updates, numeric parts (:1526-1564) --------------------------
-    next_epoch = current_epoch + u64(1)
-    half_inc = u64(cfg.EFFECTIVE_BALANCE_INCREMENT // 2)
-    stale = (balance < eff) | (eff + u64(3) * half_inc < balance)
-    new_eff = jnp.where(
-        stale,
-        jnp.minimum(balance - balance % u64(cfg.EFFECTIVE_BALANCE_INCREMENT),
-                    u64(cfg.MAX_EFFECTIVE_BALANCE)),
-        eff)
+    with jax.named_scope("final_updates"):
+        # -- Final updates, numeric parts (:1526-1564) --------------------------
+        next_epoch = current_epoch + u64(1)
+        half_inc = u64(cfg.EFFECTIVE_BALANCE_INCREMENT // 2)
+        stale = (balance < eff) | (eff + u64(3) * half_inc < balance)
+        new_eff = jnp.where(
+            stale,
+            jnp.minimum(balance - balance % u64(cfg.EFFECTIVE_BALANCE_INCREMENT),
+                        u64(cfg.MAX_EFFECTIVE_BALANCE)),
+            eff)
 
-    # Start shard rotation (get_shard_delta over the *current* epoch :1543-1545)
-    committees = jnp.maximum(
-        u64(1),
-        jnp.minimum(u64(cfg.SHARD_COUNT // cfg.SLOTS_PER_EPOCH),
-                    active_count // u64(cfg.SLOTS_PER_EPOCH) // u64(cfg.TARGET_COMMITTEE_SIZE)),
-    ) * u64(cfg.SLOTS_PER_EPOCH)
-    shard_delta = jnp.minimum(
-        committees, u64(cfg.SHARD_COUNT - cfg.SHARD_COUNT // cfg.SLOTS_PER_EPOCH))
-    start_shard = (scal.latest_start_shard + shard_delta) % u64(cfg.SHARD_COUNT)
+        # Start shard rotation (get_shard_delta over the *current* epoch :1543-1545)
+        committees = jnp.maximum(
+            u64(1),
+            jnp.minimum(u64(cfg.SHARD_COUNT // cfg.SLOTS_PER_EPOCH),
+                        active_count // u64(cfg.SLOTS_PER_EPOCH) // u64(cfg.TARGET_COMMITTEE_SIZE)),
+        ) * u64(cfg.SLOTS_PER_EPOCH)
+        shard_delta = jnp.minimum(
+            committees, u64(cfg.SHARD_COUNT - cfg.SHARD_COUNT // cfg.SLOTS_PER_EPOCH))
+        start_shard = (scal.latest_start_shard + shard_delta) % u64(cfg.SHARD_COUNT)
 
-    lsb = lsb.at[next_epoch % u64(L)].set(lsb[current_epoch % u64(L)])
+        lsb = lsb.at[next_epoch % u64(L)].set(lsb[current_epoch % u64(L)])
 
     new_cols = cols._replace(effective_balance=new_eff, balance=balance)
     new_scal = scal._replace(latest_start_shard=start_shard,
@@ -372,8 +379,12 @@ def _stage_b_traced(cfg: EpochConfig, cols: ValidatorColumns,
 
 def _epoch_transition_traced(cfg: EpochConfig, cols: ValidatorColumns,
                              scal: EpochScalars, inp: EpochInputs):
-    mid_cols, mid_scal, report = _stage_a_traced(cfg, cols, scal, inp)
-    new_cols, new_scal = _stage_b_traced(cfg, mid_cols, mid_scal)
+    # named scopes are trace-time only: they put the spec's own names on
+    # the device operations of a profiler trace, and change no program
+    with jax.named_scope("epoch_stage_a"):
+        mid_cols, mid_scal, report = _stage_a_traced(cfg, cols, scal, inp)
+    with jax.named_scope("epoch_stage_b"):
+        new_cols, new_scal = _stage_b_traced(cfg, mid_cols, mid_scal)
     return new_cols, new_scal, report
 
 

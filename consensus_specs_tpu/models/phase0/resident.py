@@ -78,6 +78,16 @@ _MIRROR_FIELDS = ("activation_epoch", "exit_epoch", "effective_balance",
                   "slashed")
 _ALL_FIELDS = ValidatorColumns._fields
 
+# The state root's fields by the span that times them (_state_root): the
+# two forests and the two attestation lists have paths of their own, the
+# history vectors are where the host hashing grows, the rest is small.
+_ATTESTATION_FIELDS = ("previous_epoch_attestations",
+                       "current_epoch_attestations")
+_HISTORY_FIELDS = ("latest_block_roots", "latest_state_roots",
+                   "latest_randao_mixes", "latest_active_index_roots",
+                   "latest_slashed_balances", "historical_roots")
+_FOREST_PAIR_LANES = telemetry.counter("merkle.forest.pair_lanes")
+
 # Per-core watchdog key prefix: layout fingerprints must not leak between
 # cores (a mesh core and a single-device core in one test process would
 # otherwise trip false re-layout events against each other's placements).
@@ -193,6 +203,11 @@ class ResidentCore:
         (resilience/errors.py) up front — never an opaque struct/index
         error from deep inside the offset-grammar walkers — so the
         checkpoint store's generation fallback can branch on type."""
+        with telemetry.span("resident.restore"):
+            return cls._from_checkpoint(spec, state_bytes, mesh)
+
+    @classmethod
+    def _from_checkpoint(cls, spec, state_bytes, mesh) -> "ResidentCore":
         if spec._insert_after_registry_updates or spec._insert_after_final_updates:
             raise NotImplementedError(
                 "resident mode covers the phase-0 fused epoch program; "
@@ -213,8 +228,9 @@ class ResidentCore:
                 f"checkpoint truncated: {len(state_bytes)} bytes < the "
                 f"{floor}-byte BeaconState fixed-part floor")
         try:
-            np_cols = state_columns_from_bytes(state_bytes, spec)
-            state = light_state_from_bytes(spec, state_bytes)
+            with telemetry.span("resident.restore.decode"):
+                np_cols = state_columns_from_bytes(state_bytes, spec)
+                state = light_state_from_bytes(spec, state_bytes)
         except CheckpointCorrupt:
             raise
         except Exception as exc:
@@ -235,7 +251,9 @@ class ResidentCore:
         core._active_idx_memo = {}
         core._att_root_memo = {}
         core._light = True
-        core._enter(state, np_cols=np_cols)
+        with telemetry.span("resident.restore.upload") as sp:
+            core._enter(state, np_cols=np_cols)
+            sp.fence(core.cols, core.pk_dev)    # the uploads have landed
         return core
 
     def _enter(self, state, np_cols: Optional[dict] = None) -> None:
@@ -330,10 +348,14 @@ class ResidentCore:
         with from_checkpoint this round-trips the original bytes when no
         transition ran."""
         from ...utils.ssz.columns import state_bytes_from_columns
-        np_cols = self._materialize_np_cols()
-        np_cols["pubkey"] = self._pk_np
-        np_cols["withdrawal_credentials"] = self._wc_np
-        return state_bytes_from_columns(self.state, np_cols, self.spec)
+        with telemetry.span("resident.checkpoint_write"):
+            with telemetry.span("resident.checkpoint_write.download"):
+                np_cols = self._materialize_np_cols()
+            with telemetry.span("resident.checkpoint_write.assemble"):
+                np_cols["pubkey"] = self._pk_np
+                np_cols["withdrawal_credentials"] = self._wc_np
+                return state_bytes_from_columns(self.state, np_cols,
+                                                self.spec)
 
     def suspended(self):
         """Context manager: temporarily restore the unpatched spec (e.g.
@@ -590,15 +612,23 @@ class ResidentCore:
         never the all-or-nothing ~2M-leaf re-Merkleization."""
         if self._big_roots is not None:
             return self._big_roots
+        with telemetry.span("resident.forests") as sp:
+            lanes0 = _FOREST_PAIR_LANES.value
+            self._big_roots = self._build_forest_roots()
+            sp.note(pair_lanes=_FOREST_PAIR_LANES.value - lanes0)
+        return self._big_roots
+
+    def _build_forest_roots(self) -> tuple:
+        """The build behind `_registry_balances_roots`: both leaf
+        programs, both forests' levels, both roots down to the host."""
         c = self.cols
         V = self._v
         if V == 0 or self.pk_dev.shape[0] == 0:
             # degenerate metadata-only state: the numpy oracle short-circuit
-            self._big_roots = bulk.registry_and_balances_roots_device(
+            return bulk.registry_and_balances_roots_device(
                 self.pk_dev, self.wc_dev, c.activation_eligibility_epoch,
                 c.activation_epoch, c.exit_epoch, c.withdrawable_epoch,
                 c.slashed, c.effective_balance, c.balance)
-            return self._big_roots
         if self._mesh is not None:
             # sharded forests: level 0 built by the mesh's placed leaf
             # programs (inert padding rows masked to the SSZ virtual-zero
@@ -634,15 +664,15 @@ class ResidentCore:
                                self._reg_forest.levels[0])
         _watchdog.layout_check(f"{self._tkey}.forest.bal.l0",
                                self._bal_forest.levels[0])
-        self._big_roots = (
-            ssz_impl.mix_in_length(self._reg_forest.root(), V),
-            ssz_impl.mix_in_length(self._bal_forest.root(), V))
-        return self._big_roots
+        return (ssz_impl.mix_in_length(self._reg_forest.root(), V),
+                ssz_impl.mix_in_length(self._bal_forest.root(), V))
 
     def _state_root(self, state):
         """Full BeaconState root: device roots for the two registry-scale
         fields (cached until the columns change), bulk-memoized roots for
-        everything else. Same leaf layout as impl.hash_tree_root.
+        everything else. Same leaf layout as impl.hash_tree_root. The
+        fields are taken group by group, one span a group, and each root
+        is put at its field's index.
 
         Declines (-> saved backend / recursive oracle) for any state other
         than the resident one: the device columns describe THIS state only,
@@ -652,21 +682,26 @@ class ResidentCore:
         if state is not self.state:
             return (self._saved_root_backend(state)
                     if self._saved_root_backend is not None else None)
-        reg_root, bal_root = self._registry_balances_roots()
-        leaves = []
-        for (value, typ), name in zip(state.get_typed_values(),
-                                      state.get_field_names()):
-            if name == "validator_registry":
-                leaves.append(reg_root)
-            elif name == "balances":
-                leaves.append(bal_root)
-            elif name in ("previous_epoch_attestations",
-                          "current_epoch_attestations"):
-                leaves.append(self._att_list_root(value, typ))
-            else:
-                leaves.append(bulk.hash_tree_root_bulk(value, typ))
-        arr = np.stack([np.frombuffer(r, np.uint8) for r in leaves])
-        return bulk.merkleize_chunk_array(arr)
+        names = state.get_field_names()
+        typed = dict(zip(names, state.get_typed_values()))
+        roots = {}
+        with telemetry.span("resident.slot_root.forests"):
+            roots["validator_registry"], roots["balances"] = \
+                self._registry_balances_roots()
+        with telemetry.span("resident.slot_root.attestations"):
+            for name in _ATTESTATION_FIELDS:
+                roots[name] = self._att_list_root(*typed[name])
+        with telemetry.span("resident.slot_root.history"):
+            for name in _HISTORY_FIELDS:
+                roots[name] = bulk.hash_tree_root_bulk(*typed[name])
+        with telemetry.span("resident.slot_root.small"):
+            for name in names:
+                if name not in roots:
+                    roots[name] = bulk.hash_tree_root_bulk(*typed[name])
+        with telemetry.span("resident.slot_root.merkleize"):
+            arr = np.stack([np.frombuffer(roots[name], np.uint8)
+                            for name in names])
+            return bulk.merkleize_chunk_array(arr)
 
     def _att_list_root(self, atts, typ) -> bytes:
         """List[PendingAttestation] root with element roots memoized by
@@ -711,15 +746,24 @@ class ResidentCore:
     def process_slots(self, state, slot: int) -> None:
         assert state.slot <= slot
         while state.slot < slot:
-            self._process_slot(state)
-            if (state.slot + 1) % self.spec.SLOTS_PER_EPOCH == 0:
-                self.process_epoch_resident(state)
-            state.slot += 1
+            boundary = (state.slot + 1) % self.spec.SLOTS_PER_EPOCH == 0
+            # the root span of everything this slot causes: `req` ties its
+            # descendants together, and its self time is what no child holds
+            with telemetry.span("resident.boundary_slot" if boundary
+                                else "resident.slot", req=int(state.slot)):
+                self._process_slot(state)
+                if boundary:
+                    self.process_epoch_resident(state)
+                state.slot += 1
 
     def _process_slot(self, state) -> None:
         spec = self.spec
-        with telemetry.span("resident.slot_root"):
+        with telemetry.span("resident.slot_root") as sp:
+            hashed0 = bulk.HOST_PAIRS_HASHED.value
+            zero0 = bulk.HOST_PAIRS_ZERO_FILLED.value
             root = self._state_root(state)
+            sp.note(pairs_hashed=bulk.HOST_PAIRS_HASHED.value - hashed0,
+                    pairs_zero_filled=bulk.HOST_PAIRS_ZERO_FILLED.value - zero0)
         state.latest_state_roots[state.slot % spec.SLOTS_PER_HISTORICAL_ROOT] = root
         if state.latest_block_header.state_root == spec.ZERO_HASH:
             state.latest_block_header.state_root = root
@@ -832,10 +876,13 @@ class ResidentCore:
 
     def process_epoch_resident(self, state) -> None:
         """The boundary transition on resident columns, under telemetry
-        spans ("resident.stage" — host distillation off the mirrors,
+        spans ("resident.stage" — host distillation off the mirrors
+        (".distill") and the wait for its uploads (".upload"),
         "resident.device" — the epoch program on resident columns,
-        "resident.refresh" — mirror download + root recompute +
-        byte-rooted final updates). self.timings keeps the historical
+        "resident.refresh" — scalars, report and mirror columns down
+        (".download"), byte-rooted final updates (".final_updates") and
+        the forest rebuild ("resident.forests")). self.timings keeps the
+        historical
         {"stage", "device", "refresh"} view, now derived from the spans
         (zeros under CSTPU_TELEMETRY=0). The retrace and re-layout
         watchdogs cover the dispatch: the epoch program must neither
@@ -843,17 +890,19 @@ class ResidentCore:
         boundaries."""
         spec = self.spec
         with telemetry.span("resident.stage") as sp_stage:
-            current_epoch = spec.get_current_epoch(state)
-            previous_epoch = spec.get_previous_epoch(state)
-            ctx = build_epoch_context(spec, state, dict(
-                self.mirrors,
-                activation_eligibility_epoch=None,  # unused by the context
-                withdrawable_epoch=None,
-                balance=None))
-            process_crosslinks_vectorized(spec, state, ctx)
-            inp = build_epoch_inputs(spec, state, ctx)
-            scal = scalars_from_state(state)
-            sp_stage.fence(scal, inp)   # uploads land in "resident.stage"
+            with telemetry.span("resident.stage.distill"):
+                current_epoch = spec.get_current_epoch(state)
+                previous_epoch = spec.get_previous_epoch(state)
+                ctx = build_epoch_context(spec, state, dict(
+                    self.mirrors,
+                    activation_eligibility_epoch=None,  # unused by the context
+                    withdrawable_epoch=None,
+                    balance=None))
+                process_crosslinks_vectorized(spec, state, ctx)
+                inp = build_epoch_inputs(spec, state, ctx)
+                scal = scalars_from_state(state)
+            with telemetry.span("resident.stage.upload") as sp_up:
+                sp_up.fence(scal, inp)  # uploads land in "resident.stage"
 
         with telemetry.span("resident.device") as sp_dev:
             # ONE layout key for the chained columns: input and output
@@ -872,24 +921,29 @@ class ResidentCore:
             self._reg_forest = None
             self._bal_forest = None
             self._active_idx_memo.clear()
-            new_scal, report = jax.device_get((dev_scal, dev_report))
-            _apply_justification(spec, state, new_scal, report,
-                                 previous_epoch, current_epoch)
-            state.latest_slashed_balances = [
-                int(x) for x in np.asarray(new_scal.latest_slashed_balances)]
-            state.latest_start_shard = int(new_scal.latest_start_shard)
-            # refresh ONLY the columns host logic reads; slashed never
-            # changes in the epoch program, balances stay device-only (the
-            # [:_v] slice drops the sharded layout's inert padding rows)
-            for f in ("activation_epoch", "exit_epoch", "effective_balance"):
-                self.mirrors[f] = np.asarray(
-                    jax.device_get(getattr(dev_cols, f)))[:self._v]
-            spec.final_updates_byte_rooted(state)   # the resident override
-            # prune attestation-root memo entries the rotation dropped
-            live = {id(a) for a in state.previous_epoch_attestations}
-            live.update(id(a) for a in state.current_epoch_attestations)
-            self._att_root_memo = {k: v for k, v in self._att_root_memo.items()
-                                   if k in live}
+            with telemetry.span("resident.refresh.download"):
+                new_scal, report = jax.device_get((dev_scal, dev_report))
+                # refresh ONLY the columns host logic reads; slashed never
+                # changes in the epoch program, balances stay device-only
+                # (the [:_v] slice drops the sharded layout's inert padding
+                # rows)
+                for f in ("activation_epoch", "exit_epoch",
+                          "effective_balance"):
+                    self.mirrors[f] = np.asarray(
+                        jax.device_get(getattr(dev_cols, f)))[:self._v]
+            with telemetry.span("resident.refresh.final_updates"):
+                _apply_justification(spec, state, new_scal, report,
+                                     previous_epoch, current_epoch)
+                state.latest_slashed_balances = [
+                    int(x)
+                    for x in np.asarray(new_scal.latest_slashed_balances)]
+                state.latest_start_shard = int(new_scal.latest_start_shard)
+                spec.final_updates_byte_rooted(state)  # the resident override
+                # prune attestation-root memo entries the rotation dropped
+                live = {id(a) for a in state.previous_epoch_attestations}
+                live.update(id(a) for a in state.current_epoch_attestations)
+                self._att_root_memo = {
+                    k: v for k, v in self._att_root_memo.items() if k in live}
             self._registry_balances_roots()      # recompute + cache the roots
         self.timings = {"stage": sp_stage.duration, "device": sp_dev.duration,
                         "refresh": sp_ref.duration}

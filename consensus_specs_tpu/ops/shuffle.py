@@ -92,7 +92,8 @@ def _shuffle_rounds(seed_words: jnp.ndarray, pivots: jnp.ndarray, n: int, rounds
 
     Returns perm [n] int32 with perm[p] = image of index p under the shuffle.
     """
-    bits = _round_bits(seed_words, n, rounds, jnp.bool_)
+    with jax.named_scope("shuffle_round_bits"):
+        bits = _round_bits(seed_words, n, rounds, jnp.bool_)
     pos = jnp.arange(n, dtype=jnp.int32)
     C0 = pos
 
@@ -110,7 +111,8 @@ def _shuffle_rounds(seed_words: jnp.ndarray, pivots: jnp.ndarray, n: int, rounds
         bit_at_max = jnp.where(pos >= flip, bits_r, bits_flip)
         return jnp.where(bit_at_max, C_flip, C)
 
-    return jax.lax.fori_loop(0, rounds, body, C0)
+    with jax.named_scope("shuffle_rounds"):
+        return jax.lax.fori_loop(0, rounds, body, C0)
 
 
 @partial(jax.jit, static_argnames=("n", "rounds"))

@@ -4,9 +4,11 @@ The one coherent observability layer for the serving loop (ISSUE 8):
 
     from consensus_specs_tpu import telemetry
 
-    with telemetry.span("epoch.device") as sp:
-        out = program(args)
-        sp.fence(out)                       # materialized at exit only
+    with telemetry.span("resident.slot", req=slot):     # a root: sets `req`
+        with telemetry.span("epoch.device") as sp:      # inherits it
+            out = program(args)
+            sp.fence(out)                   # materialized at exit only
+            sp.note(lanes=n)                # a work count on the record
     telemetry.counter("fq.redc.lanes").inc(n)
     telemetry.snapshot()                    # dict for bench JSON rows
     telemetry.prometheus_text()             # BeaconNodeAPI.get_metrics()
@@ -17,21 +19,34 @@ Env knobs: CSTPU_TELEMETRY (default on; 0 = every span/metric a no-op),
 CSTPU_TELEMETRY_FENCE (default on; 0 = spans never fence at exit),
 CSTPU_TELEMETRY_RING (span ring-buffer size, default 4096).
 
+Every ring record has `name, ts, dur, depth, parent, tid, args` and the
+span's identity: a process-unique `id`, its parent's `parent_id` (0 at a
+root) and the inherited request key `req`. Once jax is loaded, a span is
+also a `jax.profiler.TraceAnnotation` of the same name, so a profiler
+session shows the program's spans in its `/host:CPU` plane on the device
+trace's clock (core.py binds it lazily; the package never imports jax).
+
 Naming scheme (dot-separated `subsystem.stage`): spans `epoch.*`
-(process_epoch_soa stages), `resident.*` (the resident serving loop),
+(process_epoch_soa stages), `resident.*` (the resident serving loop: the
+roots `resident.slot` / `resident.boundary_slot` (`req` = the slot) over
+`resident.slot_root` with its groups `.forests .attestations .history
+.small .merkleize`, and at a boundary `resident.stage` (`.distill
+.upload`), `resident.device`, `resident.refresh` (`.download
+.final_updates`) and `resident.forests`; `resident.checkpoint_write`
+(`.download .assemble`) and `resident.restore` (`.decode .upload`)),
 `firehose.*` (streaming-verifier pipeline stages: stage/dispatch/flush,
 exit-only fences), `bench.*` / `followup.*` (harnesses); counters
 `fq.redc.*` (trace-time REDC accounting), `merkle.forest.*` (pair-hash
-lanes/launches/builds), `scalar_mul.*`, `bls.grouped.*` (grouped-pairing
+lanes/launches/builds), `merkle.host.*` (pairs hashed / taken from the
+zero-hash table by the host Merkleizer), `scalar_mul.*`, `bls.grouped.*` (grouped-pairing
 launch occupancy), `firehose.*` (queue depth / batch occupancy /
 deadline misses — always-on: /healthz reads them), `watchdog.*`
 (retrace/re-layout events), `jax.backend_compiles` (global compile
 listener).
 """
-from .core import (Counter, Gauge, Histogram, Span, counter, current_span,
-                   enabled, fencing, gauge, histogram, instrument, reset,
-                   ring, set_enabled, set_fencing, snapshot, span,
-                   span_seconds)
+from .core import (Counter, Gauge, Histogram, Span, counter, enabled,
+                   fencing, gauge, histogram, reset, ring, set_enabled,
+                   set_fencing, snapshot, span)
 from .export import (chrome_trace, dump_chrome_trace, dump_prometheus,
                      prometheus_text, write_jsonl)
 from . import watchdog
@@ -39,9 +54,8 @@ from .watchdog import TelemetryWarning
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Span", "TelemetryWarning",
-    "chrome_trace", "counter", "current_span", "dump_chrome_trace",
-    "dump_prometheus", "enabled", "fencing", "gauge", "histogram",
-    "instrument", "prometheus_text", "reset", "ring", "set_enabled",
-    "set_fencing", "snapshot", "span", "span_seconds", "watchdog",
+    "chrome_trace", "counter", "dump_chrome_trace", "dump_prometheus",
+    "enabled", "fencing", "gauge", "histogram", "prometheus_text", "reset",
+    "ring", "set_enabled", "set_fencing", "snapshot", "span", "watchdog",
     "write_jsonl",
 ]

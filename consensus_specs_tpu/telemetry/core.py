@@ -13,8 +13,8 @@ Contract:
     return a shared no-op singleton (no `perf_counter` call, no ring
     write) and turns every counter/gauge/histogram mutation into an
     early return (`tests/test_telemetry.py` asserts the bound). The
-    default is ON: spans cost two `perf_counter` reads and one deque
-    append.
+    default is ON: spans cost two `perf_counter` reads, one deque
+    append and one profiler TraceMe.
   * **fencing at span exit only** — a span never fences between the
     statements it wraps (async dispatch must not be perturbed); outputs
     registered via `Span.fence(tree)` are materialized (one element per
@@ -22,22 +22,33 @@ Contract:
     recorded wall time covers the device work the region dispatched.
     `CSTPU_TELEMETRY_FENCE=0` disables the exit fences (dispatch-only
     timing).
-  * **nesting** — spans thread a per-thread parent/child stack; the ring
-    buffer (`CSTPU_TELEMETRY_RING` entries, default 4096) keeps the most
-    recent finished spans for Chrome-trace export (export.py), and a
-    per-name aggregate (count / total / last) survives ring eviction for
-    `snapshot()` / Prometheus.
+  * **nesting and identity** — spans thread a per-thread parent/child
+    stack; every span has a process-unique `id`, its parent's
+    `parent_id` (0 at a root) and a request key `req` that a root sets
+    (`span("resident.slot", req=slot)`) and every descendant inherits,
+    so the spans of one slot are one tree and self time can be
+    computed. The ring buffer (`CSTPU_TELEMETRY_RING` entries, default
+    4096) keeps the most recent finished spans for Chrome-trace export
+    (export.py), and a per-name aggregate (count / total / last)
+    survives ring eviction for `snapshot()` / Prometheus.
+  * **one clock with the device trace** — once something else has
+    imported jax, a span also opens a `jax.profiler.TraceAnnotation` of
+    its own name for its extent, so an open profiler session holds the
+    program's spans in its `/host:CPU` plane beside the device's
+    operations. With no session open that is one TraceMe construction.
 
-This module is stdlib-only (numpy imported lazily inside the fence): it
-must stay importable from `ops/fq.py` and the analyzer fixtures without
-dragging jax in.
+This module is stdlib-only (numpy imported lazily inside the fence, the
+profiler looked up in `sys.modules`, never imported): it must stay
+importable from `ops/fq.py` and the analyzer fixtures without dragging
+jax in.
 """
 from __future__ import annotations
 
 import collections
-import functools
+import itertools
 import math as _math
 import os
+import sys
 import threading
 import time
 from typing import Dict, Iterator, List, Optional
@@ -97,6 +108,20 @@ _ring: collections.deque = collections.deque(maxlen=_RING_MAX)
 _span_agg: Dict[str, List] = {}
 _tls = threading.local()
 _lock = threading.Lock()
+_span_ids = itertools.count(1)   # next() is atomic under the GIL
+_annotation = None               # jax.profiler.TraceAnnotation, once bound
+
+
+def _annotation_factory():
+    """`jax.profiler.TraceAnnotation` once some other module has imported
+    jax, else None: a process that never loaded jax has no profiler
+    session for a span to appear in, and this module must not load it."""
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
 
 
 def _stack() -> list:
@@ -141,19 +166,25 @@ class Span:
             sp.fence(out)           # materialized at exit, never inside
         sp.duration                 # seconds
 
-    Or as a decorator through `telemetry.instrument("name")`.
+    `req` is the request key of a root span (the slot, the cycle); a
+    span opened without one inherits its parent's.
     """
 
-    __slots__ = ("name", "args", "t0", "dur", "_depth", "_parent", "_fenced")
+    __slots__ = ("name", "args", "t0", "dur", "id", "parent_id", "req",
+                 "_depth", "_parent", "_fenced", "_note")
 
-    def __init__(self, name: str, args: Optional[dict] = None):
+    def __init__(self, name: str, args: Optional[dict] = None, req=None):
         self.name = name
         self.args = args or {}
         self.t0 = 0.0
         self.dur = 0.0
+        self.id = next(_span_ids)
+        self.parent_id = 0
+        self.req = req
         self._depth = 0
         self._parent = ""
         self._fenced: list = []
+        self._note = None
 
     # -- annotations --------------------------------------------------------
 
@@ -176,9 +207,18 @@ class Span:
 
     def __enter__(self) -> "Span":
         stack = _stack()
-        self._parent = stack[-1].name if stack else ""
+        if stack:
+            parent = stack[-1]
+            self._parent = parent.name
+            self.parent_id = parent.id
+            if self.req is None:
+                self.req = parent.req
         self._depth = len(stack)
         stack.append(self)
+        factory = _annotation_factory()
+        if factory is not None:
+            self._note = factory(self.name)
+            self._note.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -189,6 +229,9 @@ class Span:
         if exc_type is None and self._fenced and fencing():
             _materialize(self._fenced)
         self.dur = time.perf_counter() - self.t0
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+            self._note = None
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -210,6 +253,9 @@ class Span:
                 "parent": self._parent,
                 "tid": threading.get_ident(),
                 "args": dict(self.args) if self.args else None,
+                "id": self.id,
+                "parent_id": self.parent_id,
+                "req": self.req,
             })
         return False
 
@@ -240,31 +286,15 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-def span(name: str, **args):
+def span(name: str, *, req=None, **args):
     """A context-managed span named `name` (dot-separated scheme:
     `subsystem.stage`, e.g. "epoch.device", "resident.slot_root").
-    Returns the shared no-op singleton when telemetry is off."""
+    `req` is a field of the record (the request key a root sets and its
+    descendants inherit), never one of the free-form `args` noted on the
+    span. Returns the shared no-op singleton when telemetry is off."""
     if not enabled():
         return _NULL_SPAN
-    return Span(name, args or None)
-
-
-def instrument(name: str, **args):
-    """Decorator form of `span` — the on/off check happens per call, so
-    functions decorated at import respect later `set_enabled` flips."""
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            with span(name, **args):
-                return fn(*a, **kw)
-        return wrapper
-    return deco
-
-
-def current_span():
-    """The innermost open span on this thread (None outside any span)."""
-    stack = _stack()
-    return stack[-1] if stack else None
+    return Span(name, args or None, req)
 
 
 # ---------------------------------------------------------------------------
@@ -416,15 +446,6 @@ def _snapshot_locked() -> dict:
             for n, a in sorted(_span_agg.items())
         },
     }
-
-
-def span_seconds(name: str, which: str = "last") -> float:
-    """Aggregate lookup: seconds of the `last` (default) or `total` time
-    recorded under a span name; 0.0 when the name never closed."""
-    agg = _span_agg.get(name)
-    if agg is None:
-        return 0.0
-    return agg[1] if which == "total" else agg[2]
 
 
 def reset() -> None:

@@ -62,8 +62,8 @@ def chrome_trace() -> dict:
         args = dict(rec["args"] or {})
         if rec["parent"]:
             args["parent"] = rec["parent"]
-        if args:
-            event["args"] = args
+        args.update(id=rec["id"], parent_id=rec["parent_id"], req=rec["req"])
+        event["args"] = args
         events.append(event)
     events.sort(key=lambda e: e["ts"])
     return {"traceEvents": events, "displayTimeUnit": "ms"}

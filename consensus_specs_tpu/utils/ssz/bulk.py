@@ -31,6 +31,7 @@ from typing import Any, Dict, Optional, Sequence
 import numpy as np
 
 from ..hash import ZERO_BYTES32, zerohashes
+from ...telemetry import counter as _tele_counter
 from . import impl
 from .typing import (
     is_bool_type, is_bytesn_type, is_container_type, is_list_kind,
@@ -42,6 +43,12 @@ from .typing import (
 # host round trip PER LEVEL; the chatty-free
 # alternative for production roots is the one-program device path below
 _DEVICE_MIN_PAIRS = 1 << 15
+
+# The host Merkleizer's work: SHA-256 pair hashes that hashlib really ran
+# (a level filled with one pair runs one) and pairs whose parent came from
+# the zero-hash table. Bumped once a call, never once a pair.
+HOST_PAIRS_HASHED = _tele_counter("merkle.host.pairs_hashed")
+HOST_PAIRS_ZERO_FILLED = _tele_counter("merkle.host.pairs_zero_filled")
 
 
 # ---------------------------------------------------------------------------
@@ -72,10 +79,12 @@ def hash_pairs_array(pairs: np.ndarray) -> np.ndarray:
     # an all-identical level (a vector filled with one root, e.g. the
     # genesis active-index roots) hashes once — O(n) check, no sort
     if n >= 64 and (pairs == pairs[0]).all():
+        HOST_PAIRS_HASHED.inc(1)
         row = np.frombuffer(sha(pairs[0].tobytes()).digest(), np.uint8)
         out = np.empty((n, 32), dtype=np.uint8)
         out[:] = row
         return out
+    HOST_PAIRS_HASHED.inc(n)
     buf = pairs.tobytes()
     digests = b"".join(sha(buf[64 * i:64 * i + 64]).digest()
                        for i in range(n))
@@ -141,6 +150,7 @@ def merkleize_chunk_array(chunks: np.ndarray) -> bytes:
             return hit
     level = np.ascontiguousarray(chunks)
     depth = 0
+    zero_filled = 0
     while level.shape[0] > 1:
         if level.shape[0] % 2 == 1:
             level = np.concatenate([level, _zero_chunk_rows(1, depth)])
@@ -150,9 +160,12 @@ def merkleize_chunk_array(chunks: np.ndarray) -> bytes:
         depth += 1
         nxt = np.empty((pairs.shape[0], 32), dtype=np.uint8)
         nxt[:] = np.frombuffer(zerohashes[depth], np.uint8)
-        if nonzero.any():
+        to_hash = int(np.count_nonzero(nonzero))
+        zero_filled += pairs.shape[0] - to_hash
+        if to_hash:
             nxt[nonzero] = hash_pairs_array(pairs[nonzero])
         level = nxt
+    HOST_PAIRS_ZERO_FILLED.inc(zero_filled)
     root = level[0].tobytes()
     if key is not None:
         _memo_put("mca", key, root)
@@ -538,26 +551,28 @@ def _registry_leaf_words(pubkeys, wc, act_elig, act, exit_ep, withdrawable,
     """Traced body: SoA validator columns -> [V, 8] per-validator root words
     (the leaves of the registry list tree — the incremental forest builds
     its level 0 from exactly these)."""
+    import jax
     import jax.numpy as jnp
 
     from ...ops.sha256 import sha256_pairs_inner, subtree_roots_words
 
     V = pubkeys.shape[0]
-    # pubkey: Bytes48 -> two chunks -> one pair-hash
-    pk_padded = jnp.concatenate(
-        [pubkeys, jnp.zeros((V, 16), dtype=pubkeys.dtype)], axis=1)
-    pk_root = sha256_pairs_inner(_u8_mat_words(pk_padded))        # [V, 8]
-    leaves = jnp.stack([
-        pk_root,
-        _u8_mat_words(wc),
-        _u64_col_words(act_elig),
-        _u64_col_words(act),
-        _u64_col_words(exit_ep),
-        _u64_col_words(withdrawable),
-        _u64_col_words(slashed.astype(jnp.uint64)),  # bool chunk: byte0 = 0/1
-        _u64_col_words(eff_balance),
-    ], axis=1)                                                    # [V, 8, 8]
-    return subtree_roots_words(leaves)                            # [V, 8]
+    with jax.named_scope("registry_leaves"):
+        # pubkey: Bytes48 -> two chunks -> one pair-hash
+        pk_padded = jnp.concatenate(
+            [pubkeys, jnp.zeros((V, 16), dtype=pubkeys.dtype)], axis=1)
+        pk_root = sha256_pairs_inner(_u8_mat_words(pk_padded))    # [V, 8]
+        leaves = jnp.stack([
+            pk_root,
+            _u8_mat_words(wc),
+            _u64_col_words(act_elig),
+            _u64_col_words(act),
+            _u64_col_words(exit_ep),
+            _u64_col_words(withdrawable),
+            _u64_col_words(slashed.astype(jnp.uint64)),  # bool: byte0 = 0/1
+            _u64_col_words(eff_balance),
+        ], axis=1)                                                # [V, 8, 8]
+        return subtree_roots_words(leaves)                        # [V, 8]
 
 
 def _registry_root_words(pubkeys, wc, act_elig, act, exit_ep, withdrawable,
@@ -579,16 +594,18 @@ def _registry_root_words(pubkeys, wc, act_elig, act, exit_ep, withdrawable,
 def _balances_chunk_words(balances):
     """Traced body: [V] uint64 -> [C, 8] SSZ pack chunk words (4 values per
     32-byte chunk) — level 0 of the balances list tree."""
+    import jax
     import jax.numpy as jnp
 
     V = balances.shape[0]
     pad = (-V) % 4
-    col = balances.astype(jnp.uint64)
-    if pad:
-        col = jnp.concatenate([col, jnp.zeros(pad, dtype=jnp.uint64)])
-    w0 = _bswap32((col & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32))
-    w1 = _bswap32((col >> jnp.uint64(32)).astype(jnp.uint32))
-    return jnp.stack([w0, w1], axis=-1).reshape(-1, 8)            # [C, 8]
+    with jax.named_scope("balances_chunks"):
+        col = balances.astype(jnp.uint64)
+        if pad:
+            col = jnp.concatenate([col, jnp.zeros(pad, dtype=jnp.uint64)])
+        w0 = _bswap32((col & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32))
+        w1 = _bswap32((col >> jnp.uint64(32)).astype(jnp.uint32))
+        return jnp.stack([w0, w1], axis=-1).reshape(-1, 8)        # [C, 8]
 
 
 def _balances_root_words(balances):

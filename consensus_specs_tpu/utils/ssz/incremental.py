@@ -95,7 +95,9 @@ def _build_levels(leaf_words: jnp.ndarray):
         if level.shape[0] % 2:
             level = jnp.concatenate([level, _zero_rows(depth, 1)])
         pairs = level.reshape(-1, 16)
-        level = sha256_pairs_inner(pairs, unroll=_unroll_for(pairs.shape[0]))
+        with jax.named_scope(f"forest_level_{depth}"):
+            level = sha256_pairs_inner(pairs,
+                                       unroll=_unroll_for(pairs.shape[0]))
         levels.append(level)
         depth += 1
     return tuple(levels)

@@ -25,6 +25,11 @@ TINY_V = 8192       # >= the device shuffler's floor: the chip's path
 
 @pytest.fixture
 def run():
+    # the phases print the process-wide resilience and watchdog counters as
+    # they stand; a test file that ran earlier in this worker (the health
+    # endpoint's test degrades the ladder once) must not show up in them
+    from consensus_specs_tpu import telemetry
+    telemetry.reset()
     return chip_smoke.Run(rehearsal=True, seed=7)
 
 

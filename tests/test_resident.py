@@ -406,3 +406,134 @@ def test_from_checkpoint_rejects_phase1_hooks(spec):
         ResidentCore.from_checkpoint(p1, data)
     with pytest.raises(NotImplementedError):
         ResidentCore(p1, state)
+
+
+# -- the span tree of a slot, a boundary, a checkpoint and a resume ------------
+
+SLOT_ROOT_GROUPS = ("forests", "attestations", "history", "small", "merkleize")
+
+
+@pytest.fixture
+def spans():
+    """Telemetry pinned on and emptied; returns a reader of the ring."""
+    from consensus_specs_tpu import telemetry
+    telemetry.set_enabled(True)
+    telemetry.reset()
+    yield telemetry.ring
+    telemetry.set_enabled(None)
+
+
+def _children(records, parent):
+    return [r for r in records if r["parent_id"] == parent["id"]]
+
+
+def test_one_epoch_leaves_one_span_tree_a_slot(spec, spans):
+    """Every slot is one root span (`req` = the slot) over exactly one slot
+    root, whose five groups cover it and never exceed it; the boundary slot
+    holds the slot root, stage, device and refresh; the slot root notes the
+    host Merkleizer's work, equal to the counters' delta."""
+    from consensus_specs_tpu import telemetry
+    spe = spec.SLOTS_PER_EPOCH
+    state = factories.seed_genesis_state(spec, 4 * spe)
+    core = ResidentCore(spec, state, mesh=None)
+    try:
+        hashed = telemetry.counter("merkle.host.pairs_hashed")
+        zeroed = telemetry.counter("merkle.host.pairs_zero_filled")
+        hashed0, zeroed0 = hashed.value, zeroed.value
+        core.process_slots(state, spe - 1)      # slot roots and nothing else
+        hashed1, zeroed1 = hashed.value, zeroed.value
+        core.process_slots(state, spe)          # the boundary slot
+        records = spans()
+    finally:
+        core.exit()
+    roots = [r for r in records if r["parent_id"] == 0]
+    assert [r["name"] for r in roots] == \
+        ["resident.slot"] * (spe - 1) + ["resident.boundary_slot"]
+    assert [r["req"] for r in roots] == list(range(spe))
+    assert all(r["req"] == root["req"] for root in roots
+               for r in _descendants(records, root))
+    noted = {"pairs_hashed": 0, "pairs_zero_filled": 0}
+    for root in roots:
+        kids = _children(records, root)
+        slot_roots = [k for k in kids if k["name"] == "resident.slot_root"]
+        assert len(slot_roots) == 1
+        groups = _children(records, slot_roots[0])
+        assert sorted(g["name"] for g in groups) == sorted(
+            f"resident.slot_root.{g}" for g in SLOT_ROOT_GROUPS)
+        assert sum(g["dur"] for g in groups) <= slot_roots[0]["dur"]
+        assert slot_roots[0]["args"]["pairs_hashed"] > 0
+        if root["name"] == "resident.slot":
+            assert len(kids) == 1
+            for key in noted:
+                noted[key] += slot_roots[0]["args"][key]
+    assert noted == {"pairs_hashed": hashed1 - hashed0,
+                     "pairs_zero_filled": zeroed1 - zeroed0}
+
+    boundary = _children(records, roots[-1])
+    assert [k["name"] for k in boundary] == [
+        "resident.slot_root", "resident.stage", "resident.device",
+        "resident.refresh"]
+    by_name = {k["name"]: k for k in boundary}
+    assert [k["name"] for k in _children(records, by_name["resident.stage"])] \
+        == ["resident.stage.distill", "resident.stage.upload"]
+    assert [k["name"] for k in _children(records, by_name["resident.refresh"])] \
+        == ["resident.refresh.download", "resident.refresh.final_updates",
+            "resident.forests"]
+    assert sum(k["dur"] for k in boundary) <= roots[-1]["dur"]
+    # the forests are built twice in the epoch: at entry, under the first
+    # slot's `slot_root.forests`, and in the boundary's refresh
+    forests = [r for r in records if r["name"] == "resident.forests"]
+    assert [f["parent"] for f in forests] == ["resident.slot_root.forests",
+                                              "resident.refresh"]
+    assert all(f["args"]["pair_lanes"] > 0 for f in forests)
+
+
+def _descendants(records, root):
+    out, frontier = [], [root]
+    while frontier:
+        kids = [r for p in frontier for r in _children(records, p)]
+        out += kids
+        frontier = kids
+    return out
+
+
+def test_checkpoint_round_trip_leaves_its_two_span_trees(spec, spans):
+    state = factories.seed_genesis_state(spec, 4 * spec.SLOTS_PER_EPOCH)
+    core = ResidentCore.from_checkpoint(
+        spec, serialize(state, spec.BeaconState), mesh=None)
+    try:
+        data = core.checkpoint_bytes()
+        root = core._state_root(core.state)     # a root without a slot
+    finally:
+        core._uninstall()
+    assert data == serialize(state, spec.BeaconState)
+    assert root == hash_tree_root(state)
+    records = spans()
+    roots = [r for r in records if r["parent_id"] == 0]
+    assert [r["name"] for r in roots] == [
+        "resident.restore", "resident.checkpoint_write",
+        *(f"resident.slot_root.{g}" for g in SLOT_ROOT_GROUPS)]
+    assert all(r["req"] is None for r in records)
+    restore, write = roots[:2]
+    assert [k["name"] for k in _children(records, restore)] == [
+        "resident.restore.decode", "resident.restore.upload"]
+    assert [k["name"] for k in _children(records, write)] == [
+        "resident.checkpoint_write.download",
+        "resident.checkpoint_write.assemble"]
+    for tree in (restore, write):
+        assert sum(k["dur"] for k in _children(records, tree)) <= tree["dur"]
+    # the resumed core's first root request builds the forests
+    (forests,) = [r for r in records if r["name"] == "resident.forests"]
+    assert forests["parent"] == "resident.slot_root.forests"
+
+
+def test_corrupt_checkpoint_closes_its_spans(spec, spans):
+    from consensus_specs_tpu.resilience.errors import CheckpointCorrupt
+    state = factories.seed_genesis_state(spec, 8)
+    data = serialize(state, spec.BeaconState)
+    with pytest.raises(CheckpointCorrupt):
+        ResidentCore.from_checkpoint(spec, data[:len(data) // 2], mesh=None)
+    assert [r["name"] for r in spans() if r["parent_id"] == 0] \
+        == ["resident.restore"]
+    from consensus_specs_tpu.telemetry import core as telemetry_core
+    assert telemetry_core._stack() == []

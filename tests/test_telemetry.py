@@ -1,6 +1,9 @@
 """Telemetry subsystem gate (consensus_specs_tpu/telemetry/):
 
-  - span nesting, exit-only fencing, decorator form, ring buffer;
+  - span nesting, exit-only fencing, ring buffer; span identity (`id`,
+    `parent_id`, the inherited request key `req`) across nesting and
+    threads; the profiler bridge (`jax.profiler.TraceAnnotation` per span,
+    bound lazily: the package never imports jax);
   - metrics registry (counters/gauges/pow2-bucket histograms), the
     `always=True` trace-time accounting path (fq REDC shims);
   - Prometheus text exposition validity and Chrome-trace JSON schema;
@@ -12,6 +15,9 @@
 """
 import json
 import re
+import subprocess
+import sys
+import threading
 import time
 from copy import deepcopy
 
@@ -66,20 +72,185 @@ def test_span_nesting_ring_and_aggregates():
     snap = T.snapshot()["spans"]
     assert snap["outer"]["count"] == 1
     assert snap["inner"]["last_ms"] == snap["inner"]["total_ms"] > 0
-    assert T.span_seconds("inner") == inner.duration
+    assert snap["inner"]["last_ms"] == round(inner.duration * 1e3, 3)
 
 
-def test_instrument_decorator_respects_runtime_toggle():
-    @T.instrument("deco.fn")
-    def double(a):
-        return a * 2
+# -- identity: id, parent_id, req ---------------------------------------------
 
-    assert double(3) == 6
-    assert T.snapshot()["spans"]["deco.fn"]["count"] == 1
+OLD_RING_KEYS = {"name", "ts", "dur", "depth", "parent", "tid", "args"}
+
+
+def test_ring_record_keeps_its_old_keys_and_gains_identity():
+    with T.span("id.root", req=7, tag="x"):
+        pass
+    (rec,) = T.ring()
+    assert set(rec) == OLD_RING_KEYS | {"id", "parent_id", "req"}
+    assert rec["args"] == {"tag": "x"}      # `req` is a field, not an arg
+    assert rec["req"] == 7 and rec["parent_id"] == 0 and rec["id"] > 0
+
+
+def test_span_ids_are_unique_and_parents_link_by_id():
+    with T.span("id.root", req=41) as root:
+        with T.span("id.child") as child:
+            with T.span("id.grandchild") as grandchild:
+                pass
+        with T.span("id.sibling", req=42) as sibling:   # its own request key
+            with T.span("id.nephew") as nephew:
+                pass
+    with T.span("id.other") as other:
+        pass
+    by_name = {r["name"]: r for r in T.ring()}
+    assert len({r["id"] for r in by_name.values()}) == 6
+    assert by_name["id.root"]["parent_id"] == 0
+    assert by_name["id.child"]["parent_id"] == root.id
+    assert by_name["id.grandchild"]["parent_id"] == child.id
+    assert by_name["id.nephew"]["parent_id"] == sibling.id
+    assert [by_name[n]["req"] for n in (
+        "id.root", "id.child", "id.grandchild", "id.sibling", "id.nephew",
+        "id.other")] == [41, 41, 41, 42, 42, None]
+    assert grandchild.req == 41 and nephew.req == 42 and other.parent_id == 0
+
+
+def test_threads_keep_their_own_span_trees():
+    """A span opened on another thread is a root there: it takes neither the
+    parent nor the request key of what the first thread has open."""
+    n_threads, per_thread = 8, 200
+    go = threading.Barrier(n_threads)
+
+    def work(k):
+        go.wait(timeout=30)
+        for i in range(per_thread):
+            with T.span("thr.root", req=(k, i)):
+                with T.span("thr.child"):
+                    pass
+
+    with T.span("main.open", req="main"):
+        threads = [threading.Thread(target=work, args=(k,))
+                   for k in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    records = T.ring()
+    assert len(records) == 2 * n_threads * per_thread + 1
+    assert len({r["id"] for r in records}) == len(records)
+    roots = {r["id"]: r for r in records if r["name"] == "thr.root"}
+    assert all(r["parent_id"] == 0 and r["req"] != "main"
+               for r in roots.values())
+    for child in (r for r in records if r["name"] == "thr.child"):
+        parent = roots[child["parent_id"]]
+        assert child["req"] == parent["req"] and child["tid"] == parent["tid"]
+
+
+# -- the profiler bridge --------------------------------------------------------
+
+def test_importing_telemetry_leaves_jax_out():
+    """core.py's contract: `ops/fq.py` and the analyzer fixtures import it
+    without dragging jax in; a span there opens no annotation."""
+    code = (
+        "import sys\n"
+        "from consensus_specs_tpu import telemetry as T\n"
+        "with T.span('no.jax', req=1) as sp:\n"
+        "    pass\n"
+        "assert sp._note is None and T.ring()[0]['req'] == 1\n"
+        "assert 'jax' not in sys.modules, 'telemetry imported jax'\n")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+
+
+class _FakeAnnotation:
+    made: list = []
+
+    def __init__(self, name):
+        self.name = name
+        self.events = []
+        _FakeAnnotation.made.append(self)
+
+    def __enter__(self):
+        self.events.append(("enter", time.perf_counter()))
+
+    def __exit__(self, *exc):
+        self.events.append(("exit", time.perf_counter()))
+
+
+@pytest.fixture
+def fake_annotation(monkeypatch):
+    from consensus_specs_tpu.telemetry import core
+    _FakeAnnotation.made = []
+    monkeypatch.setattr(core, "_annotation", _FakeAnnotation)
+    return _FakeAnnotation
+
+
+def test_span_opens_one_annotation_of_its_name_round_the_fence(fake_annotation):
+    leaf = _FakeLeaf()
+    with T.span("note.outer") as sp:
+        with T.span("note.inner"):
+            pass
+        sp.fence(leaf)
+    inner, outer = sorted(fake_annotation.made, key=lambda a: a.name)
+    assert (inner.name, outer.name) == ("note.inner", "note.outer")
+    assert [e for e, _ in outer.events] == ["enter", "exit"]
+    # the fence runs inside both the span and its annotation
+    assert outer.events[0][1] <= leaf.fetched_at[0] <= outer.events[1][1]
+    assert outer.events[0][1] <= inner.events[0][1]
+    assert inner.events[1][1] <= outer.events[1][1]
+
+
+def test_telemetry_off_builds_no_annotation(fake_annotation):
     T.set_enabled(False)
-    assert double(4) == 8          # still runs, nothing recorded
-    T.set_enabled(True)
-    assert T.snapshot()["spans"]["deco.fn"]["count"] == 1
+    with T.span("note.off") as sp:
+        sp.fence(None)
+    assert fake_annotation.made == [] and T.ring() == []
+
+
+def test_span_binds_the_profilers_annotation_once_jax_is_loaded():
+    from consensus_specs_tpu.telemetry import core
+    assert core._annotation_factory() is jax.profiler.TraceAnnotation
+
+
+def test_profiler_session_holds_the_resident_spans(spec, tmp_path):
+    """One clock with the device trace: a short profiler session round a
+    resident epoch at V = 256 leaves the program's spans in the trace's
+    `/host:CPU` plane, read back through the benchmark's own reduction."""
+    from benchmark import reduce
+    from consensus_specs_tpu.models.phase0.resident import ResidentCore
+    spe = spec.SLOTS_PER_EPOCH
+    state = factories.seed_genesis_state(spec, 256)
+    core = ResidentCore(spec, state, mesh=None)
+    try:
+        core.process_slots(state, spe + 1)      # warm: the programs compile
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            with jax.profiler.TraceAnnotation(reduce.WINDOW_ANNOTATION):
+                core.process_slots(state, 2 * spe + 1)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        core.exit()
+    planes = reduce.load(reduce.find_xplane(str(tmp_path)))
+    lo, hi = reduce.window(planes)
+    events = reduce.annotations(planes, prefix="resident.")
+    names = {e.name for e in events}
+    assert names >= {
+        "resident.slot", "resident.boundary_slot", "resident.slot_root",
+        "resident.slot_root.forests", "resident.slot_root.attestations",
+        "resident.slot_root.history", "resident.slot_root.small",
+        "resident.slot_root.merkleize", "resident.stage",
+        "resident.stage.distill", "resident.stage.upload", "resident.device",
+        "resident.refresh", "resident.refresh.download",
+        "resident.refresh.final_updates", "resident.forests"}
+    assert sum(e.name == "resident.slot_root" for e in events) == spe
+    assert all(lo <= e.start_ns and e.end_ns <= hi for e in events)
+    # the trace's extents agree with the ring's durations
+    ring = [r["dur"] for r in T.ring() if r["name"] == "resident.device"]
+    traced = [e.duration_ns / 1e9 for e in events
+              if e.name == "resident.device"]
+    assert traced[-1] == pytest.approx(ring[-1], rel=0.2, abs=2e-4)
 
 
 class _FakeLeaf:
@@ -234,6 +405,9 @@ def test_chrome_trace_schema_and_dump(tmp_path):
     assert [e["ts"] for e in events] == sorted(e["ts"] for e in events)
     child = next(e for e in events if e["name"] == "trace.b")
     assert child["args"]["parent"] == "trace.a" and child["args"]["idx"] == 3
+    parent = next(e for e in events if e["name"] == "trace.a")
+    assert child["args"]["parent_id"] == parent["args"]["id"] > 0
+    assert parent["args"]["parent_id"] == 0 and parent["args"]["req"] is None
     path = tmp_path / "trace.json"
     T.dump_chrome_trace(str(path))
     assert json.loads(path.read_text())["traceEvents"]
