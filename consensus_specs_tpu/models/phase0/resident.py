@@ -19,10 +19,12 @@ small byte-rooted fields. This module makes that story real:
   * per-slot state roots combine the registry/balances roots of two
     device-resident INCREMENTAL Merkle forests (utils/ssz/incremental.py:
     every tree level stays on device, invalidation is per leaf) with the
-    bulk-memoized roots of every other field — the object registry is
-    never materialized for a root, and a registry-mutating block re-hashes
-    only the validators it touched (O(dirty * log V)) instead of forcing
-    the old all-or-nothing registry-scale rebuild.
+    roots of every other field, the wide ones (history and crosslink
+    vectors, attestation lists) kept as persistent HOST trees that take
+    only the leaves a slot wrote (utils/ssz/host_tree.py) — the object
+    registry is never materialized for a root, and a registry-mutating
+    block re-hashes only the validators it touched (O(dirty * log V))
+    instead of forcing the old all-or-nothing registry-scale rebuild.
   * at an epoch boundary the existing distillation machinery
     (build_epoch_context / process_crosslinks_vectorized /
     build_epoch_inputs) runs straight off the mirrors — the object-walk
@@ -61,10 +63,11 @@ from ... import telemetry
 from ...resilience.errors import (CheckpointCorrupt, DispatchError,
                                   FatalDispatchError)
 from ...telemetry import watchdog as _watchdog
-from ...utils.ssz import bulk
+from ...utils.ssz import bulk, host_tree
 from ...utils.ssz import impl as ssz_impl
 from ...utils.ssz.incremental import (IncrementalMerkleTree,
                                       ShardedIncrementalMerkleTree)
+from ...utils.ssz.typing import Vector
 from . import helpers as helpers_mod
 from .epoch_soa import (EpochConfig, ValidatorColumns, build_epoch_context,
                         build_epoch_inputs, columns_np_from_state,
@@ -80,13 +83,26 @@ _ALL_FIELDS = ValidatorColumns._fields
 
 # The state root's fields by the span that times them (_state_root): the
 # two forests and the two attestation lists have paths of their own, the
-# history vectors are where the host hashing grows, the rest is small.
+# history vectors are the widest host fields, the rest is small.
 _ATTESTATION_FIELDS = ("previous_epoch_attestations",
                        "current_epoch_attestations")
 _HISTORY_FIELDS = ("latest_block_roots", "latest_state_roots",
                    "latest_randao_mixes", "latest_active_index_roots",
                    "latest_slashed_balances", "historical_roots")
+_CROSSLINK_FIELDS = ("current_crosslinks", "previous_crosslinks")
+# The fields whose root is kept as a persistent host tree (host_tree.py),
+# by how the tree learns what changed: the attestation lists grow at their
+# end and rotate as objects, the vectors are written an index at a time.
+_TREE_KINDS = {
+    **dict.fromkeys(_ATTESTATION_FIELDS, host_tree.AppendOnlyListTree),
+    **dict.fromkeys(_HISTORY_FIELDS + _CROSSLINK_FIELDS,
+                    host_tree.TrackedSeriesTree)}
 _FOREST_PAIR_LANES = telemetry.counter("merkle.forest.pair_lanes")
+# The host Merkleizer's work that `resident.slot_root` notes on its record.
+_SLOT_ROOT_NOTES = {"pairs_hashed": bulk.HOST_PAIRS_HASHED,
+                    "pairs_zero_filled": bulk.HOST_PAIRS_ZERO_FILLED,
+                    "leaves_updated": host_tree.LEAVES_UPDATED,
+                    "trees_rebuilt": host_tree.TREE_REBUILDS}
 
 # Per-core watchdog key prefix: layout fingerprints must not leak between
 # cores (a mesh core and a single-device core in one test process would
@@ -172,12 +188,7 @@ class ResidentCore:
         self._saved_methods: Dict[str, object] = {}
         self._saved_root_backend = None
         self._active_idx_memo: Dict[int, np.ndarray] = {}
-        # id-keyed PendingAttestation root memo: the lists only ever APPEND
-        # between boundaries (process_attestation :1625-1645) and rotate at
-        # final updates, so per-slot state roots re-merkleize only the new
-        # tail, not the whole epoch's ~2k attestations. Entries keep a
-        # strong ref so an id cannot be recycled while memoized.
-        self._att_root_memo: Dict[int, tuple] = {}
+        self._host_trees: Dict[tuple, object] = {}
         self._light = False
         self._enter(state)
 
@@ -249,7 +260,7 @@ class ResidentCore:
         core._saved_methods = {}
         core._saved_root_backend = None
         core._active_idx_memo = {}
-        core._att_root_memo = {}
+        core._host_trees = {}
         core._light = True
         with telemetry.span("resident.restore.upload") as sp:
             core._enter(state, np_cols=np_cols)
@@ -669,10 +680,13 @@ class ResidentCore:
 
     def _state_root(self, state):
         """Full BeaconState root: device roots for the two registry-scale
-        fields (cached until the columns change), bulk-memoized roots for
-        everything else. Same leaf layout as impl.hash_tree_root. The
-        fields are taken group by group, one span a group, and each root
-        is put at its field's index.
+        fields (cached until the columns change), persistent host trees
+        for the history vectors, the crosslink vectors and the attestation
+        lists (_field_root: only the leaves written since the last root
+        are re-hashed), one-shot bulk roots for the small rest. Same leaf
+        layout as impl.hash_tree_root, every pair of it hashed at some
+        root from the inputs it has now. The fields are taken group by
+        group, one span a group, and each root is put at its field's index.
 
         Declines (-> saved backend / recursive oracle) for any state other
         than the resident one: the device columns describe THIS state only,
@@ -685,45 +699,56 @@ class ResidentCore:
         names = state.get_field_names()
         typed = dict(zip(names, state.get_typed_values()))
         roots = {}
+        live: Dict[tuple, object] = {}
         with telemetry.span("resident.slot_root.forests"):
             roots["validator_registry"], roots["balances"] = \
                 self._registry_balances_roots()
         with telemetry.span("resident.slot_root.attestations"):
             for name in _ATTESTATION_FIELDS:
-                roots[name] = self._att_list_root(*typed[name])
+                roots[name] = self._field_root(state, name, *typed[name], live)
         with telemetry.span("resident.slot_root.history"):
             for name in _HISTORY_FIELDS:
-                roots[name] = bulk.hash_tree_root_bulk(*typed[name])
+                roots[name] = self._field_root(state, name, *typed[name], live)
         with telemetry.span("resident.slot_root.small"):
             for name in names:
                 if name not in roots:
-                    roots[name] = bulk.hash_tree_root_bulk(*typed[name])
+                    roots[name] = self._field_root(
+                        state, name, *typed[name], live)
+        self._host_trees = live     # a tree no field holds any more goes
         with telemetry.span("resident.slot_root.merkleize"):
-            arr = np.stack([np.frombuffer(roots[name], np.uint8)
-                            for name in names])
-            return bulk.merkleize_chunk_array(arr)
+            return bulk.merkleize_few([roots[name] for name in names])
 
-    def _att_list_root(self, atts, typ) -> bytes:
-        """List[PendingAttestation] root with element roots memoized by
-        object identity (append-only lists; same value as
-        bulk.hash_tree_root_bulk's list branch)."""
-        from ...utils.ssz import impl
-        elem_t = typ.elem_type
-        memo = self._att_root_memo
-        if not atts:
-            leaves = np.zeros((0, 32), dtype=np.uint8)
-        else:
-            rows = []
-            for a in atts:
-                ent = memo.get(id(a))
-                if ent is None or ent[0] is not a:
-                    ent = memo[id(a)] = (
-                        a, np.frombuffer(bulk.hash_tree_root_bulk(a, elem_t),
-                                         np.uint8))
-                rows.append(ent[1])
-            leaves = np.stack(rows)
-        return impl.mix_in_length(bulk.merkleize_chunk_array(leaves),
-                                  len(atts))
+    def _field_root(self, state, name, value, typ, live) -> bytes:
+        """One field's root: through its persistent host tree where the
+        field has one (_TREE_KINDS), by the one-shot bulk path otherwise.
+
+        A tree is bound to the list OBJECT it was built on and found by
+        it, so the attestation rotation (`previous = current`) hands the
+        tree over with the list, and a field that holds another object
+        than last slot (a wholesale assignment such as process_crosslinks'
+        `previous_crosslinks = [...]`, a state decoded or copied anew)
+        builds from content. A vector's list is swapped for a
+        host_tree.TrackedList the first time it is seen, so that whoever
+        writes it afterwards (this core, the spec's block code, a test)
+        leaves a record; a caller that kept the plain list it assigned no
+        longer holds the state's field."""
+        kind = _TREE_KINDS.get(name)
+        lst = value.items if isinstance(value, Vector) else value
+        if kind is None or not isinstance(lst, list):
+            return bulk.hash_tree_root_bulk(value, typ)
+        if (kind is host_tree.TrackedSeriesTree
+                and type(lst) is not host_tree.TrackedList):
+            lst = host_tree.TrackedList(lst)
+            if isinstance(value, Vector):
+                value.items = lst
+            else:
+                setattr(state, name, lst)
+        key = (id(lst), typ)
+        tree = self._host_trees.get(key)
+        if tree is None or tree.bound is not lst:
+            tree = kind(lst, typ)
+        live[key] = tree
+        return tree.root()
 
     # -- transition drive ---------------------------------------------------
 
@@ -759,11 +784,10 @@ class ResidentCore:
     def _process_slot(self, state) -> None:
         spec = self.spec
         with telemetry.span("resident.slot_root") as sp:
-            hashed0 = bulk.HOST_PAIRS_HASHED.value
-            zero0 = bulk.HOST_PAIRS_ZERO_FILLED.value
+            before = {k: c.value for k, c in _SLOT_ROOT_NOTES.items()}
             root = self._state_root(state)
-            sp.note(pairs_hashed=bulk.HOST_PAIRS_HASHED.value - hashed0,
-                    pairs_zero_filled=bulk.HOST_PAIRS_ZERO_FILLED.value - zero0)
+            sp.note(**{k: c.value - before[k]
+                       for k, c in _SLOT_ROOT_NOTES.items()})
         state.latest_state_roots[state.slot % spec.SLOTS_PER_HISTORICAL_ROOT] = root
         if state.latest_block_header.state_root == spec.ZERO_HASH:
             state.latest_block_header.state_root = root
@@ -934,16 +958,15 @@ class ResidentCore:
             with telemetry.span("resident.refresh.final_updates"):
                 _apply_justification(spec, state, new_scal, report,
                                      previous_epoch, current_epoch)
-                state.latest_slashed_balances = [
-                    int(x)
-                    for x in np.asarray(new_scal.latest_slashed_balances)]
+                # write the entries that moved (one an epoch): assigning
+                # the vector anew would have its host tree built anew too
+                slashed = state.latest_slashed_balances
+                new = np.asarray(new_scal.latest_slashed_balances, np.uint64)
+                for i in np.nonzero(
+                        new != np.asarray(list(slashed), np.uint64))[0]:
+                    slashed[int(i)] = int(new[i])
                 state.latest_start_shard = int(new_scal.latest_start_shard)
                 spec.final_updates_byte_rooted(state)  # the resident override
-                # prune attestation-root memo entries the rotation dropped
-                live = {id(a) for a in state.previous_epoch_attestations}
-                live.update(id(a) for a in state.current_epoch_attestations)
-                self._att_root_memo = {
-                    k: v for k, v in self._att_root_memo.items() if k in live}
             self._registry_balances_roots()      # recompute + cache the roots
         self.timings = {"stage": sp_stage.duration, "device": sp_dev.duration,
                         "refresh": sp_ref.duration}

@@ -26,6 +26,7 @@ tests/test_bulk_htr.py). `state_root_bulk` is the BeaconState entry point.
 """
 from __future__ import annotations
 
+import hashlib
 from typing import Any, Dict, Optional, Sequence
 
 import numpy as np
@@ -74,7 +75,6 @@ def hash_pairs_array(pairs: np.ndarray) -> np.ndarray:
         # pair_hash_words is the CSTPU_MERKLE_BACKEND switch (XLA vs Pallas)
         digests = pair_hash_words(jnp.asarray(bytes_to_words(padded)))
         return words_to_bytes(np.asarray(digests))[:n]
-    import hashlib
     sha = hashlib.sha256
     # an all-identical level (a vector filled with one root, e.g. the
     # genesis active-index roots) hashes once — O(n) check, no sort
@@ -130,6 +130,25 @@ def _zero_chunk_rows(n: int, depth: int) -> np.ndarray:
     return np.broadcast_to(row, (n, 32))
 
 
+def next_level(level: np.ndarray, depth: int) -> tuple:
+    """One level up from the [n, 32] nodes at `depth`: ([ceil(n/2), 32]
+    parents, pairs filled from the zero-hash table). An odd level takes the
+    zero subtree of its depth as its last sibling. Shared by the one-shot
+    root below and the persistent host tree's build (host_tree.py), so a
+    build costs what a root costs."""
+    if level.shape[0] % 2 == 1:
+        level = np.concatenate([level, _zero_chunk_rows(1, depth)])
+    pairs = level.reshape(-1, 64)
+    zero_pair = np.frombuffer(zerohashes[depth] * 2, dtype=np.uint8)
+    nonzero = ~np.all(pairs == zero_pair, axis=1)
+    nxt = np.empty((pairs.shape[0], 32), dtype=np.uint8)
+    nxt[:] = np.frombuffer(zerohashes[depth + 1], np.uint8)
+    to_hash = int(np.count_nonzero(nonzero))
+    if to_hash:
+        nxt[nonzero] = hash_pairs_array(pairs[nonzero])
+    return nxt, pairs.shape[0] - to_hash
+
+
 def merkleize_chunk_array(chunks: np.ndarray) -> bytes:
     """Root over an [N, 32] uint8 chunk matrix (next-pow2 zero padding),
     identical to merkle.merkleize_chunks on the equivalent byte list.
@@ -152,24 +171,35 @@ def merkleize_chunk_array(chunks: np.ndarray) -> bytes:
     depth = 0
     zero_filled = 0
     while level.shape[0] > 1:
-        if level.shape[0] % 2 == 1:
-            level = np.concatenate([level, _zero_chunk_rows(1, depth)])
-        pairs = level.reshape(-1, 64)
-        zero_pair = np.frombuffer(zerohashes[depth] * 2, dtype=np.uint8)
-        nonzero = ~np.all(pairs == zero_pair, axis=1)
+        level, filled = next_level(level, depth)
+        zero_filled += filled
         depth += 1
-        nxt = np.empty((pairs.shape[0], 32), dtype=np.uint8)
-        nxt[:] = np.frombuffer(zerohashes[depth], np.uint8)
-        to_hash = int(np.count_nonzero(nonzero))
-        zero_filled += pairs.shape[0] - to_hash
-        if to_hash:
-            nxt[nonzero] = hash_pairs_array(pairs[nonzero])
-        level = nxt
     HOST_PAIRS_ZERO_FILLED.inc(zero_filled)
     root = level[0].tobytes()
     if key is not None:
         _memo_put("mca", key, root)
     return root
+
+
+def merkleize_few(chunks: list) -> bytes:
+    """merkle.merkleize_chunks for a handful of 32-byte chunks: one hashlib
+    call a pair and nothing else, counted like the array path. A container
+    of a few fields or a short byte string costs its hashes here; the numpy
+    level pass above costs a dozen array operations a level whatever the
+    width, and only pays for itself from `_MEMO_MIN_CHUNKS` chunks up."""
+    if not chunks:
+        return ZERO_BYTES32
+    sha = hashlib.sha256
+    level, depth, hashed = chunks, 0, 0
+    while len(level) > 1:
+        if len(level) % 2:
+            level = level + [zerohashes[depth]]
+        level = [sha(level[i] + level[i + 1]).digest()
+                 for i in range(0, len(level), 2)]
+        hashed += len(level)
+        depth += 1
+    HOST_PAIRS_HASHED.inc(hashed)
+    return level[0]
 
 
 def subtree_roots_batch(leaves: np.ndarray) -> np.ndarray:
@@ -407,8 +437,11 @@ def hash_tree_root_bulk(obj: Any, typ: Any = None) -> bytes:
         return impl.hash_tree_root(obj)
 
     if impl.is_bottom_layer_kind(typ) and not impl.is_basic_type(typ):
-        chunks = pack_basic_list_chunks(obj, read_elem_type(typ))
-        root = merkleize_chunk_array(chunks)
+        elem = read_elem_type(typ)
+        if len(obj) * impl.fixed_byte_size(elem) < _MEMO_MIN_CHUNKS * 32:
+            root = merkleize_few(impl.chunkify(impl.pack(obj, elem)))
+        else:
+            root = merkleize_chunk_array(pack_basic_list_chunks(obj, elem))
         return impl.mix_in_length(root, len(obj)) if is_list_kind(typ) else root
 
     if is_list_type(typ) or is_vector_type(typ):
@@ -428,10 +461,11 @@ def hash_tree_root_bulk(obj: Any, typ: Any = None) -> bytes:
         return impl.mix_in_length(root, n) if is_list_kind(typ) else root
 
     if is_container_type(typ):
-        leaves = np.stack([
-            np.frombuffer(hash_tree_root_bulk(v, t), np.uint8)
-            for v, t in obj.get_typed_values()])
-        return merkleize_chunk_array(leaves)
+        roots = [hash_tree_root_bulk(v, t) for v, t in obj.get_typed_values()]
+        if len(roots) < _MEMO_MIN_CHUNKS:
+            return merkleize_few(roots)
+        return merkleize_chunk_array(np.stack(
+            [np.frombuffer(r, np.uint8) for r in roots]))
 
     return impl.hash_tree_root(obj, typ)
 

@@ -488,6 +488,76 @@ def test_one_epoch_leaves_one_span_tree_a_slot(spec, spans):
     assert all(f["args"]["pair_lanes"] > 0 for f in forests)
 
 
+def test_slot_root_rehashes_only_what_was_written(spec, spans):
+    """Two epochs and more of attestation-carrying blocks on a full core
+    (rotation included; one block slashes a proposer, so it takes the
+    fallback and writes a slashed balance through the spec's own code):
+    every slot's recorded root is the object model's, and between
+    boundaries a root rebuilds no host tree and re-hashes exactly the
+    leaves written since the last one: the slot's state root and block
+    root, and what a block wrote (a randao mix, an attestation)."""
+    spe = spec.SLOTS_PER_EPOCH
+    state = factories.seed_genesis_state(spec, 4 * spe)
+    factories.advance_slots(spec, state, 2)
+    ref, res = deepcopy(state), deepcopy(state)
+    core = ResidentCore(spec, res, mesh=None)
+    block_leaves = {}
+    try:
+        for i in range(2 * spe + 3):
+            slashes = i == spe + 1
+            with core.suspended():
+                block = _attestation_block(spec, ref)
+                if slashes:
+                    block.body.proposer_slashings.append(
+                        factories.double_proposal(spec, ref))
+                spec.process_slots(ref, block.slot)
+                spec.process_block(ref, block)
+            core.state_transition(res, block)
+            block_leaves[int(block.slot)] = 2 + slashes
+            assert list(res.latest_state_roots) == list(ref.latest_state_roots)
+        assert hash_tree_root(ref) == core._state_root(res)
+        records = spans()
+    finally:
+        core.exit()
+    assert serialize(ref, spec.BeaconState) == serialize(res, spec.BeaconState)
+    assert any(v.slashed for v in ref.validator_registry)
+    noted = [(r["req"], r["args"]) for r in records
+             if r["name"] == "resident.slot_root"]
+    assert len(noted) == int(ref.slot) - int(state.slot) > 2 * spe
+    assert all(set(args) == {"pairs_hashed", "pairs_zero_filled",
+                             "leaves_updated", "trees_rebuilt"}
+               for _, args in noted)
+    assert noted[0][1]["trees_rebuilt"] == 10       # the core's first root
+    for slot, args in noted[1:]:
+        if slot % spe == 0:
+            # the boundary assigned previous_crosslinks and started a new
+            # current attestation list; the old one's tree went to previous
+            assert args["trees_rebuilt"] == 2
+            continue
+        assert args["trees_rebuilt"] == 0, slot
+        assert args["leaves_updated"] == 2 + block_leaves.get(slot, 0), slot
+
+
+def test_checkpoint_of_a_tracked_state_is_the_plain_one(spec):
+    """Once a root has been taken the state's vectors hold tracked lists;
+    the checkpoint serialises them as the lists they are."""
+    from consensus_specs_tpu.utils.ssz.host_tree import TrackedList
+    state = factories.seed_genesis_state(spec, 4 * spec.SLOTS_PER_EPOCH)
+    factories.advance_slots(spec, state, 2)
+    ref = deepcopy(state)
+    core = ResidentCore.from_checkpoint(
+        spec, serialize(state, spec.BeaconState), mesh=None)
+    try:
+        with core.suspended():
+            spec.process_slots(ref, ref.slot + 3)
+        core.process_slots(core.state, core.state.slot + 3)
+        assert type(core.state.latest_state_roots.items) is TrackedList
+        assert type(core.state.current_crosslinks.items) is TrackedList
+        assert core.checkpoint_bytes() == serialize(ref, spec.BeaconState)
+    finally:
+        core._uninstall()
+
+
 def _descendants(records, root):
     out, frontier = [], [root]
     while frontier:
