@@ -46,7 +46,8 @@ class Deployment:
         data = reference.seeded_checkpoint(spec, self.validators, seed)
         self.timings["state_build_s"] = time.perf_counter() - t0
         t0 = time.perf_counter()
-        self.core = ResidentCore.from_checkpoint(spec, data, mesh=None)
+        self.mesh = serving_mesh(int(config["chips"]))
+        self.core = ResidentCore.from_checkpoint(spec, data, mesh=self.mesh)
         self.timings["enter_s"] = time.perf_counter() - t0
         self.state = self.core.state
         self._lay = None
@@ -139,6 +140,19 @@ class Deployment:
     def close(self) -> None:
         self.core._uninstall()
         self.spec.clear_caches()
+
+
+def serving_mesh(chips: int):
+    """Where the configuration's file places the core: on one chip with no
+    mesh, on more with the validator axis over the first `chips` devices."""
+    from consensus_specs_tpu.parallel.sharding import ServingMesh
+    if chips == 1:
+        return None
+    if chips < 1 or chips & (chips - 1):
+        raise SystemExit(
+            f"benchmark: a serving mesh takes a power of two of chips, the "
+            f"configuration states {chips}")
+    return ServingMesh.create(chips)
 
 
 def _bytes_differing(got: bytes, want: bytes) -> int:

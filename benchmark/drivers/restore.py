@@ -35,7 +35,8 @@ class Driver(Base):
             data = dep.core.checkpoint_bytes()
         t1 = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench.restore.enter"):
-            resumed = ResidentCore.from_checkpoint(dep.spec, data, mesh=None)
+            resumed = ResidentCore.from_checkpoint(
+                dep.spec, data, mesh=dep.mesh)
             try:
                 roots = (*resumed._registry_balances_roots(),
                          resumed._state_root(resumed.state))

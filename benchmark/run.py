@@ -5,11 +5,25 @@
 
 A cell is `<config>.<mix>` of BENCHMARK.json. Everything that belongs to one
 configuration, one mix, one driver, one per-layer metric or one kind of
-reader is a file of its own, found by name:
+reader is a file of its own, found by name, so each is added by new files
+and new entries; no file that is there is edited, and of BENCHMARK.json's
+entries only the `workloads` lists of metrics grow:
 
-    configs/<config>.json   traffic/<mix>.json   drivers/<driver>.py
-    layer_metrics/<metric>.json   sources/<kind>.py   costs/<program>.py
-    presets/<preset>.json (the constants the plain references read)
+    configuration  configs/<config>.json (sizes, `chips`, `preset`, guarantees) + an entry in `configs`;
+                   a new preset brings presets/<preset>.json, the constants the plain references read
+    cell           an entry in `workloads` naming a configuration and a mix, with the file's `chips`,
+                   AND its name appended to the `workloads` list of every end-to-end metric it reports
+                   and every per-layer metric it reads: a cell inherits nothing from its mix
+    mix            traffic/<mix>.json, naming its `driver`; a new driver is drivers/<driver>.py
+    per-layer      layer_metrics/<metric>.json (the reader, `what`) + an entry in `per_layer` equal to it
+    metric         key for key, whose `workloads` (in the entry alone) lists the cells that read it
+    reader kind    sources/<kind>.py (`read(reader, seen)`; a roofline's bytes in costs/<program>.py),
+                   with a synthetic record in tests/benchmark/reader_records/<kind>.py
+
+Which cells report a metric is written once, in BENCHMARK.json, where the
+driver reads it: an end-to-end metric with no list is every cell's, a
+per-layer entry with no list is refused, and so is one that lists a cell
+which does not report what it `moves`.
 
 Set-up (backend, state from the seed, residency, warm-up) is `setup_s`; the
 window measures for --seconds; the comparisons that decide `correct` (at
@@ -51,10 +65,12 @@ def load_json(path: Path) -> dict:
 
 
 class Cell:
-    """One entry of BENCHMARK.json's `workloads`, resolved to its files."""
+    """One entry of BENCHMARK.json's `workloads`, resolved to its files
+    under `root` (the checkout; the tests resolve a copy with a cell added)."""
 
-    def __init__(self, name: str):
-        bench = load_json(ROOT / "BENCHMARK.json")
+    def __init__(self, name: str, root: Path = ROOT):
+        bench = load_json(root / "BENCHMARK.json")
+        data = root / HERE.relative_to(ROOT)
         rows = [w for w in bench["workloads"] if w["name"] == name]
         if not rows:
             raise SystemExit(
@@ -65,15 +81,30 @@ class Cell:
         self.chips = int(self.row["chips"])
         config_row = next(c for c in bench["configs"]
                           if c["name"] == self.row["config"])
-        self.config = load_json(ROOT / config_row["file"])
-        self.mix = load_json(HERE / "traffic" / f"{self.row['traffic']}.json")
+        self.config = load_json(root / config_row["file"])
+        if int(self.config["chips"]) != self.chips:
+            raise SystemExit(
+                f"benchmark: workload {name!r} asks for {self.chips} chip(s), "
+                f"{config_row['file']} lays the deployment out on "
+                f"{self.config['chips']}: the file places the core")
+        self.mix = load_json(data / "traffic" / f"{self.row['traffic']}.json")
         self.end_to_end = [m for m in bench["end_to_end"]
                            if name in m.get("workloads", [name])]
         reported = {m["name"] for m in self.end_to_end}
-        self.per_layer = [
-            load_json(HERE / "layer_metrics" / f"{m['name']}.json")
-            for m in bench["per_layer"]
-            if m["moves"] in reported and name in m.get("workloads", [name])]
+        self.per_layer = []
+        for m in bench["per_layer"]:
+            if "workloads" not in m:
+                raise SystemExit(
+                    f"benchmark: per-layer metric {m['name']!r} lists no "
+                    f"`workloads`: an entry names the cells that read it")
+            if name not in m["workloads"]:
+                continue
+            if m["moves"] not in reported:
+                raise SystemExit(
+                    f"benchmark: per-layer metric {m['name']!r} lists "
+                    f"{name!r}, which does not report {m['moves']!r}")
+            self.per_layer.append(
+                load_json(data / "layer_metrics" / f"{m['name']}.json"))
 
     def driver(self):
         return importlib.import_module(
@@ -272,8 +303,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, device: dict,
     guard_events = sum(abs(v) for v in guards.values())
     attempted = int(driver.attempted)
     failed = attempted if guard_events else int(driver.failed)
-    for c in compared:
+    for c in compared:      # on standard error too: the driver keeps its end
         say(compared=c.name, got=c.got, limit=c.limit, ok=c.ok)
+        print(f"compared {c.name}: got {c.got}, limit {c.limit}, "
+              f"{'ok' if c.ok else 'NOT OK'}", file=sys.stderr, flush=True)
     correct = all(c.ok for c in compared) and failed == 0 and attempted > 0
 
     values = dict(driver.values, compiles_in_window=compiles_in_window)

@@ -9,8 +9,14 @@ hand-built trace, that the plain references (hashlib SSZ, numpy epoch,
 swap-or-not shuffle) agree with the package where the package is sound, and
 that `correct` comes out false for the controls (one Gwei on the device, one
 attestation dropped before the boundary) and for a timed path broken
-underneath. No timing read here means anything; no test describes a TPU
-topology.
+underneath, and that a four-chip configuration, its cells and a per-layer
+metric added to a copy of the benchmark (new files, new entries, and the
+cells' names appended to the `workloads` lists of the metrics they report
+and read: no other edit) run on four of this process's virtual devices at a
+size the mesh has to pad, sound and under control 1. What a line must hold
+comes from the cell (`_check_line`), so a PR that adds a cell or a metric
+edits nothing here. No timing read here means anything; no test describes a
+TPU topology.
 """
 import json
 import os
@@ -66,16 +72,18 @@ def test_config_file_states_the_deployment(config):
     body = json.loads((REPO / config["file"]).read_text())
     assert body["source"] == config["source"] and len(config["source"]) <= 200
     assert body["reduced"] == config["reduced"]
-    assert body["preset"] == "mainnet" and body["chips"] == 1
+    assert (REPO / "benchmark" / "presets" / f"{body['preset']}.json").is_file()
     assert body["guarantees"] and body["assumed"]
-    assert any(w["config"] == config["name"] for w in BENCH["workloads"])
+    cells = [w for w in BENCH["workloads"] if w["config"] == config["name"]]
+    # the file places the core; its cells ask the driver for as many chips
+    assert cells and {w["chips"] for w in cells} == {body["chips"]} <= {1, 4}
 
 
 @pytest.mark.parametrize("name", CELLS)
 def test_cell_resolves_to_config_mix_driver_and_metric_files(name):
     cell = run.Cell(name)
     assert cell.name == f"{cell.row['config']}.{cell.row['traffic']}"
-    assert cell.chips == 1 and len(cell.row["why"]) <= 200
+    assert cell.chips == cell.config["chips"] and len(cell.row["why"]) <= 200
     assert cell.config["validators"] >= 300_000
     assert callable(cell.driver())
     reported = {m["name"] for m in cell.end_to_end}
@@ -93,7 +101,11 @@ def test_per_layer_entry_is_its_file(entry):
                        / f"{entry['name']}.json").read_text())
     assert set(entry) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-    assert {k: body[k] for k in entry} == entry
+    # the file holds the reader; which cells read it is BENCHMARK.json's to
+    # say, where the driver looks, and is written nowhere else
+    assert "workloads" not in body and body["reader"]["kind"] and body["what"]
+    assert {k: body[k] for k in entry if k != "workloads"} \
+        == {k: v for k, v in entry.items() if k != "workloads"}
     assert entry["source"] in ("device_trace", "program_span",
                                "program_counter", "host_clock")
     assert entry["moves"] in {m["name"] for m in BENCH["end_to_end"]}
@@ -160,12 +172,19 @@ def drive(monkeypatch, capsys):
     monkeypatch.setattr(reference, "oracle_small", lambda: [])
     monkeypatch.setattr(run, "peaks_for", lambda kind: V5E)
 
-    def _drive(name, *, trace, seconds=0.5):
+    def _drive(name, *, trace, seconds=0.5, root=REPO, validators=TINY_V):
+        cell = run.Cell(name, root)
         result = run.run_cell(
-            run.Cell(name), SEED, seconds, trace,
-            run.describe_device(jax.devices()[:1]), validators=TINY_V)
-        rows = [json.loads(line)
-                for line in capsys.readouterr().out.splitlines()]
+            cell, SEED, seconds, trace,
+            run.describe_device(jax.devices()[:cell.chips]),
+            validators=validators)
+        said = capsys.readouterr()
+        rows = [json.loads(line) for line in said.out.splitlines()]
+        # each number compared stands beside its limit on standard error too
+        assert [l.split()[1].rstrip(":") for l in said.err.splitlines()
+                if l.startswith("compared ")] \
+            == [r["compared"] for r in rows if "compared" in r] != []
+        _check_line(result, cell, trace)
         return result, rows
     return _drive
 
@@ -203,12 +222,18 @@ def drop_one_attestation_at_the_boundary(driver):
     core.process_epoch_resident = dropped
 
 
-def _check_line(result, metrics):
+def _check_line(result, cell, trace):
+    """The contract's keys, and the cell's own metrics: its end-to-end ones
+    untraced; traced on this host backend its per-layer ones but those the
+    device's trace gives, whose readers find no device plane."""
     assert set(result) - {"breakdown"} == {"correct", "attempted", "failed",
                                            "metrics", "device"}
     assert set(result["device"]) >= {"platform", "kind", "count",
                                      "memory_peak_bytes"}
-    assert set(result["metrics"]) == set(metrics)
+    assert result["device"]["count"] == cell.chips
+    metrics = [m for m in cell.per_layer if m["source"] != "device_trace"] \
+        if trace else cell.end_to_end
+    assert set(result["metrics"]) == {m["name"] for m in metrics}
     for m in result["metrics"].values():
         assert set(m) == {"value", "unit"} and isinstance(m["value"], float)
     json.dumps(result)
@@ -229,8 +254,7 @@ def test_main_prints_the_contract_line_last(monkeypatch, capsys):
     assert run.main(ARGV) == 0
     lines = capsys.readouterr().out.splitlines()
     result = json.loads(lines[-1])
-    cell = run.Cell(CELLS[0])
-    _check_line(result, [m["name"] for m in cell.end_to_end])
+    _check_line(result, run.Cell(CELLS[0]), trace=False)
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] >= 64 and result["attempted"] % 64 == 0
     assert all(m["value"] > 0 for m in result["metrics"].values())
@@ -254,9 +278,6 @@ def test_restore_driver_traced_run_reports_its_layers(monkeypatch, drive):
     # the profiler stops after the first cycle; the window runs on
     monkeypatch.setattr(run, "TRACED_SECONDS", 0.0)
     result, rows = drive("mainnet-1m.restore", trace=True)
-    # a host backend's trace has no device plane: the two trace readers
-    # find nothing to read and the harness leaves their metrics out
-    _check_line(result, ["restore_enter_ms", "checkpoint_write_ms"])
     assert result["correct"] is True and result["attempted"] >= 1
     assert any(r.get("compared")
                == "resumed_state_root.bytes_differing_from_hashlib"
@@ -273,9 +294,6 @@ def test_one_gwei_on_the_device_makes_correct_false(monkeypatch, drive):
     from benchmark.drivers import replay
     _before_compare(monkeypatch, replay, one_gwei_on_the_device)
     result, rows = drive(CELLS[0], trace=True)
-    _check_line(result, ["generator_share", "compiles_in_window",
-                         "slot_root_ms", "stage_ms",
-                         "epoch_device_ms", "refresh_ms", "guard_events"])
     assert result["correct"] is False
     assert _failed(rows) == ["balances_root.bytes_differing_from_hashlib",
                              "state_root.bytes_differing_from_hashlib"]
@@ -352,6 +370,201 @@ def test_one_gwei_on_the_live_core_fails_the_restore_comparison(
     result, rows = drive("mainnet-1m.restore", trace=False)
     assert result["correct"] is False
     assert _failed(rows) == ["restore.check_cycle_roots_differing_from_live"]
+
+
+# -- a configuration, its cells and a metric added to a copy of the benchmark ---
+
+DATA_DIRS = ("configs", "traffic", "layer_metrics", "presets")
+MESH_CONFIG = "mainnet-tiny-mesh4"
+# one cell the benchmark has on each of its mixes: the added configuration's
+# cell on that mix reports and reads what this one does
+LIKE = {w["traffic"]: w["name"] for w in reversed(BENCH["workloads"])}
+MESH_CELLS = {mix: f"{MESH_CONFIG}.{mix}" for mix in LIKE}
+MESH_CELL = MESH_CELLS["replay"]
+MESH_V = TINY_V + 2     # no multiple of four: the device columns pad by two rows
+MESH_METRIC = {
+    "name": "relayout_events.mesh4", "unit": "count", "better": "lower",
+    "source": "program_counter", "layer": "guard rails",
+    "moves": "replay_slots_per_s", "workloads": [MESH_CELL]}
+
+
+def _tree(root):
+    return {p.relative_to(root): p.read_bytes()
+            for p in root.rglob("*") if p.is_file()}
+
+
+def _but_for_cells_appended(was: dict, now: dict) -> dict:
+    """`now` cut back to the entries `was` has, each metric's `workloads`
+    to as many cells as `was` lists there."""
+    out = dict(now)
+    for key in ("configs", "workloads", "end_to_end", "per_layer"):
+        out[key] = [
+            dict(new, workloads=new["workloads"][:len(old["workloads"])])
+            if "workloads" in old else new
+            for old, new in zip(was[key], now[key])]
+    return out
+
+
+@pytest.fixture
+def with_mesh_cells(tmp_path):
+    """A copy of BENCHMARK.json and the benchmark's data, with what a
+    `model_config` PR adds: a four-chip configuration in a file of its own,
+    its cell on every mix that is there, and one per-layer metric that only
+    its replay cell reads. No copied file is touched, and no entry of
+    BENCHMARK.json that was there, but for this: the `workloads` list of a
+    metric is where the driver reads which cells report it, so each new
+    cell's name is appended to the lists that name the cell it is like.
+    Gives `make(cell_chips=4)` -> the copy's root."""
+    def make(cell_chips=4):
+        root = tmp_path / f"cells_ask_for_{cell_chips}"
+        for d in DATA_DIRS:
+            shutil.copytree(REPO / "benchmark" / d, root / "benchmark" / d)
+        copied = _tree(root)
+        was = json.loads((REPO / "BENCHMARK.json").read_text())
+        bench = json.loads((REPO / "BENCHMARK.json").read_text())
+        one_chip = run.Cell(LIKE["replay"]).config
+        row = {"name": MESH_CONFIG, "source": one_chip["source"],
+               "file": f"benchmark/configs/{MESH_CONFIG}.json",
+               "reduced": one_chip["reduced"] + ["validators"],
+               "why": "the harness's own proof: the mainnet preset with the "
+                      "validator axis over four devices, at a test's size"}
+        (root / row["file"]).write_text(json.dumps(dict(
+            one_chip, name=MESH_CONFIG, validators=MESH_V, chips=4,
+            reduced=row["reduced"],
+            layout="four chips, the validator axis sharded over them")))
+        (root / "benchmark" / "layer_metrics"
+         / f"{MESH_METRIC['name']}.json").write_text(json.dumps(dict(
+             {k: v for k, v in MESH_METRIC.items() if k != "workloads"},
+             reader={"kind": "counter_delta",
+                     "counters": ["watchdog.relayout_events"]})))
+        bench["configs"].append(row)
+        for mix, name in MESH_CELLS.items():
+            bench["workloads"].append({
+                "name": name, "config": MESH_CONFIG, "traffic": mix,
+                "chips": cell_chips, "why": f"the {mix} mix on the sharded core"})
+            for m in bench["end_to_end"] + bench["per_layer"]:
+                if LIKE[mix] in m.get("workloads", []):
+                    m["workloads"].append(name)
+        bench["per_layer"].append(MESH_METRIC)
+        (root / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))
+        assert {k: v for k, v in _tree(root).items() if k in copied} == copied
+        assert _but_for_cells_appended(was, bench) == was
+        return root
+    return make
+
+
+def test_cells_added_to_a_copy_leave_the_others_as_they_resolve(
+        with_mesh_cells):
+    root = with_mesh_cells()
+    for name in CELLS:
+        here, there = run.Cell(name), run.Cell(name, root)
+        assert there.per_layer == here.per_layer
+        assert [m["name"] for m in there.end_to_end] \
+            == [m["name"] for m in here.end_to_end]
+        assert (there.config, there.mix, there.chips) \
+            == (here.config, here.mix, here.chips)
+    for mix, name in MESH_CELLS.items():
+        added, like = run.Cell(name, root), run.Cell(LIKE[mix])
+        assert added.chips == added.config["chips"] == 4
+        assert [m["name"] for m in added.end_to_end] \
+            == [m["name"] for m in like.end_to_end]
+        assert [m["name"] for m in added.per_layer] \
+            == [m["name"] for m in like.per_layer] \
+            + [MESH_METRIC["name"]] * (name == MESH_CELL)
+
+
+def test_a_new_cell_reports_only_what_lists_it(with_mesh_cells):
+    """Without its name in an end-to-end metric's `workloads` a new cell
+    reports `setup_s` alone, and a per-layer metric that lists it is then
+    refused by name: the lists are not inherited from the mix."""
+    root = with_mesh_cells()
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    for m in bench["end_to_end"]:
+        if MESH_CELL in m.get("workloads", []):
+            m["workloads"].remove(MESH_CELL)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    with pytest.raises(SystemExit, match=f"{MESH_CELL!r}, which does not report"):
+        run.Cell(MESH_CELL, root)
+    for m in bench["per_layer"]:
+        if MESH_CELL in m["workloads"]:
+            m["workloads"].remove(MESH_CELL)
+    bench["per_layer"] = [m for m in bench["per_layer"] if m["workloads"]]
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    bare = run.Cell(MESH_CELL, root)
+    assert [m["name"] for m in bare.end_to_end] == ["setup_s"]
+    assert bare.per_layer == []
+
+
+def test_a_cell_whose_chips_differ_from_its_configurations_is_refused(
+        with_mesh_cells):
+    with pytest.raises(SystemExit, match=(
+            f"{MESH_CELL!r} asks for 1 chip.*{MESH_CONFIG}.json lays the "
+            f"deployment out on 4")):
+        run.Cell(MESH_CELL, with_mesh_cells(cell_chips=1))
+
+
+def test_the_mesh_cell_runs_correct_on_four_devices(
+        monkeypatch, drive, with_mesh_cells):
+    """The configuration's file places the core: its columns live on the
+    first four devices, padded there to a multiple of four, and every
+    comparison holds on the logical rows."""
+    from benchmark.drivers import replay
+    placed = {}
+    _before_compare(monkeypatch, replay, lambda driver: placed.update(
+        devices=driver.dep.core.cols.balance.sharding.device_set,
+        rows=driver.dep.core.cols.balance.shape[0],
+        fetched=len(driver.dep.fetch_columns()["balance"])))
+    result, rows = drive(MESH_CELL, trace=False, root=with_mesh_cells(),
+                         validators=MESH_V)
+    import jax
+    assert placed["devices"] == set(jax.devices()[:4])
+    assert placed["fetched"] == MESH_V < placed["rows"] == MESH_V + 2
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["attempted"] >= 64 and _failed(rows) == []
+    assert all(m["value"] > 0 for m in result["metrics"].values())
+
+
+def test_one_gwei_on_the_mesh_cells_device_makes_correct_false(
+        monkeypatch, drive, with_mesh_cells):
+    """Control 1 on the sharded core, traced: the line holds every metric of
+    the replay cells that a host backend can read, and the one the cell
+    brought, read through its own file."""
+    from benchmark.drivers import replay
+    _before_compare(monkeypatch, replay, one_gwei_on_the_device)
+    result, rows = drive(MESH_CELL, trace=True, root=with_mesh_cells(),
+                         validators=MESH_V)
+    assert result["correct"] is False
+    assert _failed(rows) == ["balances_root.bytes_differing_from_hashlib",
+                             "state_root.bytes_differing_from_hashlib"]
+    assert result["metrics"][MESH_METRIC["name"]] == {"value": 0.0,
+                                                      "unit": "count"}
+    assert len(result["metrics"]) > 1
+
+
+@pytest.mark.skipif("restore" not in MESH_CELLS, reason="no restore mix")
+def test_the_mesh_restore_cell_resumes_onto_the_four_devices(
+        monkeypatch, drive, with_mesh_cells):
+    """The restore driver resumes every cycle's core onto the deployment's
+    mesh: padded columns on the four devices, roots equal to the live
+    core's and to hashlib's over the logical rows."""
+    from consensus_specs_tpu.models.phase0.resident import ResidentCore
+    real, resumed = ResidentCore.from_checkpoint.__func__, []
+
+    def recording(cls, spec, data, mesh="env"):
+        core = real(cls, spec, data, mesh=mesh)
+        resumed.append((mesh, core.cols.balance.sharding.device_set,
+                        core.cols.balance.shape[0]))
+        return core
+    monkeypatch.setattr(ResidentCore, "from_checkpoint", classmethod(recording))
+    result, rows = drive(MESH_CELLS["restore"], trace=False,
+                         root=with_mesh_cells(), validators=MESH_V)
+    import jax
+    assert len(resumed) >= 1 + 1 + result["attempted"] + 1  # live, warm-up, window, check
+    assert all(mesh is not None and mesh != "env"
+               and devices == set(jax.devices()[:4]) and rows_ == MESH_V + 2
+               for mesh, devices, rows_ in resumed)
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["attempted"] >= 1 and _failed(rows) == []
 
 
 def test_oracle_small_compares_with_the_object_model():

@@ -1,48 +1,37 @@
-"""The readers and per-layer metrics that PR 25 added to the benchmark, on
-hand-built spans and planes: `span_arg_median`, `span_arg_slope`,
-`span_least`, `span_self_median`, `trace_idle_in_span`, the `traced_on_device` wrapper, and every new
-`layer_metrics/*.json` run once on a synthetic record."""
+"""The span and trace readers on hand-built spans and planes, every
+per-layer metric of BENCHMARK.json through its own `layer_metrics/*.json` on
+a synthetic record, and what holds of `per_layer` whatever it lists: every
+entry names the cells that read it, every cell reads the entries that list
+it, every file has an entry and every reader kind a source and a record.
+
+Nothing here counts entries or names a metric's file: a PR that adds a
+cell, a metric or a reader kind adds files and entries, and a cell's name
+to the `workloads` lists of the metrics it reports and reads (the recipe is
+`benchmark/run.py`'s docstring), and no line of this file."""
 from __future__ import annotations
 
+import importlib.util
 import json
+import sys
 from pathlib import Path
 
 import pytest
 
-from benchmark import reduce, run
-from benchmark.reduce import Event, Line, Plane
-from benchmark.sources import Seen
+HERE = Path(__file__).resolve().parent
+REPO = HERE.parents[1]
+for p in (REPO, HERE):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
 
-REPO = Path(__file__).resolve().parents[2]
+from benchmark import reduce, run  # noqa: E402
+from synthetic_run import (  # noqa: E402
+    OPS, on_a_host_backend, planes as _planes, seen as _seen, span as _span)
+
 BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
-MS = 1e6    # the planes' clock is nanoseconds
-
-# what PR 24 left; every entry after them is this file's to cover
-PR24_METRICS = 12
-NEW_METRICS = [m["name"] for m in BENCH["per_layer"][PR24_METRICS:]]
-
-
-def _planes(ops, notes, device=True):
-    """A 100 ms `bench.window`, device operations and host annotations, each
-    (name, start ms, duration ms)."""
-    host = Plane("/host:CPU", [Line("python", [
-        Event("bench.window", 0.0, 100 * MS),
-        *(Event(n, a * MS, d * MS) for n, a, d in notes)])])
-    if not device:
-        return [host]
-    return [Plane("/device:TPU:0", [Line("XLA Ops", [
-        Event(n, a * MS, d * MS) for n, a, d in ops])]), host]
-
-
-def _seen(**kw):
-    base = dict(spans=[], counters={}, values={}, planes=None,
-                config={"validators": 1_000_000}, mix={}, peaks={})
-    return Seen(**dict(base, **kw))
-
-
-def _span(name, dur, id=0, parent_id=0, req=None, **args):
-    return {"name": name, "dur": dur, "id": id, "parent_id": parent_id,
-            "req": req, "args": args or None}
+CELLS = [w["name"] for w in BENCH["workloads"]]
+LAYER_METRICS = REPO / "benchmark" / "layer_metrics"
+SOURCES = REPO / "benchmark" / "sources"
+RECORDS = HERE / "reader_records"
 
 
 # idle [0,20] [30,60] [70,100] of the window; the slot root's annotations
@@ -125,17 +114,6 @@ READERS = [
     ({"kind": "trace_idle_in_span", "span": "resident.slot_root"},
      dict(planes=_planes(OPS, NOTES, device=False)), None),  # a host backend
     ({"kind": "trace_idle_in_span", "span": "resident.slot_root"}, {}, None),
-    # -- traced_on_device -----------------------------------------------------
-    ({"kind": "traced_on_device",
-      "inner": {"kind": "span_median", "span": "a", "scale": 1000.0}},
-     dict(spans=[_span("a", 0.25)], planes=_planes(OPS, [])), 250.0),
-    ({"kind": "traced_on_device",
-      "inner": {"kind": "span_median", "span": "a"}},
-     dict(spans=[_span("a", 0.25)], planes=_planes(OPS, [], device=False)),
-     None),
-    ({"kind": "traced_on_device",
-      "inner": {"kind": "span_median", "span": "a"}},
-     dict(spans=[_span("a", 0.25)]), None),                 # an untraced run
 ]
 
 
@@ -160,68 +138,115 @@ def test_idle_in_spans_never_exceeds_the_idle_share():
     assert sum(parts) <= idle
 
 
-# -- every metric this PR added, through its own file -------------------------
+# -- every per-layer metric, through its own file ------------------------------
 
-def _record_for(reader):
-    """A synthetic run that holds exactly what `reader` looks for."""
-    inner = reader.get("inner", reader)
-    span = inner["span"]
-    if inner["kind"] == "trace_idle_in_span":
-        return _seen(planes=_planes(OPS, [(span, 10, 40)])), 30.0
-    planes = _planes(OPS, [])
-    if inner["kind"] in ("span_median", "span_least"):
-        return _seen(planes=planes, spans=[
-            _span(span, d) for d in (0.020, 0.030, 0.010)]), \
-            20.0 if inner["kind"] == "span_median" else 10.0
-    if inner["kind"] == "span_arg_slope":
-        return _seen(planes=planes, spans=[
-            _span(span, 1.0, req=64 * k, **{inner["arg"]: 1000 + 130 * k})
-            for k in range(4)]), 130.0 * inner["per"] / 64
-    if inner["kind"] == "span_arg_median":
-        return _seen(planes=planes, spans=[
-            _span(span, 1.0, **{inner["arg"]: n}) for n in (5, 9, 7)]), 7.0
-    assert inner["kind"] == "span_self_median"
-    return _seen(planes=planes, spans=[
-        _span("child", 0.4, 2, 1), _span(span, 1.0, 1)]), 600.0
+def _record(kind: str):
+    """`reader_records/<kind>.py`: `record(reader)` gives the fields of a run
+    that holds exactly what the reader looks for, and the value it must
+    read. A PR that adds `sources/<kind>.py` adds its record beside these."""
+    path = RECORDS / f"{kind}.py"
+    assert path.is_file(), (
+        f"no test drives a {kind!r} reader through its metric's own file: "
+        f"add tests/benchmark/reader_records/{kind}.py")
+    spec = importlib.util.spec_from_file_location(f"reader_records.{kind}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.record
 
 
-@pytest.mark.parametrize("name", NEW_METRICS)
-def test_new_metric_reads_its_span(name):
-    metric = json.loads((REPO / "benchmark" / "layer_metrics"
-                         / f"{name}.json").read_text())
-    seen, want = _record_for(metric["reader"])
-    assert run.read_metric(metric, seen) == pytest.approx(want)
-    assert isinstance(run.read_metric(metric, seen), float)
-    # the parent commit's program has no such span: nothing is read, and
-    # nothing is raised
-    assert run.read_metric(metric, _seen(planes=_planes(OPS, []))) is None
+@pytest.mark.parametrize("entry", BENCH["per_layer"], ids=lambda m: m["name"])
+def test_metric_reads_its_record_through_its_own_file(entry):
+    metric = json.loads((LAYER_METRICS / f"{entry['name']}.json").read_text())
+    fields, want = _record(metric["reader"]["kind"])(metric["reader"])
+    seen = _seen(**fields)
+    got = run.read_metric(metric, seen)
+    assert isinstance(got, float) and got == pytest.approx(want)
+    # a run that holds nothing for it (the parent commit's program has no
+    # such span, or records without identity): nothing read, nothing raised
+    assert run.read_metric(metric, _seen()) is None
     assert run.read_metric(metric, _seen(
-        planes=_planes(OPS, []),
-        spans=[{"name": "resident.slot_root", "ts": 0.0, "dur": 0.02,
+        planes=_planes([], [], device=False),
+        spans=[{"name": "some.older.span", "ts": 0.0, "dur": 0.02,
                 "depth": 0, "parent": "", "tid": 1, "args": None}])) is None
-    # a host backend's run reports none of them
-    host = seen._replace(planes=_planes(OPS, [], device=False))
-    assert run.read_metric(metric, host) is None
+    # a host backend writes no device plane: what the device's trace gives
+    # reads nothing there, every other source reads what it read
+    host = run.read_metric(metric, on_a_host_backend(seen))
+    assert host == (None if entry["source"] == "device_trace" else got)
 
 
-def test_new_metrics_are_the_issues_sixteen_and_the_reviews_eleven():
-    """ISSUE 25's sixteen, then one for each span, counter and field that
-    REVIEW.md found without a reader and the least value of the two
-    bimodal spans; each cell gets its own."""
-    assert len(NEW_METRICS) == 16 + 9 + 2
-    got = {cell["name"]: {m["name"] for m in run.Cell(cell["name"]).per_layer}
-           & set(NEW_METRICS) for cell in BENCH["workloads"]}
-    replay = {"slot_root_history_ms", "slot_root_attestations_ms",
-              "slot_root_pairs_hashed", "idle_in_slot_root",
-              "boundary_self_ms", "stage_distill_ms", "refresh_download_ms",
-              "refresh_final_updates_ms", "forest_build_ms.replay",
-              "forest_pair_lanes", "slot_root_forests_ms",
-              "slot_root_small_ms", "slot_root_merkleize_ms",
-              "slot_root_pairs_zero_filled", "slot_root_pairs_per_epoch",
-              "slot_self_ms", "stage_upload_ms"}
-    restore = set(NEW_METRICS) - replay
-    assert got == {"mainnet-1m.replay": replay, "mainnet-300k.replay": replay,
-                   "mainnet-1m.restore": restore}
-    assert {"forest_build_ms.restore", "checkpoint_ms",
-            "restore_ms", "restore_decode_least_ms"} <= restore
-    assert len(restore) == 10
+# -- what holds of `per_layer`, whatever it lists --------------------------------
+
+@pytest.mark.parametrize("name", CELLS)
+def test_cell_reads_exactly_the_entries_that_list_it(name):
+    listed = {m["name"] for m in BENCH["per_layer"] if name in m["workloads"]}
+    assert {m["name"] for m in run.Cell(name).per_layer} == listed
+    assert listed, "a cell reports at least one per-layer metric"
+
+
+@pytest.mark.parametrize("entry", BENCH["per_layer"], ids=lambda m: m["name"])
+def test_entry_lists_cells_that_exist_and_report_what_it_moves(entry):
+    cells = entry.get("workloads")
+    assert cells, "an entry names the cells that read it: no list, no default"
+    assert len(cells) == len(set(cells)) and set(cells) <= set(CELLS)
+    for name in cells:
+        reported = {m["name"] for m in BENCH["end_to_end"]
+                    if name in m.get("workloads", [name])}
+        assert entry["moves"] in reported, (name, entry["moves"])
+
+
+def test_every_metric_file_has_an_entry_and_every_entry_a_file():
+    files = sorted(p.stem for p in LAYER_METRICS.glob("*.json"))
+    assert files == sorted(m["name"] for m in BENCH["per_layer"])
+    assert [p.name for p in LAYER_METRICS.iterdir() if p.suffix != ".json"] == []
+
+
+def test_every_reader_kind_has_a_source_and_a_record():
+    used = {json.loads(p.read_text())["reader"]["kind"]
+            for p in LAYER_METRICS.glob("*.json")}
+    sources = {p.stem for p in SOURCES.glob("*.py")} - {"__init__"}
+    records = {p.stem for p in RECORDS.glob("*.py")}
+    assert used <= sources, "a reader.kind is sources/<kind>.py"
+    assert sources == records, "each kind of reader has its synthetic record"
+
+
+@pytest.mark.parametrize("spoiled,message", [
+    (lambda e: e.pop("workloads"), "lists no `workloads`"),
+    (lambda e: e.update(moves="restore_s"), "does not report 'restore_s'"),
+], ids=["no-list", "moves-what-the-cell-does-not-report"])
+def test_an_entry_the_cell_cannot_place_is_refused(tmp_path, spoiled, message):
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    entry = next(m for m in bench["per_layer"] if CELLS[0] in m["workloads"])
+    spoiled(entry)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    (tmp_path / "benchmark").symlink_to(REPO / "benchmark")
+    with pytest.raises(SystemExit, match=message):
+        run.Cell(CELLS[0], root=tmp_path)
+
+
+# what each cell read when its entries got their lists (PR 27): a later PR
+# adds to these and takes nothing away
+REPLAY = {
+    "generator_share", "compiles_in_window", "slot_root_ms", "stage_ms",
+    "epoch_device_ms", "refresh_ms", "epoch_program_roofline",
+    "device_idle_share.replay", "guard_events", "slot_root_history_ms",
+    "slot_root_attestations_ms", "slot_root_pairs_hashed",
+    "idle_in_slot_root", "boundary_self_ms", "stage_distill_ms",
+    "refresh_download_ms", "refresh_final_updates_ms",
+    "forest_build_ms.replay", "forest_pair_lanes", "slot_root_forests_ms",
+    "slot_root_small_ms", "slot_root_merkleize_ms",
+    "slot_root_pairs_zero_filled", "slot_root_pairs_per_epoch",
+    "slot_self_ms", "stage_upload_ms"}
+RESTORE = {
+    "restore_enter_ms", "checkpoint_write_ms", "device_idle_share.restore",
+    "forest_build_ms.restore", "checkpoint_download_ms",
+    "checkpoint_assemble_ms", "restore_decode_ms", "restore_upload_ms",
+    "idle_in_restore_decode", "checkpoint_ms", "restore_ms",
+    "restore_decode_least_ms", "checkpoint_assemble_least_ms"}
+READ_SINCE_PR27 = {"mainnet-1m.replay": REPLAY, "mainnet-300k.replay": REPLAY,
+                   "mainnet-1m.restore": RESTORE}
+
+
+@pytest.mark.parametrize("name", sorted(READ_SINCE_PR27))
+def test_a_cell_still_reads_what_it_read(name):
+    assert {m["name"] for m in run.Cell(name).per_layer} \
+        >= READ_SINCE_PR27[name]
