@@ -482,25 +482,28 @@ def pad_epoch_inputs(inp: EpochInputs, vp: int) -> EpochInputs:
     """Pad the [V] participation facts to [vp] rows with the neutral
     values build_epoch_inputs uses for non-participants (flags False,
     inclusion delay 1, proposer 0, no crosslink committee); the two
-    replicated per-shard tables pass through."""
+    replicated per-shard tables pass through. Host facts
+    (build_epoch_inputs_np) pad on the host and stay there, so that they
+    can go from the host straight to their shards."""
     V = int(inp.prev_src.shape[0])
     k = vp - V
     assert k >= 0, (vp, V)
     if k == 0:
         return inp
-    f_bool = jnp.zeros(k, dtype=bool)
+    xp = np if isinstance(inp.prev_src, np.ndarray) else jnp
+    f_bool = xp.zeros(k, dtype=bool)
     return inp._replace(
-        prev_src=jnp.concatenate([inp.prev_src, f_bool]),
-        prev_tgt=jnp.concatenate([inp.prev_tgt, f_bool]),
-        prev_head=jnp.concatenate([inp.prev_head, f_bool]),
-        curr_tgt=jnp.concatenate([inp.curr_tgt, f_bool]),
-        incl_delay=jnp.concatenate(
-            [inp.incl_delay, jnp.ones(k, dtype=jnp.uint64)]),
-        att_proposer=jnp.concatenate(
-            [inp.att_proposer, jnp.zeros(k, dtype=jnp.int32)]),
-        v_shard=jnp.concatenate(
-            [inp.v_shard, jnp.full(k, -1, dtype=jnp.int32)]),
-        in_winning=jnp.concatenate([inp.in_winning, f_bool]),
+        prev_src=xp.concatenate([inp.prev_src, f_bool]),
+        prev_tgt=xp.concatenate([inp.prev_tgt, f_bool]),
+        prev_head=xp.concatenate([inp.prev_head, f_bool]),
+        curr_tgt=xp.concatenate([inp.curr_tgt, f_bool]),
+        incl_delay=xp.concatenate(
+            [inp.incl_delay, xp.ones(k, dtype=xp.uint64)]),
+        att_proposer=xp.concatenate(
+            [inp.att_proposer, xp.zeros(k, dtype=xp.int32)]),
+        v_shard=xp.concatenate(
+            [inp.v_shard, xp.full(k, -1, dtype=xp.int32)]),
+        in_winning=xp.concatenate([inp.in_winning, f_bool]),
     )
 
 
@@ -537,17 +540,23 @@ def columns_from_state(state, np_cols: dict = None) -> ValidatorColumns:
                                for f in ValidatorColumns._fields})
 
 
-def scalars_from_state(state) -> EpochScalars:
+def scalars_np_from_state(state) -> EpochScalars:
+    """The epoch scalars as host values: what `scalars_from_state` uploads,
+    before it does (a serving mesh places them itself, replicated)."""
     return EpochScalars(
-        slot=u64(state.slot),
-        previous_justified_epoch=u64(state.previous_justified_epoch),
-        current_justified_epoch=u64(state.current_justified_epoch),
-        justification_bitfield=u64(state.justification_bitfield),
-        finalized_epoch=u64(state.finalized_epoch),
-        latest_start_shard=u64(state.latest_start_shard),
-        latest_slashed_balances=jnp.asarray(
-            np.array([int(x) for x in state.latest_slashed_balances], dtype=np.uint64)),
+        slot=np.uint64(state.slot),
+        previous_justified_epoch=np.uint64(state.previous_justified_epoch),
+        current_justified_epoch=np.uint64(state.current_justified_epoch),
+        justification_bitfield=np.uint64(state.justification_bitfield),
+        finalized_epoch=np.uint64(state.finalized_epoch),
+        latest_start_shard=np.uint64(state.latest_start_shard),
+        latest_slashed_balances=np.array(
+            [int(x) for x in state.latest_slashed_balances], dtype=np.uint64),
     )
+
+
+def scalars_from_state(state) -> EpochScalars:
+    return EpochScalars(*map(jnp.asarray, scalars_np_from_state(state)))
 
 
 # ---------------------------------------------------------------------------
@@ -872,7 +881,16 @@ def process_crosslinks_vectorized(spec, state, ctx: EpochContext) -> None:
 
 
 def build_epoch_inputs(spec, state, ctx: EpochContext = None) -> EpochInputs:
-    """Distill PendingAttestations + committee layout into device arrays.
+    """Distill PendingAttestations + committee layout into device arrays
+    (on the default device; a serving mesh takes build_epoch_inputs_np's
+    host arrays and places them itself)."""
+    return EpochInputs(*map(jnp.asarray,
+                            build_epoch_inputs_np(spec, state, ctx)))
+
+
+def build_epoch_inputs_np(spec, state,
+                          ctx: EpochContext = None) -> EpochInputs:
+    """Distill PendingAttestations + committee layout into host arrays.
 
     Must be called AFTER process_crosslinks has run on `state` (winner
     selection for deltas reads the updated current_crosslinks, matching the
@@ -929,17 +947,11 @@ def build_epoch_inputs(spec, state, ctx: EpochContext = None) -> EpochInputs:
         shard_comm_balance[shard] = comm_bal[off]
 
     return EpochInputs(
-        prev_src=jnp.asarray(prev_src),
-        prev_tgt=jnp.asarray(prev_tgt),
-        prev_head=jnp.asarray(prev_head),
-        curr_tgt=jnp.asarray(curr_tgt),
-        incl_delay=jnp.asarray(incl_delay),
-        att_proposer=jnp.asarray(att_proposer),
-        v_shard=jnp.asarray(v_shard),
-        in_winning=jnp.asarray(in_winning),
-        shard_att_balance=jnp.asarray(shard_att_balance),
-        shard_comm_balance=jnp.asarray(shard_comm_balance),
-    )
+        prev_src=prev_src, prev_tgt=prev_tgt, prev_head=prev_head,
+        curr_tgt=curr_tgt, incl_delay=incl_delay, att_proposer=att_proposer,
+        v_shard=v_shard, in_winning=in_winning,
+        shard_att_balance=shard_att_balance,
+        shard_comm_balance=shard_comm_balance)
 
 
 def process_epoch_soa(spec, state, timings: dict = None):

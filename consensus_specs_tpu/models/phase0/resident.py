@@ -70,10 +70,11 @@ from ...utils.ssz.incremental import (IncrementalMerkleTree,
 from ...utils.ssz.typing import Vector
 from . import helpers as helpers_mod
 from .epoch_soa import (EpochConfig, ValidatorColumns, build_epoch_context,
-                        build_epoch_inputs, columns_np_from_state,
-                        inert_column_tail, pad_epoch_inputs,
-                        pad_validator_columns, process_crosslinks_vectorized,
-                        scalars_from_state, _apply_justification,
+                        build_epoch_inputs, build_epoch_inputs_np,
+                        columns_np_from_state, inert_column_tail,
+                        pad_epoch_inputs, pad_validator_columns,
+                        process_crosslinks_vectorized, scalars_from_state,
+                        scalars_np_from_state, _apply_justification,
                         _apply_validator_columns, _epoch_transition_jit)
 
 # Mirror columns the host-side spec logic reads between boundaries.
@@ -821,13 +822,29 @@ class ResidentCore:
                         f"{self._tkey}.forest.bal.l0"):
                 _watchdog.forget(key)
 
+    def _stage_epoch_inputs(self, state, ctx) -> tuple:
+        """(scal, inp) for the boundary's dispatch, uploaded where the
+        program takes them: on the default device without a mesh; under a
+        mesh padded on the host to the columns' rows and put from the host
+        straight into `epoch_shardings()`'s placement (no `[V]` fact is
+        copied from chip to chip on its way to the program)."""
+        if self._mesh is None:
+            return (scalars_from_state(state),
+                    build_epoch_inputs(self.spec, state, ctx))
+        inp = pad_epoch_inputs(build_epoch_inputs_np(self.spec, state, ctx),
+                               int(self.cols.balance.shape[0]))
+        return self._mesh.place_epoch_inputs(scalars_np_from_state(state),
+                                             inp)
+
     def _epoch_dispatch(self, scal, inp):
         """The guarded boundary dispatch + the degradation ladder.
 
-        `inp` arrives UNPADDED ([V] facts); padding to the mesh multiple
-        happens per attempt, because a ladder walk can end at the
-        single-device rung (`degrade_to_single_device`) where the padded
-        shape no longer applies. The inner guard (guarded_dispatch, via
+        `scal` and `inp` arrive as `_stage_epoch_inputs` placed them: on a
+        mesh padded and in the program's own placement. A ladder walk can
+        end at the single-device rung (`degrade_to_single_device`), where
+        neither applies any more: the facts are then cut back to the
+        logical rows and taken to the one device, as part of the
+        recovery. The inner guard (guarded_dispatch, via
         ServingMesh.epoch_transition on the mesh path) owns retry/
         backoff/deadline/tripwires; this loop owns only the LADDER: each
         typed failure that survives its retries steps one rung — oracle
@@ -843,10 +860,8 @@ class ResidentCore:
                 if self._mesh is not None:
                     # matched in/out shardings: this boundary's output
                     # columns are the next boundary's inputs, zero re-layout
-                    inp_p = pad_epoch_inputs(
-                        inp, int(self.cols.balance.shape[0]))
                     return self._mesh.epoch_transition(
-                        self.cfg, self.cols, scal, inp_p, check=check)
+                        self.cfg, self.cols, scal, inp, check=check)
                 # _epoch_transition_jit() donates off-CPU exactly like
                 # the mesh program: same no-retry pin for post-consume
                 # failures (pre-dispatch transients still retry inside
@@ -886,17 +901,31 @@ class ResidentCore:
                 # hops on the way to the rung that can help
                 # (single_device) — the price of one simple invariant,
                 # rung k == knobs 1..k, that /healthz can report
+                on_mesh = self._mesh is not None
                 ladder.register_single_device(self.degrade_to_single_device)
                 try:
                     rung = ladder.degrade(reason=type(exc).__name__)
                 finally:
                     ladder.unregister_single_device(
                         self.degrade_to_single_device)
+                if on_mesh and self._mesh is None:
+                    # staged for the mesh this core has just left
+                    scal, inp = self._unstage_for_single_device(scal, inp)
                 if rung is None:
                     raise FatalDispatchError(
                         f"epoch boundary dispatch failed with the "
                         f"degradation ladder exhausted: {exc}",
                         key=exc.key, attempts=exc.attempts) from exc
+
+    def _unstage_for_single_device(self, scal, inp) -> tuple:
+        """Mesh-staged (scal, inp) -> the default device, the facts cut
+        back to the logical rows (the single-device rung's recovery)."""
+        import jax.numpy as jnp
+        scal, inp = jax.device_get((scal, inp))
+        tables = {"shard_att_balance", "shard_comm_balance"}    # not [V]
+        inp = inp._replace(**{f: getattr(inp, f)[:self._v]
+                              for f in inp._fields if f not in tables})
+        return jax.tree_util.tree_map(jnp.asarray, (scal, inp))
 
     def process_epoch_resident(self, state) -> None:
         """The boundary transition on resident columns, under telemetry
@@ -923,8 +952,7 @@ class ResidentCore:
                     withdrawable_epoch=None,
                     balance=None))
                 process_crosslinks_vectorized(spec, state, ctx)
-                inp = build_epoch_inputs(spec, state, ctx)
-                scal = scalars_from_state(state)
+                scal, inp = self._stage_epoch_inputs(state, ctx)
             with telemetry.span("resident.stage.upload") as sp_up:
                 sp_up.fence(scal, inp)  # uploads land in "resident.stage"
 
@@ -935,6 +963,9 @@ class ResidentCore:
             _watchdog.layout_check(f"{self._tkey}.epoch.cols", self.cols)
             dev_cols, dev_scal, dev_report = self._epoch_dispatch(scal, inp)
             _watchdog.layout_check(f"{self._tkey}.epoch.cols", dev_cols)
+            # the layout that served: the ladder may have left the mesh
+            sp_dev.note(mesh_size=1 if self._mesh is None
+                        else self._mesh.size)
             sp_dev.fence(dev_cols.balance)
 
         with telemetry.span("resident.refresh") as sp_ref:

@@ -146,13 +146,23 @@ def _leaves(tree) -> Iterator:
 
 
 def _materialize(trees) -> None:
-    """The fence: fetch one element of every device leaf (the repo-wide
-    `_sync` idiom — materialized output bytes cannot arrive before the
-    program that makes them; chip_smoke.py prints this fence next to
-    `block_until_ready` for one epoch dispatch)."""
+    """The fence: fetch one element of every one-device leaf (the
+    repo-wide `_sync` idiom — materialized output bytes cannot arrive
+    before the program that makes them; chip_smoke.py prints this fence
+    next to `block_until_ready` for one epoch dispatch), and wait on every
+    shard of a leaf that lies over several devices."""
     import numpy as np
     for tree in trees:
         for leaf in _leaves(tree):
+            sharding = getattr(leaf, "sharding", None)
+            if sharding is not None and len(sharding.device_set) > 1:
+                # a leaf laid out over a mesh: wait for every shard. One
+                # fetched element there is five programs launched on all
+                # the devices (ravel, the index's converts, a gather with
+                # its all-reduce, a broadcast): 4 ms a leaf on four v5e
+                # chips, which the span would book as its own
+                leaf.block_until_ready()
+                continue
             ravel = getattr(leaf, "ravel", None)
             if ravel is not None:
                 np.asarray(ravel()[0:1])

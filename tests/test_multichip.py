@@ -298,3 +298,159 @@ def test_hierarchical_mesh_epoch_equals_single():
         for f in inp._fields[:-2]})
     sharded = jax.device_get(epoch_transition_device(cfg, cols_s, scal_s, inp_s))
     assert trees_bitwise_equal(single, sharded)
+
+
+# -- the resident core on a serving mesh against the one-device core -----------
+
+MESH4_V = 4 * 8 + 2     # minimal preset; no multiple of four: two inert rows
+
+
+def _epoch_avals(mesh):
+    """Shapes of the mesh epoch program's (cols, scal, inp) at MESH4_V."""
+    from consensus_specs_tpu.models.phase0.epoch_soa import (
+        pad_epoch_inputs, pad_validator_columns)
+    cfg = EpochConfig.from_spec(phase0.get_spec("minimal"))
+    cols, scal, inp = synthetic_epoch_state(
+        cfg, MESH4_V, np.random.default_rng(0))
+    vp = mesh.pad_rows(MESH4_V)
+    return jax.eval_shape(lambda: (
+        pad_validator_columns(cols, vp, cfg.FAR_FUTURE_EPOCH), scal,
+        pad_epoch_inputs(inp, vp)))
+
+
+def _resident_run(mesh, blocks, data, on_dispatch=None):
+    """Drive a checkpoint-free ResidentCore through `blocks` and take, at
+    every epoch boundary it crosses, the logical columns, both forest roots
+    and the state root; then the `resident.device` records."""
+    from consensus_specs_tpu import telemetry
+    from consensus_specs_tpu.models.phase0.resident import ResidentCore
+    from consensus_specs_tpu.utils.ssz.impl import deserialize
+
+    spec = phase0.get_spec("minimal")
+    spec.clear_caches()
+    state = deserialize(data, spec.BeaconState)
+    telemetry.set_enabled(True)
+    telemetry.reset()
+    core = ResidentCore(spec, state, mesh=mesh)
+    taken = []
+    try:
+        if on_dispatch is not None:
+            real = core._epoch_dispatch
+
+            def dispatch(scal, inp):
+                on_dispatch(core, scal, inp)
+                return real(scal, inp)
+            core._epoch_dispatch = dispatch
+        epoch = int(spec.get_current_epoch(state))
+        for block in blocks:
+            core.state_transition(state, block)
+            if int(spec.get_current_epoch(state)) != epoch:
+                epoch = int(spec.get_current_epoch(state))
+                taken.append((core._materialize_np_cols(),
+                              core._registry_balances_roots(),
+                              bytes(core._state_root(state))))
+        records = [s for s in telemetry.ring() if s["name"] == "resident.device"]
+    finally:
+        core.exit()
+        telemetry.set_enabled(None)
+        spec.clear_caches()
+    return taken, records
+
+
+@pytest.fixture(scope="module")
+def attested_blocks():
+    """Attestation-carrying blocks over two epoch boundaries, built on the
+    object model, and the serialized state they start from."""
+    from consensus_specs_tpu.crypto import bls
+    from consensus_specs_tpu.testing import factories
+    from consensus_specs_tpu.utils.ssz.impl import serialize
+
+    bls.bls_active = False
+    spec = phase0.get_spec("minimal")
+    spec.clear_caches()
+    state = factories.seed_genesis_state(spec, MESH4_V)
+    factories.advance_slots(spec, state, 2)
+    data = serialize(state, spec.BeaconState)
+    blocks = []
+    while int(spec.get_current_epoch(state)) < 2:
+        att = factories.new_attestation(spec, state)
+        block = factories.empty_block_next(spec, state)
+        block.slot = state.slot + spec.MIN_ATTESTATION_INCLUSION_DELAY
+        block.body.attestations.append(att)
+        spec.state_transition(state, block)
+        blocks.append(block)
+    spec.clear_caches()
+    return data, blocks
+
+
+def test_resident_mesh_equals_single_over_two_chained_boundaries(
+        attested_blocks):
+    """Every column, both forest roots and the state root of the sharded
+    core equal the one-device core's after each of two chained boundaries
+    that hold attestations; and the inputs of each dispatch sit where the
+    mesh program takes them once `resident.stage.upload` has closed."""
+    from consensus_specs_tpu.parallel.sharding import ServingMesh
+    if len(jax.devices()) < 4:
+        pytest.skip(f"needs 4 devices, have {len(jax.devices())}")
+    data, blocks = attested_blocks
+    mesh = ServingMesh.create(4)
+    staged = []
+
+    def placement(core, scal, inp):
+        _, scal_sh, inp_sh = mesh.epoch_shardings()
+        rows = int(core.cols.balance.shape[0])
+        assert rows == mesh.pad_rows(MESH4_V) == MESH4_V + 2
+        for x, want in zip(tuple(scal) + tuple(inp),
+                           tuple(scal_sh) + tuple(inp_sh)):
+            assert x.sharding.is_equivalent_to(want, x.ndim), x.sharding
+            assert x.committed
+        v_cols = [x for x, sh in zip(inp, inp_sh) if sh == mesh.shard_v]
+        assert len(v_cols) == 8 and all(x.shape == (rows,) for x in v_cols)
+        assert inp.shard_att_balance.sharding.is_fully_replicated
+        assert all(x.sharding.is_fully_replicated for x in scal)
+        staged.append(rows)
+
+    single, single_records = _resident_run(None, blocks, data)
+    sharded, mesh_records = _resident_run(mesh, blocks, data,
+                                          on_dispatch=placement)
+    assert len(single) == len(sharded) == 2 == len(staged)
+    for (cols_1, roots_1, root_1), (cols_4, roots_4, root_4) in zip(
+            single, sharded):
+        assert set(cols_1) == set(cols_4)
+        for f in cols_1:
+            assert cols_1[f].shape == (MESH4_V,) == cols_4[f].shape
+            assert (cols_1[f] == cols_4[f]).all(), f
+        assert roots_1 == roots_4 and root_1 == root_4
+    # the mesh program compiles under the function's name (the XLA module a
+    # device trace is read by), not as a partial's `jit__unknown`
+    (program,) = [pd for key, pd in mesh._jits.items() if key[0] == "epoch"]
+    lowered = program.resolve().lower(*_epoch_avals(mesh))
+    assert "module @jit__epoch_transition_traced " in lowered.as_text()[:200]
+    # the layout that served each boundary rides on its record
+    assert [r["args"]["mesh_size"] for r in mesh_records] == [4, 4]
+    assert [r["args"]["mesh_size"] for r in single_records] == [1, 1]
+
+
+def test_resident_device_notes_one_after_the_ladder_leaves_the_mesh(
+        attested_blocks):
+    """A dispatch that keeps failing before it runs walks the ladder down
+    to `degrade_to_single_device`: the inputs staged for the mesh are taken
+    to the one device, the boundary is served there, bit-identical, and its
+    record says so."""
+    from consensus_specs_tpu.parallel.sharding import ServingMesh
+    from consensus_specs_tpu.resilience import dispatch as rdispatch, faults
+    if len(jax.devices()) < 4:
+        pytest.skip(f"needs 4 devices, have {len(jax.devices())}")
+    data, blocks = attested_blocks
+    single, _ = _resident_run(None, blocks, data)
+    faults.set_schedule("seed=7;dispatch:*mesh.epoch*@1-99=raise")
+    try:
+        degraded, records = _resident_run(ServingMesh.create(4), blocks, data)
+    finally:
+        faults.set_schedule(None)
+        rdispatch.ladder().reset()
+    assert [r["args"]["mesh_size"] for r in records] == [1, 1]
+    for (cols_1, roots_1, root_1), (cols_d, roots_d, root_d) in zip(
+            single, degraded):
+        assert all((cols_1[f] == cols_d[f]).all() for f in cols_1)
+        assert roots_1 == roots_d and root_1 == root_d

@@ -161,3 +161,32 @@ def test_serving_mesh_padding_and_row_sharding():
     assert mesh.row_sharding(1) == mesh.replicated
     with pytest.raises(AssertionError):
         ServingMesh.create(3)                    # mesh size must be pow2
+
+
+def test_serving_mesh_places_epoch_inputs_from_the_host():
+    """Host facts, padded on the host, land in `epoch_shardings()`'s
+    placement with their values unchanged: every `[Vp]` fact a quarter a
+    device, the scalars and the two shard tables whole on each."""
+    from consensus_specs_tpu.models import phase0
+    from consensus_specs_tpu.models.phase0.epoch_soa import (
+        EpochConfig, pad_epoch_inputs, synthetic_epoch_state)
+    from consensus_specs_tpu.parallel.sharding import ServingMesh
+    mesh = ServingMesh.create(4)
+    cfg = EpochConfig.from_spec(phase0.get_spec("minimal"))
+    V = 4 * 16 + 3
+    _, scal, inp = jax.device_get(
+        synthetic_epoch_state(cfg, V, np.random.default_rng(5)))
+    padded = pad_epoch_inputs(inp, mesh.pad_rows(V))
+    assert all(isinstance(x, np.ndarray) for x in padded)   # still the host's
+    assert padded.prev_src.shape == (V + 1,) and not padded.prev_src[V]
+    assert padded.v_shard[V] == -1 and padded.incl_delay[V] == 1
+    scal_d, inp_d = mesh.place_epoch_inputs(scal, padded)
+    _, scal_sh, inp_sh = mesh.epoch_shardings()
+    for got, want in zip(tuple(scal_d) + tuple(inp_d),
+                         tuple(scal_sh) + tuple(inp_sh)):
+        assert got.sharding.is_equivalent_to(want, got.ndim)
+    assert [s.data.shape for s in inp_d.prev_src.addressable_shards] \
+        == [((V + 1) // 4,)] * 4
+    assert len(inp_d.shard_att_balance.addressable_shards) == 4
+    assert inp_d.shard_att_balance.sharding.is_fully_replicated
+    assert trees_bitwise_equal((scal_d, inp_d), (scal, padded))

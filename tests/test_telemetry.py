@@ -280,6 +280,40 @@ def test_span_fences_at_exit_only():
     assert silent.fetched_at == []
 
 
+def test_span_fences_a_mesh_leaf_by_waiting_for_every_shard():
+    """A leaf laid out over several devices is fenced by
+    `block_until_ready`, not by a fetched element: the fetch would launch
+    programs of its own on every device (a gather with its all-reduce among
+    them) and the span would book them. A one-device leaf keeps the fetch."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 devices")
+    mesh = Mesh(np.asarray(jax.devices()[:4]), ("v",))
+    waited = []
+
+    class Watched:
+        """A real sharded array behind a recording `block_until_ready`."""
+
+        def __init__(self, array):
+            self.array, self.sharding = array, array.sharding
+
+        def block_until_ready(self):
+            waited.append(len(self.sharding.device_set))
+            return self.array.block_until_ready()
+
+        def ravel(self):
+            raise AssertionError("a mesh leaf is not fenced by a fetch")
+
+    sharded = Watched(jax.device_put(np.arange(16), NamedSharding(mesh, P("v"))))
+    whole = Watched(jax.device_put(np.arange(4), NamedSharding(mesh, P())))
+    single = _FakeLeaf()
+    single.sharding = jax.device_put(np.arange(4)).sharding     # one device
+    with T.span("mesh.fenced") as sp:
+        sp.fence(sharded, (whole, single))
+    assert waited == [4, 4] and len(single.fetched_at) == 1
+
+
 # ---------------------------------------------------------------------------
 # metrics registry
 # ---------------------------------------------------------------------------
