@@ -6,13 +6,14 @@ spec (epoch.py) keeps reference semantics one-to-one; this module runs the
 same transition as masked elementwise math over `[V]`-shaped columns:
 
   - justification/finalization  (:1326-1373)  -> masked balance sums + scalar bit logic
-  - attestation deltas          (:1398-1443)  -> flag-masked reward vectors, one
-        scatter-add for proposer micro-rewards (the reference's O(V*A) list
-        membership tests become O(V) mask ops)
+  - attestation deltas          (:1398-1443)  -> flag-masked reward vectors; the
+        proposer micro-rewards summed through a table of the epoch's distinct
+        proposers (the reference's O(V*A) list membership tests become O(V)
+        mask ops)
   - crosslink deltas            (:1445-1463)  -> per-shard balance tables gathered per validator
   - registry updates            (:1479-1503)  -> closed-form exit-queue assignment + stable-sort
-        activation queue (the reference's sequential churn loop has a closed form:
-        rank r among new exits gets epoch b + (min(c0, churn) + r) // churn)
+        activation queue cut at its churn-th row (the reference's sequential churn loop has a
+        closed form: rank r among new exits gets epoch b + (min(c0, churn) + r) // churn)
   - slashings                   (:1507-1524)  -> elementwise, 128-bit exact muldiv
   - final updates               (:1526-1564)  -> hysteresis + rotation (numeric parts)
 
@@ -65,6 +66,7 @@ class EpochConfig(NamedTuple):
     MIN_SLASHING_PENALTY_QUOTIENT: int
     SHARD_COUNT: int
     TARGET_COMMITTEE_SIZE: int
+    MAX_ATTESTATIONS: int
 
     @classmethod
     def from_spec(cls, spec) -> "EpochConfig":
@@ -108,6 +110,55 @@ class EpochInputs(NamedTuple):
     in_winning: jnp.ndarray      # [V] bool - in the winning crosslink's attesting set
     shard_att_balance: jnp.ndarray   # [SHARD_COUNT] uint64 (>=1)
     shard_comm_balance: jnp.ndarray  # [SHARD_COUNT] uint64 (>=1)
+    proposer_table: jnp.ndarray  # [C] int32 - the distinct values of att_proposer's
+    #                              attestations, ascending, then -1 (proposer_table_np)
+    proposer_rows: jnp.ndarray   # int32 - how many rows of proposer_table are in use
+
+
+# The EpochInputs fields that are NOT [V] participation-fact columns: small
+# tables and a count, the same on every device of a serving mesh. The one
+# list the padding, the mesh placement and the single-device unstaging share.
+REPLICATED_INPUT_FIELDS = ("shard_att_balance", "shard_comm_balance",
+                           "proposer_table", "proposer_rows")
+assert EpochInputs._fields[-len(REPLICATED_INPUT_FIELDS):] \
+    == REPLICATED_INPUT_FIELDS
+
+# Rows of the proposer table summed at a time: one lane-width of keys
+# against every validator's att_proposer.
+PROPOSER_CHUNK = 128
+
+
+def including_blocks(cfg) -> int:
+    """The blocks that can include an attestation whose target is one
+    given epoch (`cfg` is an EpochConfig or a spec): process_attestation
+    (block.py) takes it at a slot of its own epoch no earlier than
+    MIN_ATTESTATION_INCLUSION_DELAY after the epoch's first, and at any
+    slot of the next epoch. Each has one proposer."""
+    return int(2 * cfg.SLOTS_PER_EPOCH - cfg.MIN_ATTESTATION_INCLUSION_DELAY)
+
+
+def proposer_table_capacity(cfg) -> int:
+    """Rows of `EpochInputs.proposer_table` for a preset: the most
+    PendingAttestations a chain of blocks can leave with the previous
+    epoch as target, each naming one proposer: including_blocks of
+    MAX_ATTESTATIONS each (124 x 128 = 15,872 on mainnet), up to a whole
+    number of chunks. One static shape a preset, so every state runs the
+    one compiled program: the device loops over the rows in use."""
+    rows = int(cfg.MAX_ATTESTATIONS) * including_blocks(cfg)
+    return -(-rows // PROPOSER_CHUNK) * PROPOSER_CHUNK
+
+
+def proposer_table_np(proposers, capacity: int):
+    """(table, rows) from proposer indices, duplicates allowed: the
+    distinct values ascending in an int32 table padded with -1, and their
+    count. `capacity` is the preset's (proposer_table_capacity); more
+    distinct values than that (a hand-built state: no chain of blocks
+    leaves them) take the next multiple of it, and one more compile."""
+    distinct = np.unique(np.asarray(proposers, dtype=np.int32))
+    rows = len(distinct)
+    table = np.full(max(1, -(-rows // capacity)) * capacity, -1, np.int32)
+    table[:rows] = distinct
+    return table, np.int32(rows)
 
 
 class EpochReport(NamedTuple):
@@ -121,6 +172,57 @@ class EpochReport(NamedTuple):
 def _total_balance(eff: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
     """get_total_balance over a mask (reference :933-941): max(sum, 1)."""
     return jnp.maximum(jnp.sum(jnp.where(mask, eff, u64(0))), u64(1))
+
+
+@jax.jit
+def _proposer_chunk_sums(att_proposer: jnp.ndarray, gain: jnp.ndarray,
+                         keys: jnp.ndarray) -> jnp.ndarray:
+    """sums[k] = the sum of gain[v] over the v with att_proposer[v] ==
+    keys[k]: one compare-select-reduce over [V, PROPOSER_CHUNK], which the
+    compiler runs inside the reduction's loop (tests/test_tpu_compile.py
+    holds the v5e compiler to it: no [V, 128] buffer, 1 GB at V = 1M).
+    A named call so that the memory tier can be told so
+    (`fused_calls`): its liveness model counts every traced value as a
+    buffer."""
+    hit = att_proposer[:, None] == keys[None, :]
+    return jnp.sum(jnp.where(hit, gain[:, None], u64(0)), axis=0)
+
+
+def _add_proposer_rewards(rewards: jnp.ndarray, att_proposer: jnp.ndarray,
+                          gain: jnp.ndarray, table: jnp.ndarray,
+                          rows: jnp.ndarray) -> jnp.ndarray:
+    """rewards[p] += the sum of gain[v] over the v with att_proposer[v] == p,
+    for every p of table[:rows] (:1416-1421: each source attester pays its
+    attestation's proposer).
+
+    A scatter-add of the [V] gains by att_proposer runs one row after
+    another on the chip (139 ns a row at V = 1M, PERF.md section 5), and
+    all but a few of its million updates land in the same thousand rows.
+    So the sum goes the other way round: a chunk of PROPOSER_CHUNK table
+    rows at a time is compared with every att_proposer, the hits' gains
+    are summed down the validator axis (shard-local under a mesh, the
+    chunk's sums combined across shards), and only the chunk's rows are
+    written. The loop runs over the rows in use, a traced count, never
+    over the table's capacity. Exact: the same uint64 terms in an
+    addition that has no order.
+
+    The table's rows are distinct (proposer_table_np), so every row of
+    `rewards` is written at most once and the value to add to is the one
+    it had before the loop: read from there, the carry's bound does not
+    grow with the trip count (the range tier proves the loop without an
+    invariant). Padding rows (-1) match no att_proposer and are dropped."""
+    V = rewards.shape[0]
+
+    def chunk(c, acc):
+        keys = jax.lax.dynamic_slice(
+            table, (c * PROPOSER_CHUNK,), (PROPOSER_CHUNK,))
+        sums = _proposer_chunk_sums(att_proposer, gain, keys)
+        at = jnp.where(keys >= 0, keys, V)      # padding: out of bounds
+        return acc.at[at].set(rewards.at[at].get(mode="clip") + sums,
+                              mode="drop")
+
+    n_chunks = (rows + (PROPOSER_CHUNK - 1)) // PROPOSER_CHUNK
+    return jax.lax.fori_loop(0, n_chunks, chunk, rewards)
 
 
 def _stage_a_traced(cfg: EpochConfig, cols: ValidatorColumns,
@@ -210,7 +312,9 @@ def _stage_a_traced(cfg: EpochConfig, cols: ValidatorColumns,
         # Proposer + inclusion-delay micro-rewards for source attesters (:1416-1429)
         src_set = inp.prev_src & unslashed
         proposer_gain = jnp.where(src_set, base_reward // u64(cfg.PROPOSER_REWARD_QUOTIENT), u64(0))
-        rewards = rewards.at[inp.att_proposer].add(proposer_gain)
+        rewards = _add_proposer_rewards(
+            rewards, inp.att_proposer, proposer_gain,
+            inp.proposer_table, inp.proposer_rows)
         delay = jnp.maximum(inp.incl_delay, u64(1))
         rewards = rewards + jnp.where(
             src_set, base_reward * u64(cfg.MIN_ATTESTATION_INCLUSION_DELAY) // delay, u64(0))
@@ -276,13 +380,26 @@ def _stage_a_traced(cfg: EpochConfig, cols: ValidatorColumns,
         withdrawable = jnp.where(
             ejected, assigned + u64(cfg.MIN_VALIDATOR_WITHDRAWABILITY_DELAY), cols.withdrawable_epoch)
 
-        # Activation queue: stable sort by eligibility epoch, dequeue churn-many
+        # Activation queue: stable sort by eligibility epoch, dequeue
+        # churn-many. A stable sort orders rows by (sort_key, row), so a
+        # row is among the first `churn` exactly when its pair is at or
+        # before that of the last row to make the cut: no row needs its
+        # own position in the order. When churn >= V the cut is the last
+        # row and every queued row passes.
         delayed_fin = finalized + u64(1) + u64(cfg.ACTIVATION_EXIT_DELAY)
         queued = (elig != FAR) & (cols.activation_epoch >= delayed_fin)
         sort_key = jnp.where(queued, elig, FAR)
-        order = jnp.argsort(sort_key, stable=True)
-        pos = jnp.zeros(V, dtype=jnp.uint64).at[order].set(jnp.arange(V, dtype=jnp.uint64))
-        dequeued = queued & (pos < churn)
+        row = jnp.arange(V, dtype=jnp.int32)
+        sorted_key, order = jax.lax.sort(
+            (sort_key, row), num_keys=1, is_stable=True)
+        # the one element at the cut, picked by a masked sum: under a mesh
+        # a shard-local pass and a scalar all-reduce, where an index into
+        # the sharded order gathers it
+        cut = row.astype(jnp.uint64) + u64(1) == jnp.minimum(churn, u64(V))
+        cut_key = jnp.sum(jnp.where(cut, sorted_key, u64(0)))
+        cut_row = jnp.sum(jnp.where(cut, order, 0))
+        dequeued = queued & ((sort_key < cut_key)
+                             | ((sort_key == cut_key) & (row <= cut_row)))
         activation = jnp.where(
             dequeued & (cols.activation_epoch == FAR),
             current_epoch + u64(1) + u64(cfg.ACTIVATION_EXIT_DELAY), cols.activation_epoch)
@@ -448,7 +565,7 @@ _stage_b_jit = partial(jax.jit, static_argnums=(0,))(_stage_b_traced)
 #     every real row (padding indices are the largest), so queued positions
 #     are unchanged,
 #   * the exit-queue base/count scans see exit_epoch == FAR (excluded), and
-#   * the proposer scatter-add receives a zero gain at index 0.
+#   * the proposer sums gain an exact zero (att_proposer 0, gain 0).
 # The `[V]` prefix of the padded program's outputs is therefore
 # bit-identical to the unpadded program (asserted differentially in
 # tests/test_multichip.py, including a non-divisible V).
@@ -481,8 +598,8 @@ def pad_validator_columns(cols: ValidatorColumns, vp: int,
 def pad_epoch_inputs(inp: EpochInputs, vp: int) -> EpochInputs:
     """Pad the [V] participation facts to [vp] rows with the neutral
     values build_epoch_inputs uses for non-participants (flags False,
-    inclusion delay 1, proposer 0, no crosslink committee); the two
-    replicated per-shard tables pass through. Host facts
+    inclusion delay 1, proposer 0, no crosslink committee); the
+    REPLICATED_INPUT_FIELDS pass through. Host facts
     (build_epoch_inputs_np) pad on the host and stay there, so that they
     can go from the host straight to their shards."""
     V = int(inp.prev_src.shape[0])
@@ -946,12 +1063,18 @@ def build_epoch_inputs_np(spec, state,
         shard_att_balance[shard] = att_bal
         shard_comm_balance[shard] = comm_bal[off]
 
+    # every value att_proposer holds is some attestation's proposer_index
+    proposer_table, proposer_rows = proposer_table_np(
+        [a.proposer_index for a in ctx.prev_atts],
+        proposer_table_capacity(spec))
+
     return EpochInputs(
         prev_src=prev_src, prev_tgt=prev_tgt, prev_head=prev_head,
         curr_tgt=curr_tgt, incl_delay=incl_delay, att_proposer=att_proposer,
         v_shard=v_shard, in_winning=in_winning,
         shard_att_balance=shard_att_balance,
-        shard_comm_balance=shard_comm_balance)
+        shard_comm_balance=shard_comm_balance,
+        proposer_table=proposer_table, proposer_rows=proposer_rows)
 
 
 def process_epoch_soa(spec, state, timings: dict = None):
@@ -1122,7 +1245,9 @@ def synthetic_epoch_state(cfg: EpochConfig, V: int, rng,
                           random_slashed_balances: bool = False):
     """Plausible random (cols, scal, inp) for benches/dryruns/mesh tests —
     the ONE example-state builder shared by bench.py, __graft_entry__, and
-    tests/test_multichip.py so placement/shape drift cannot split them."""
+    tests/test_multichip.py so placement/shape drift cannot split them.
+    Proposers are a block chain's: a few validators (one an including
+    block) listed in the proposer table, att_proposer drawn from them."""
     FAR = cfg.FAR_FUTURE_EPOCH
     MAX_EB = 32_000_000_000
     if random_eligibility:
@@ -1158,6 +1283,13 @@ def synthetic_epoch_state(cfg: EpochConfig, V: int, rng,
     comm_bal = np.maximum(
         np.full(cfg.SHARD_COUNT, (V // max(1, cfg.SHARD_COUNT)) * MAX_EB,
                 dtype=np.uint64), 1)
+    # proposers as a chain of blocks leaves them: every attester's
+    # att_proposer is one of the few validators that proposed an including
+    # block, and the table lists exactly those
+    proposers = rng.choice(V, size=min(V, including_blocks(cfg)),
+                           replace=False)
+    proposer_table, proposer_rows = proposer_table_np(
+        proposers, proposer_table_capacity(cfg))
     inp = EpochInputs(
         prev_src=jnp.asarray(rng.random(V) < 0.95),
         prev_tgt=jnp.asarray(rng.random(V) < 0.90),
@@ -1165,11 +1297,13 @@ def synthetic_epoch_state(cfg: EpochConfig, V: int, rng,
         curr_tgt=jnp.asarray(rng.random(V) < 0.90),
         incl_delay=jnp.asarray(
             rng.integers(1, incl_delay_max + 1, V).astype(np.uint64)),
-        att_proposer=jnp.asarray(rng.integers(0, V, V).astype(np.int32)),
+        att_proposer=jnp.asarray(rng.choice(proposers, V).astype(np.int32)),
         v_shard=jnp.asarray(rng.integers(0, cfg.SHARD_COUNT, V).astype(np.int32)),
         in_winning=jnp.asarray(rng.random(V) < 0.90),
         shard_att_balance=jnp.asarray((comm_bal * 9) // 10 + 1),
         shard_comm_balance=jnp.asarray(comm_bal),
+        proposer_table=jnp.asarray(proposer_table),
+        proposer_rows=jnp.asarray(proposer_rows),
     )
     return cols, scal, inp
 
@@ -1217,10 +1351,17 @@ TRACE_CONTRACTS = [
 # 10M-validator ceiling, mainnet constants, traced over
 # ShapeDtypeStructs (nothing allocates 10M-row columns). What is
 # proven: effective-balance sums (10^7 * MAX_EFFECTIVE_BALANCE < 2^58),
-# base-reward products, the proposer scatter-add at full duplicate
-# fan-in, exit-queue/activation-queue counts, the int32 att_proposer
-# index at V = 10^7, and the slashing table's int64 3x window — none of
-# it can wrap uint64/int64/int32. What is DECLARED rather than proven:
+# base-reward products, the proposer sums with every attester paying ONE
+# table row (a chunk's masked sum over all V gains, added to the reward
+# the row had before the loop; the loop's traced trip count, at most
+# capacity / PROPOSER_CHUNK = 124 < the unroll window, is proven by
+# joining the carries of every turn it may leave at, with no declared
+# invariant), exit-queue counts and the activation cut's masked picks,
+# the int32 att_proposer / proposer_table index at V = 10^7, and the
+# slashing table's int64 3x window — none of it can wrap
+# uint64/int64/int32. The declared inputs: proposer_table in [-1, V - 1]
+# (-1 is the padding), proposer_rows in [0, capacity]. What is DECLARED
+# rather than proven:
 # saturating subtractions (`uint64:sub` — the where-masked balance
 # decrease idiom), the justification bitfield's shifted-out bit
 # (`uint64:shl`), ops/intmath.py's documented 128-bit wrap machinery
@@ -1237,9 +1378,11 @@ def _epoch_ranges_build(V: int = 10_000_000):
     cols = ValidatorColumns(u, u, u, u, b, u, u)
     scal = EpochScalars(*([S((), jnp.uint64)] * 6),
                         S((cfg.LATEST_SLASHED_EXIT_LENGTH,), jnp.uint64))
+    rows = proposer_table_capacity(cfg)
     inp = EpochInputs(b, b, b, b, u, S((V,), jnp.int32), S((V,), jnp.int32),
                       b, S((cfg.SHARD_COUNT,), jnp.uint64),
-                      S((cfg.SHARD_COUNT,), jnp.uint64))
+                      S((cfg.SHARD_COUNT,), jnp.uint64),
+                      S((rows,), jnp.int32), S((), jnp.int32))
     far = {"lo": 0, "hi": cfg.FAR_FUTURE_EPOCH}
     flag = {"lo": 0, "hi": 1}
     epoch = {"lo": 0, "hi": 1 << 19}          # ~12k years of epochs
@@ -1262,7 +1405,9 @@ def _epoch_ranges_build(V: int = 10_000_000):
             att_proposer={"lo": 0, "hi": V - 1},
             v_shard={"lo": -1, "hi": cfg.SHARD_COUNT - 1}, in_winning=flag,
             shard_att_balance={"lo": 1, "hi": 1 << 58},
-            shard_comm_balance={"lo": 1, "hi": 1 << 58}),
+            shard_comm_balance={"lo": 1, "hi": 1 << 58},
+            proposer_table={"lo": -1, "hi": V - 1},
+            proposer_rows={"lo": 0, "hi": rows}),
     )
     return dict(
         fn=lambda c, s, i: _epoch_transition_traced(cfg, c, s, i),
@@ -1298,7 +1443,8 @@ RANGE_CONTRACTS = [
 
 def _epoch_mem_build(V: int = 10_000_000):
     spec = _epoch_ranges_build(V)
-    return dict(fn=spec["fn"], args=spec["args"], donate_argnums=(0,))
+    return dict(fn=spec["fn"], args=spec["args"], donate_argnums=(0,),
+                fused_calls=(_proposer_chunk_sums.__name__,))
 
 
 MEM_CONTRACTS = [

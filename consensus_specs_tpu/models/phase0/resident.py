@@ -69,8 +69,9 @@ from ...utils.ssz.incremental import (IncrementalMerkleTree,
                                       ShardedIncrementalMerkleTree)
 from ...utils.ssz.typing import Vector
 from . import helpers as helpers_mod
-from .epoch_soa import (EpochConfig, ValidatorColumns, build_epoch_context,
-                        build_epoch_inputs, build_epoch_inputs_np,
+from .epoch_soa import (REPLICATED_INPUT_FIELDS, EpochConfig,
+                        ValidatorColumns, build_epoch_context,
+                        build_epoch_inputs_np,
                         columns_np_from_state, inert_column_tail,
                         pad_epoch_inputs, pad_validator_columns,
                         process_crosslinks_vectorized, scalars_from_state,
@@ -822,17 +823,18 @@ class ResidentCore:
                         f"{self._tkey}.forest.bal.l0"):
                 _watchdog.forget(key)
 
-    def _stage_epoch_inputs(self, state, ctx) -> tuple:
-        """(scal, inp) for the boundary's dispatch, uploaded where the
-        program takes them: on the default device without a mesh; under a
-        mesh padded on the host to the columns' rows and put from the host
-        straight into `epoch_shardings()`'s placement (no `[V]` fact is
-        copied from chip to chip on its way to the program)."""
+    def _stage_epoch_inputs(self, state, inp) -> tuple:
+        """(scal, inp) for the boundary's dispatch, the host facts `inp`
+        (build_epoch_inputs_np) uploaded where the program takes them: on
+        the default device without a mesh; under a mesh padded on the host
+        to the columns' rows and put from the host straight into
+        `epoch_shardings()`'s placement (no `[V]` fact is copied from chip
+        to chip on its way to the program)."""
         if self._mesh is None:
+            import jax.numpy as jnp
             return (scalars_from_state(state),
-                    build_epoch_inputs(self.spec, state, ctx))
-        inp = pad_epoch_inputs(build_epoch_inputs_np(self.spec, state, ctx),
-                               int(self.cols.balance.shape[0]))
+                    jax.tree_util.tree_map(jnp.asarray, inp))
+        inp = pad_epoch_inputs(inp, int(self.cols.balance.shape[0]))
         return self._mesh.place_epoch_inputs(scalars_np_from_state(state),
                                              inp)
 
@@ -922,9 +924,9 @@ class ResidentCore:
         back to the logical rows (the single-device rung's recovery)."""
         import jax.numpy as jnp
         scal, inp = jax.device_get((scal, inp))
-        tables = {"shard_att_balance", "shard_comm_balance"}    # not [V]
         inp = inp._replace(**{f: getattr(inp, f)[:self._v]
-                              for f in inp._fields if f not in tables})
+                              for f in inp._fields
+                              if f not in REPLICATED_INPUT_FIELDS})
         return jax.tree_util.tree_map(jnp.asarray, (scal, inp))
 
     def process_epoch_resident(self, state) -> None:
@@ -943,7 +945,7 @@ class ResidentCore:
         boundaries."""
         spec = self.spec
         with telemetry.span("resident.stage") as sp_stage:
-            with telemetry.span("resident.stage.distill"):
+            with telemetry.span("resident.stage.distill") as sp_distill:
                 current_epoch = spec.get_current_epoch(state)
                 previous_epoch = spec.get_previous_epoch(state)
                 ctx = build_epoch_context(spec, state, dict(
@@ -952,7 +954,11 @@ class ResidentCore:
                     withdrawable_epoch=None,
                     balance=None))
                 process_crosslinks_vectorized(spec, state, ctx)
-                scal, inp = self._stage_epoch_inputs(state, ctx)
+                facts = build_epoch_inputs_np(spec, state, ctx)
+                # how hard the epoch program's proposer sum works: its
+                # loop runs over this many table rows
+                sp_distill.note(proposer_rows=int(facts.proposer_rows))
+                scal, inp = self._stage_epoch_inputs(state, facts)
             with telemetry.span("resident.stage.upload") as sp_up:
                 sp_up.fence(scal, inp)  # uploads land in "resident.stage"
 

@@ -4,9 +4,10 @@ Placement contract (SURVEY.md §2c: the registry is the protocol's
 embarrassingly-parallel axis):
   - every `[V]` column of ValidatorColumns / EpochInputs shards over the
     mesh's "v" axis;
-  - scalars and per-shard tables (EpochScalars, the two shard-balance
-    tables) replicate — they feed cross-shard reductions XLA lowers to
-    psum/all-gather collectives over ICI.
+  - scalars and small tables (EpochScalars, the two shard-balance
+    tables, the proposer table and its row count) replicate — they feed
+    cross-shard reductions XLA lowers to psum/all-gather collectives
+    over ICI.
 """
 from __future__ import annotations
 
@@ -19,8 +20,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.phase0.epoch_soa import (
-    EpochInputs, EpochReport, EpochScalars, ValidatorColumns,
-    _epoch_transition_traced)
+    REPLICATED_INPUT_FIELDS, EpochInputs, EpochReport, EpochScalars,
+    ValidatorColumns, _epoch_transition_traced)
 from ..resilience import faults as _faults
 from ..resilience.dispatch import RETRIES_DEFAULT, guarded_dispatch
 from ..utils.donation import platform_donated_jit
@@ -40,12 +41,14 @@ def validator_mesh(devices=None, n: int = None) -> Mesh:
     return Mesh(np.asarray(devices), axis_names=("v",))
 
 
-# EpochInputs placement convention: every field is a [V] participation-fact
-# column EXCEPT the trailing two per-shard balance tables, which replicate.
-# Single definition shared by shard_epoch_state and ServingMesh.
-_N_INPUT_VCOLS = len(EpochInputs._fields) - 2
-assert EpochInputs._fields[_N_INPUT_VCOLS:] == (
-    "shard_att_balance", "shard_comm_balance")
+def _epoch_input_shardings(shard_v, replicated) -> EpochInputs:
+    """EpochInputs placement convention: every field is a [V]
+    participation-fact column EXCEPT epoch_soa.REPLICATED_INPUT_FIELDS
+    (the small tables and the row count), which replicate. Single
+    definition shared by shard_epoch_state and ServingMesh."""
+    return EpochInputs(**{
+        f: replicated if f in REPLICATED_INPUT_FIELDS else shard_v
+        for f in EpochInputs._fields})
 
 
 def shard_epoch_state(mesh: Mesh, cols: ValidatorColumns, scal: EpochScalars,
@@ -55,12 +58,7 @@ def shard_epoch_state(mesh: Mesh, cols: ValidatorColumns, scal: EpochScalars,
     repl = NamedSharding(mesh, P())
     cols_s = ValidatorColumns(*(jax.device_put(x, shard_v) for x in cols))
     scal_s = EpochScalars(*(jax.device_put(x, repl) for x in scal))
-    n_vcols = _N_INPUT_VCOLS
-    inp_s = EpochInputs(
-        *(jax.device_put(x, shard_v) for x in inp[:n_vcols]),
-        shard_att_balance=jax.device_put(inp.shard_att_balance, repl),
-        shard_comm_balance=jax.device_put(inp.shard_comm_balance, repl),
-    )
+    inp_s = jax.device_put(inp, _epoch_input_shardings(shard_v, repl))
     return cols_s, scal_s, inp_s
 
 
@@ -269,16 +267,14 @@ class ServingMesh:
         return (
             ValidatorColumns(*([self.shard_v] * len(ValidatorColumns._fields))),
             EpochScalars(*([self.replicated] * len(EpochScalars._fields))),
-            EpochInputs(*([self.shard_v] * _N_INPUT_VCOLS),
-                        shard_att_balance=self.replicated,
-                        shard_comm_balance=self.replicated),
+            _epoch_input_shardings(self.shard_v, self.replicated),
         )
 
     def place_epoch_inputs(self, scal, inp):
         """(scal, inp) from the HOST to where the epoch program takes them
         (`epoch_shardings`): each `[Vp]` fact's rows go to their own
-        shard's device, the scalars and the two shard tables to every
-        device. `inp` is already padded to a mesh multiple
+        shard's device, the scalars, the small tables and the row count
+        to every device. `inp` is already padded to a mesh multiple
         (epoch_soa.pad_epoch_inputs on the host arrays). Facts uploaded
         to one device first would be re-laid-out chip to chip by the
         program's in_shardings, inside the dispatch."""

@@ -13,7 +13,9 @@ import pytest
 
 from consensus_specs_tpu.crypto import bls
 from consensus_specs_tpu.models import phase0
-from consensus_specs_tpu.models.phase0.epoch_soa import process_epoch_soa
+from consensus_specs_tpu.models.phase0.epoch_soa import (
+    build_epoch_context, build_epoch_inputs_np, process_epoch_soa,
+    proposer_table_capacity)
 from consensus_specs_tpu.testing.cases.finality import attested_epoch
 from consensus_specs_tpu.testing.factories import (
     advance_epoch as next_epoch,
@@ -121,6 +123,214 @@ def test_slashed_and_ejected_validators(spec):
         state.balances[i] = max(0, state.balances[i] - rng.randrange(0, 3 * 10 ** 9))
 
     assert_same_epoch_transition(spec, state)
+
+
+# ---------------------------------------------------------------------------
+# The proposer table and the activation cut (PR 29): the epoch program sums
+# proposer rewards through a table of the epoch's distinct proposers and
+# cuts the activation queue by one threshold element. Each case builds a
+# boundary state that drives one corner of either, says so with an assert
+# on the distilled inputs, and is held to spec.process_epoch by state root.
+# ---------------------------------------------------------------------------
+
+def _attested_state(spec, validators):
+    """A boundary-ready state whose two attestation lists blocks filled
+    (one proposer a slot)."""
+    state = create_genesis_state(spec, validators)
+    next_epoch(spec, state)
+    apply_empty_block(spec, state)
+    _, _, state = attested_epoch(spec, state, current=True, previous=True)
+    return state
+
+
+def _enqueue(spec, state, eligibility_epochs):
+    """Fresh validators on the activation queue, one an entry, eligible
+    since the given epoch."""
+    from consensus_specs_tpu.testing.factories import seed_validator
+    for e in eligibility_epochs:
+        nv = seed_validator(spec, len(state.validator_registry),
+                            spec.MAX_EFFECTIVE_BALANCE)
+        nv.activation_eligibility_epoch = e
+        state.validator_registry.append(nv)
+        state.balances.append(spec.MAX_EFFECTIVE_BALANCE)
+
+
+def _one_proposer_an_attester(spec, state):
+    """Rewrite the previous epoch's attestations as one single-signer
+    attestation a committee member, each with a proposer of its own (the
+    benchmark generator's shape, taken to its end)."""
+    singles = []
+    for a in state.previous_epoch_attestations:
+        size = len(spec.get_crosslink_committee(
+            state, a.data.target_epoch, a.data.crosslink.shard))
+        for bit in range(size):
+            one = deepcopy(a)
+            field = bytearray((size + 7) // 8)
+            field[bit // 8] |= 1 << (bit % 8)
+            one.aggregation_bitfield = bytes(field)
+            one.proposer_index = len(singles)
+            one.inclusion_delay = 1 + len(singles) % 3
+            singles.append(one)
+    state.previous_epoch_attestations = singles
+
+
+def _case_queue_longer_than_churn_with_ties(preset):
+    spec = phase0.get_spec(preset)
+    state = _attested_state(spec, spec.SLOTS_PER_EPOCH * 8)
+    # churn is 4: the cut falls inside the five rows tied at epoch 2
+    _enqueue(spec, state, [3, 2, 2, 2, 2, 2, 1])
+    return spec, state, dict(dequeued=4, still_queued=3)
+
+
+def _case_queue_shorter_than_churn(preset):
+    spec = phase0.get_spec(preset)
+    state = _attested_state(spec, spec.SLOTS_PER_EPOCH * 8)
+    _enqueue(spec, state, [2, 1])
+    return spec, state, dict(dequeued=2, still_queued=0)
+
+
+def _case_queue_empty(preset):
+    spec = phase0.get_spec(preset)
+    state = _attested_state(spec, spec.SLOTS_PER_EPOCH * 8)
+    return spec, state, dict(dequeued=0, still_queued=0)
+
+
+def _case_churn_at_least_v(preset):
+    spec = phase0.get_spec(preset.replace(MIN_PER_EPOCH_CHURN_LIMIT=10_000))
+    state = _attested_state(spec, spec.SLOTS_PER_EPOCH * 8)
+    _enqueue(spec, state, [3, 2, 2, 2, 2, 2, 1])
+    return spec, state, dict(dequeued=7, still_queued=0)
+
+
+def _case_one_proposer_a_slot(preset):
+    spec = phase0.get_spec(preset)
+    state = _attested_state(spec, spec.SLOTS_PER_EPOCH * 8)
+    rows = len({a.proposer_index for a in state.previous_epoch_attestations})
+    assert 1 < rows <= 2 * spec.SLOTS_PER_EPOCH - 1
+    return spec, state, dict(proposer_rows=rows,
+                             table=proposer_table_capacity(spec))
+
+
+def _case_one_proposer_an_attestation(preset):
+    spec = phase0.get_spec(preset)
+    state = _attested_state(spec, spec.SLOTS_PER_EPOCH * 24)
+    _one_proposer_an_attester(spec, state)
+    # more than one chunk of the table
+    return spec, state, dict(proposer_rows=spec.SLOTS_PER_EPOCH * 24,
+                             table=proposer_table_capacity(spec))
+
+
+def _case_more_proposers_than_capacity(preset):
+    spec = phase0.get_spec(preset.replace(MAX_ATTESTATIONS=8))
+    assert proposer_table_capacity(spec) == 128
+    state = _attested_state(spec, spec.SLOTS_PER_EPOCH * 24)
+    _one_proposer_an_attester(spec, state)
+    return spec, state, dict(proposer_rows=spec.SLOTS_PER_EPOCH * 24,
+                             table=2 * proposer_table_capacity(spec))
+
+
+def _case_proposer_attests_and_proposed_twice(preset):
+    spec = phase0.get_spec(preset)
+    state = _attested_state(spec, spec.SLOTS_PER_EPOCH * 8)
+    atts = state.previous_epoch_attestations
+    # a member of the first attestation's committee included it and the next
+    member = spec.get_crosslink_committee(
+        state, atts[0].data.target_epoch, atts[0].data.crosslink.shard)[0]
+    atts[0].proposer_index = atts[1].proposer_index = member
+    rows = len({a.proposer_index for a in atts})
+    return spec, state, dict(proposer_rows=rows,
+                             table=proposer_table_capacity(spec))
+
+
+@pytest.mark.parametrize("case", [
+    _case_queue_longer_than_churn_with_ties,
+    _case_queue_shorter_than_churn,
+    _case_queue_empty,
+    _case_churn_at_least_v,
+    _case_one_proposer_a_slot,
+    _case_one_proposer_an_attestation,
+    _case_more_proposers_than_capacity,
+    _case_proposer_attests_and_proposed_twice,
+], ids=lambda c: c.__name__[len("_case_"):])
+def test_proposer_table_and_activation_cut_parity(case):
+    from consensus_specs_tpu.utils.config import load_preset
+
+    spec, state, expect = case(load_preset("minimal"))
+    spec.process_slots(state, state.slot + spec.SLOTS_PER_EPOCH - 1
+                       - state.slot % spec.SLOTS_PER_EPOCH)
+    far = spec.FAR_FUTURE_EPOCH
+    waiting = [i for i, v in enumerate(state.validator_registry)
+               if v.activation_epoch == far
+               and v.activation_eligibility_epoch != far]
+
+    # the case drives what its name says
+    facts = build_epoch_inputs_np(spec, deepcopy(state),
+                                  build_epoch_context(spec, state))
+    if "proposer_rows" in expect:
+        assert int(facts.proposer_rows) == expect["proposer_rows"]
+        assert facts.proposer_table.shape == (expect["table"],)
+
+    ref = assert_same_epoch_transition(spec, state)
+    if "dequeued" in expect:
+        left = sum(ref.validator_registry[i].activation_epoch == far
+                   for i in waiting)
+        assert (len(waiting) - left, left) == (
+            expect["dequeued"], expect["still_queued"])
+
+
+def _scatter_updates(jaxpr):
+    """Shapes of the updates operand of every scatter in a jaxpr, its
+    sub-jaxprs (loops, calls, branches) included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("scatter"):
+            found.append((eqn.primitive.name,
+                          tuple(eqn.invars[2].aval.shape)))
+        for val in eqn.params.values():
+            for item in (val if isinstance(val, (tuple, list)) else (val,)):
+                inner = getattr(item, "jaxpr", item)
+                if hasattr(inner, "eqns"):
+                    found += _scatter_updates(inner)
+    return found
+
+
+def test_epoch_program_scatters_no_row_of_the_validator_axis(spec):
+    """The two serial loops cannot come back unnoticed: at two sizes of V
+    the traced program holds the same scatters, none with a V-sized
+    update (what is left writes one chunk of proposer-table rows and one
+    latest_slashed_balances entry), and a state with another number of
+    proposers runs the program compiled for the first."""
+    import jax
+    from functools import partial
+
+    from consensus_specs_tpu.models.phase0.epoch_soa import (
+        PROPOSER_CHUNK, EpochConfig, _epoch_transition_traced,
+        proposer_table_capacity, proposer_table_np, synthetic_epoch_state)
+
+    cfg = EpochConfig.from_spec(spec)
+    program = partial(_epoch_transition_traced, cfg)
+    scatters = {}
+    for V in (256, 1024):
+        args = synthetic_epoch_state(cfg, V, np.random.default_rng(V))
+        scatters[V] = _scatter_updates(jax.make_jaxpr(program)(*args).jaxpr)
+        assert all(V not in shape for _, shape in scatters[V]), scatters[V]
+    assert scatters[256] == scatters[1024]
+    assert sorted(shape for _, shape in scatters[256]) \
+        == [(), (PROPOSER_CHUNK,)]
+
+    jitted = jax.jit(program)
+    cols, scal, inp = synthetic_epoch_state(cfg, 256, np.random.default_rng(1))
+    outs = []
+    for rows in (3, 2 * PROPOSER_CHUNK + 1):      # one chunk, three chunks
+        proposers = np.arange(rows, dtype=np.int32)
+        table, n = proposer_table_np(proposers, proposer_table_capacity(cfg))
+        outs.append(jitted(cols, scal, inp._replace(
+            att_proposer=jax.numpy.asarray(proposers[np.arange(256) % rows]),
+            proposer_table=jax.numpy.asarray(table),
+            proposer_rows=jax.numpy.asarray(n))))
+    assert jitted._cache_size() == 1
+    # and the row count is read: the same attesters pay other proposers
+    assert not np.array_equal(outs[0][0].balance, outs[1][0].balance)
 
 
 def test_epoch_transition_donates_column_buffers(spec):

@@ -4,7 +4,7 @@ The protocol's data-parallel axis is the validator registry (SURVEY.md §2c);
 these tests jit the SAME epoch program once per placement — all inputs on
 one device vs `[V]` columns sharded over an explicit 8-device Mesh — and
 require bit-identical outputs. XLA inserts the cross-shard collectives
-(balance-sum reductions, proposer scatter-add, activation-queue sort);
+(balance-sum reductions, the proposer sums, the activation-queue sort);
 equality proves the sharded program is semantically the single-chip one.
 
 Runs on the virtual 8-device CPU mesh the conftest pins; the driver's
@@ -225,6 +225,60 @@ def test_serving_mesh_epoch_padded_equals_single(serving_mesh):
     assert sh2_cols.balance.sharding.is_equivalent_to(mesh.shard_v, 1)
 
 
+def test_mesh4_proposer_table_and_activation_cut_equal_single():
+    """PR 29's two mechanisms across shards, on the four-device layout of
+    the benchmark's mesh cell: a V that is no multiple of four (inert
+    rows), proposers on every shard and more of them than one chunk of the
+    table, an activation queue longer than the churn whose tied rows lie
+    on several shards. The sharded boundary is the one-device boundary,
+    bit for bit, and so is the next one chained onto it."""
+    import jax.numpy as jnp
+    from consensus_specs_tpu.models.phase0.epoch_soa import (
+        PROPOSER_CHUNK, pad_epoch_inputs, pad_validator_columns,
+        proposer_table_capacity, proposer_table_np)
+    from consensus_specs_tpu.parallel import ServingMesh
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 devices")
+    mesh = ServingMesh.create(4)
+    cfg = EpochConfig.from_spec(phase0.get_spec("minimal"))
+    V = 4 * 150 + 2
+    rng = np.random.default_rng(29)
+    cols, scal, inp = synthetic_epoch_state(
+        cfg, V, rng, random_eligibility=True, random_slashed_balances=True)
+    vp = mesh.pad_rows(V)
+    proposers = rng.choice(V, size=2 * PROPOSER_CHUNK + 9, replace=False)
+    assert set(proposers // (vp // 4)) == {0, 1, 2, 3}
+    table, rows = proposer_table_np(proposers, proposer_table_capacity(cfg))
+    inp = inp._replace(
+        att_proposer=jnp.asarray(rng.choice(proposers, V).astype(np.int32)),
+        proposer_table=jnp.asarray(table), proposer_rows=jnp.asarray(rows))
+    queued = (np.asarray(cols.activation_eligibility_epoch) == 0) \
+        & (np.asarray(cols.activation_epoch) == cfg.FAR_FUTURE_EPOCH)
+    assert queued.sum() > cfg.MIN_PER_EPOCH_CHURN_LIMIT
+    assert len(set(np.nonzero(queued)[0] // (vp // 4))) > 1
+
+    cols_p = pad_validator_columns(cols, vp, cfg.FAR_FUTURE_EPOCH)
+    inp_p = pad_epoch_inputs(inp, vp)
+    single = epoch_transition_device(cfg, cols, scal, inp)
+    # the cut admitted exactly the churn, the rest of the queue waits
+    admitted = queued & (np.asarray(single[0].activation_epoch)
+                         != cfg.FAR_FUTURE_EPOCH)
+    assert admitted.sum() == cfg.MIN_PER_EPOCH_CHURN_LIMIT
+
+    sh_cols, sh_scal, sh_rep = mesh.epoch_transition(cfg, cols_p, scal, inp_p)
+    assert trees_bitwise_equal(single[0],
+                               type(sh_cols)(*[x[:V] for x in sh_cols]))
+    assert trees_bitwise_equal(single[1:], (sh_scal, sh_rep))
+    nxt = jnp.uint64(cfg.SLOTS_PER_EPOCH)
+    sh2_cols, _, _ = mesh.epoch_transition(
+        cfg, sh_cols, sh_scal._replace(slot=sh_scal.slot + nxt), inp_p)
+    single2 = epoch_transition_device(
+        cfg, single[0], single[1]._replace(slot=single[1].slot + nxt), inp)
+    assert trees_bitwise_equal(
+        single2[0], type(sh2_cols)(*[x[:V] for x in sh2_cols]))
+
+
 def test_serving_mesh_forest_leaf_builders_match_oracle(serving_mesh):
     """registry_forest_leaves / balances_forest_chunks: inert padding rows
     mask to the SSZ virtual-zero rows, real rows equal the single-device
@@ -289,13 +343,13 @@ def test_hierarchical_mesh_epoch_equals_single():
     import jax as _jax
     from jax.sharding import NamedSharding, PartitionSpec
     repl = NamedSharding(hmesh, PartitionSpec())
-    inp_s = inp._replace(
-        shard_att_balance=_jax.device_put(inp.shard_att_balance, repl),
-        shard_comm_balance=_jax.device_put(inp.shard_comm_balance, repl))
-    inp_s = inp_s._replace(**{
+    from consensus_specs_tpu.models.phase0.epoch_soa import (
+        REPLICATED_INPUT_FIELDS)
+    flat = NamedSharding(hmesh, PartitionSpec(("host", "v")))
+    inp_s = inp._replace(**{
         f: _jax.device_put(getattr(inp, f),
-                           NamedSharding(hmesh, PartitionSpec(("host", "v"))))
-        for f in inp._fields[:-2]})
+                           repl if f in REPLICATED_INPUT_FIELDS else flat)
+        for f in inp._fields})
     sharded = jax.device_get(epoch_transition_device(cfg, cols_s, scal_s, inp_s))
     assert trees_bitwise_equal(single, sharded)
 

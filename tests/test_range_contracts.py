@@ -17,6 +17,7 @@ import json
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 from consensus_specs_tpu.ops import fq as F
 from consensus_specs_tpu.ops import fq_tower as T
@@ -280,6 +281,52 @@ def test_inductive_invariant_proves_long_loop(tmp_path):
     report = engine.run_contracts([c], baseline={})
     assert "CSA1401" not in _rules(report)
     assert "CSA1403" not in _rules(report)
+
+
+def _traced_trips(step):
+    """fori_loop over a TRACED trip count (lowers to `while`): the epoch
+    program's proposer sum loops over ceil(rows / 128) chunks."""
+    def fn(n, x):
+        return jax.lax.fori_loop(0, n, lambda i, a: step(a, x), x)
+    return fn
+
+
+@pytest.mark.parametrize("step, hi, proves", [
+    # every turn writes the same bound: the join over the turns the loop
+    # may leave at is that bound, whatever the trip count
+    (lambda a, x: jnp.maximum(a, x + jnp.int64(7)), 107, True),
+    # every turn adds: after the 100th possible turn the carry has grown
+    # by 100 steps, and a bound one turn short fails
+    (lambda a, x: a + jnp.int64(1), 200, True),
+    (lambda a, x: a + jnp.int64(1), 199, False),
+], ids=["stable", "growing", "growing_one_short"])
+def test_bounded_traced_trip_count_joins_the_exits(tmp_path, step, hi, proves):
+    """A `while` whose trip count is traced but bounded proves without an
+    invariant: the interpreter unrolls while the loop MAY go on, joins the
+    carries of every turn at which it may leave, and stops at the turn
+    where the decision is definitely no."""
+    c = _contract(
+        tmp_path,
+        build=lambda: dict(fn=_traced_trips(step),
+                           args=(jnp.int64(0), jnp.int64(0)),
+                           ranges=({"lo": 0, "hi": 100},
+                                   {"lo": 0, "hi": 100})),
+        output={"lo": 0, "hi": hi})
+    report = engine.run_contracts([c], baseline={})
+    assert "CSA1403" not in _rules(report)
+    assert ("CSA1401" not in _rules(report)) == proves
+
+
+def test_traced_trip_count_past_the_window_needs_an_invariant(tmp_path):
+    """Past the unroll window the bounded-trip path gives way to the
+    declared-invariant path, as before."""
+    c = _contract(
+        tmp_path,
+        build=lambda: dict(fn=_traced_trips(lambda a, x: a + jnp.int64(1)),
+                           args=(jnp.int64(0), jnp.int64(0)),
+                           ranges=({"lo": 0, "hi": 100_000},
+                                   {"lo": 0, "hi": 100})))
+    assert "CSA1403" in _rules(engine.run_contracts([c], baseline={}))
 
 
 # ---------------------------------------------------------------------------

@@ -127,3 +127,25 @@ def test_shuffle_program_compiles(one_chip):
     from consensus_specs_tpu.ops.shuffle import _shuffle_rounds
     _compile(_shuffle_rounds, one_chip,
              ((8,), jnp.uint32), ((90,), jnp.int32), n=V, rounds=90)
+
+
+def test_proposer_sum_holds_no_wide_buffer(one_chip):
+    """The epoch program's proposer sum (epoch_soa._add_proposer_rewards)
+    at the 1M registry and the mainnet table: the compare-select-reduce
+    over [V, 128] must stay inside the reduction's fusion. One such
+    operand materialised is 128 MB as bool and 1 GB as uint64 a chunk;
+    the memory tier's contract declares the call fused (`fused_calls`)
+    on the strength of this compile."""
+    from consensus_specs_tpu.models import phase0
+    from consensus_specs_tpu.models.phase0.epoch_soa import (
+        PROPOSER_CHUNK, _add_proposer_rewards, proposer_table_capacity)
+    rows = proposer_table_capacity(phase0.get_spec("mainnet"))
+    assert rows == 15_872 and rows % PROPOSER_CHUNK == 0
+    compiled = _compile(_add_proposer_rewards, one_chip,
+                        ((V,), jnp.uint64), ((V,), jnp.int32),
+                        ((V,), jnp.uint64), ((rows,), jnp.int32),
+                        ((), jnp.int32))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < V * PROPOSER_CHUNK // 8, \
+        f"{temp / 2**20:.0f} MiB of temporaries: a [V, 128] operand is held"
+    assert " while(" in compiled.as_text()      # the traced trip count

@@ -221,7 +221,8 @@ def _trace(spec):
 
 def _analyze_spec(spec, bytes_fn=L.aval_bytes) -> L.Liveness:
     closed, donated = _trace(spec)
-    return L.analyze(closed, donated=donated, bytes_fn=bytes_fn)
+    return L.analyze(closed, donated=donated, bytes_fn=bytes_fn,
+                     fused_calls=tuple(spec.get("fused_calls", ())))
 
 
 def _vmem_bytes(vmem: dict) -> int:
@@ -321,7 +322,8 @@ def _measure(contract: dict):
     if "build" in contract:
         spec = contract["build"]()
         closed, donated = _trace(spec)
-        model = L.analyze(closed, donated=donated)
+        fused_calls = tuple(spec.get("fused_calls", ()))
+        model = L.analyze(closed, donated=donated, fused_calls=fused_calls)
         res.measured["peak_bytes"] = model.peak_bytes
         res.measured["temp_bytes"] = model.temp_bytes
         res.detail["arg_bytes"] = model.arg_bytes
@@ -352,7 +354,7 @@ def _measure(contract: dict):
         if sharded:
             n = int(sharded["devices"])
             shard_model = L.analyze(
-                closed, donated=donated,
+                closed, donated=donated, fused_calls=fused_calls,
                 bytes_fn=L.sharded_bytes_fn(n, int(sharded["min_elems"])))
             cap = int(sharded["replicated_cap_bytes"])
             bound = -(-model.peak_bytes // n) + cap

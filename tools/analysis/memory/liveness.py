@@ -22,7 +22,14 @@ reports it):
     eqn's operands and results) atop the live set carried across the
     eqn;
   * the modeled peak is the max, over eqns, of live bytes at that eqn
-    plus the eqn's transient contribution.
+    plus the eqn's transient contribution;
+  * every traced value is a buffer: the model knows no fusion. Where a
+    kernel leans on one (a compare-select-reduce over `[V, K]` that the
+    compiler runs inside the reduction's loop), it wraps that expression
+    in a named jit and its contract DECLARES the name (`fused_calls` in
+    the build spec): such a call contributes its operands and results
+    and no transient. Declared, never inferred, and backed by a compile
+    for the chip that holds the compiler to it (tests/test_tpu_compile.py).
 
 Per-shard footprints reuse the same walk with a different byte
 function: a leaf whose element count reaches the contract's sharding
@@ -145,11 +152,13 @@ def _match_donations(invars, outvars, donated: set,
 
 
 def analyze(closed, donated: Optional[set] = None,
-            bytes_fn: Callable = aval_bytes) -> Liveness:
+            bytes_fn: Callable = aval_bytes,
+            fused_calls: Tuple[str, ...] = ()) -> Liveness:
     """Walk a ClosedJaxpr and return the modeled peak liveness.
 
     `donated` holds FLAT invar indices (the engine expands jit-level
-    donate_argnums over each argument's leaves)."""
+    donate_argnums over each argument's leaves); `fused_calls` names the
+    nested jits whose bodies the contract declares fused (no transient)."""
     jaxpr = getattr(closed, "jaxpr", closed)
     donated = donated or set()
     res = Liveness(n_eqns=len(jaxpr.eqns))
@@ -195,9 +204,11 @@ def analyze(closed, donated: Optional[set] = None,
         # transient contribution of sub-jaxpr bodies beyond their own
         # I/O (already tracked as this eqn's operands and results)
         extra = 0
-        for sub in _sub_jaxprs(eqn):
-            inner = analyze(sub, bytes_fn=bytes_fn)
-            extra = max(extra, inner.temp_bytes)
+        if eqn.params.get("name") not in fused_calls:
+            for sub in _sub_jaxprs(eqn):
+                inner = analyze(sub, bytes_fn=bytes_fn,
+                                fused_calls=fused_calls)
+                extra = max(extra, inner.temp_bytes)
         prim = getattr(eqn.primitive, "name", str(eqn.primitive))
         if any(h in prim for h in _HOST_PRIMS):
             spanning = sum(b for vid, b in live.items()

@@ -22,8 +22,9 @@ or a `wrap_ok_sources` file match for e.g. ops/intmath.py's documented
 one finding, not a cascade.
 
 Loops (`while`/`scan`, what fori_loop lowers to) unroll abstractly while
-the trip decision stays definite and the count stays under
-`max_unroll`; past that the contract must supply the carry invariant
+the count stays under `max_unroll` (a `while` whose decision is open,
+a traced but bounded trip count, joins the carries of every turn it may
+leave at); past that the contract must supply the carry invariant
 and the interpreter checks the body maps invariant -> invariant
 (CSA1401 if not, CSA1403 if none declared), widening on failure.
 
@@ -280,6 +281,14 @@ class Interp:
         if len(val.vec) == n:
             return val.vec
         return (val.hull(),) * n
+
+    def _joined(self, a: AbsVal, b: AbsVal) -> AbsVal:
+        """The join of two abstract values of one shape and dtype."""
+        n = max(len(a.vec), len(b.vec))
+        return AbsVal(a.shape, a.dtype,
+                      tuple(I.join(p, q) for p, q in zip(
+                          self._aligned(a, n), self._aligned(b, n))),
+                      a.tainted or b.tainted)
 
     def _ew(self, eqn, vals, fn, kind=None) -> AbsVal:
         out_aval = eqn.outvars[0].aval
@@ -1078,15 +1087,19 @@ def _while(self, eqn, vals):
     cond_consts, body_consts = vals[:cn], vals[cn:cn + bn]
     carry = list(vals[cn + bn:])
     init = list(carry)
+    # a trip count that is traced but bounded (`i < n`, n in [0, 127]):
+    # the loop may leave at every turn where the decision is open, so the
+    # result is the join of the carries at those turns, and the unrolling
+    # ends at the turn where the decision is definitely no
+    left = None
     for _ in range(self.max_unroll):
         pred = self.eval_closed(cond, cond_consts + carry)[0].hull()
-        if pred == I.FALSE:
-            return carry
         if pred != I.TRUE:
-            break
+            left = carry if left is None else [
+                self._joined(a, b) for a, b in zip(left, carry)]
+        if pred == I.FALSE:
+            return left
         carry = self.eval_closed(body, body_consts + carry)
-    else:
-        pred = I.BOOL
     inv, check = _loop_fallback(self, eqn, body, body_consts, init,
                                 len(init), "while loop")
     if check:
@@ -1123,14 +1136,7 @@ def _scan(self, eqn, vals):
             if ys_join[i] is None:
                 ys_join[i] = y
             else:
-                prev = ys_join[i]
-                n = max(len(prev.vec), len(y.vec))
-                pv = self._aligned(prev, n)
-                yv = self._aligned(y, n)
-                ys_join[i] = AbsVal(y.shape, y.dtype,
-                                    tuple(I.join(p, q)
-                                          for p, q in zip(pv, yv)),
-                                    prev.tainted or y.tainted)
+                ys_join[i] = self._joined(ys_join[i], y)
 
     if length <= self.max_unroll:
         for _ in range(length):
