@@ -8,7 +8,7 @@ PYTHON ?= python
 VECTOR_DIR ?= out/vectors
 JUNIT ?= out/test-results.xml
 
-.PHONY: test testall citest citest-cov citest-mainnet lint analyze contracts ranges lifetime memory vectors vectors-minimal chip-smoke bench bench-cpu multichip telemetry chaos firehose smoke clean
+.PHONY: test testall citest citest-cov citest-mainnet lint analyze contracts ranges lifetime memory vectors vectors-minimal chip-smoke multichip telemetry chaos firehose smoke clean
 
 # measured 90.64% on the round-5 full suite; floor set just under so real
 # regressions fail while normal drift doesn't
@@ -46,7 +46,7 @@ citest-mainnet:
 
 # Syntax + style gate (see tools/lint.py; no third-party linters in image).
 lint:
-	$(PYTHON) tools/lint.py consensus_specs_tpu tests bench.py chip_smoke.py __graft_entry__.py tools
+	$(PYTHON) tools/lint.py consensus_specs_tpu tests chip_smoke.py __graft_entry__.py tools
 
 # Trace-safety / spec-conformance static analysis (tools/analysis/README.md):
 # ten pass families over the call-graph IR — Python control flow on
@@ -60,7 +60,7 @@ lint:
 # `# csa: ignore[...]` suppressions. JSON artifact: out/analysis.json.
 REFERENCE_ROOT ?= /root/reference
 analyze:
-	$(PYTHON) -m tools.analysis consensus_specs_tpu bench.py chip_smoke.py __graft_entry__.py \
+	$(PYTHON) -m tools.analysis consensus_specs_tpu chip_smoke.py __graft_entry__.py \
 		--baseline tools/analysis/baseline.json --json out/analysis.json \
 		--reference-root $(REFERENCE_ROOT)
 
@@ -136,21 +136,13 @@ vectors:
 vectors-minimal:
 	$(PYTHON) -m consensus_specs_tpu.generators -o $(VECTOR_DIR) -p minimal
 
-# The chip's quickest proof: the resident serving path at mainnet 1M on
-# one TPU chip, one process (exits non-zero where jax finds no TPU).
+# What no benchmark cell drives, on one TPU chip: the device check and the
+# Mosaic pair-hash kernel (`--bls`: one block through the jax BLS backend).
+# Exits non-zero where jax finds no TPU. The served path's bring-up is
+# `python3 benchmark/run.py --workload mainnet-1m.replay --seed 1
+# --seconds 51 --trace 0`.
 chip-smoke:
 	$(PYTHON) chip_smoke.py
-
-# Headline benchmark on whatever jax.devices() gives (a failing stage
-# exits non-zero; CSTPU_BENCH_CPU=1 pins the host CPU to smoke the harness).
-bench:
-	$(PYTHON) bench.py
-
-# Harness smoke: the identical harness pinned to XLA:CPU at V=65536
-# (override V); its numbers are not device numbers.
-bench-cpu:
-	CSTPU_BENCH_CPU=1 CSTPU_BENCH_V=$(or $(V),65536) \
-	CSTPU_BENCH_ATT=32 $(PYTHON) bench.py
 
 # The driver's multi-chip dry run, locally on 8 virtual devices.
 multichip:
@@ -178,9 +170,8 @@ chaos:
 # aggregates accumulated across slot ticks into full device batches,
 # flushed at an armed deadline. Exits non-zero on any streamed-vs-
 # synchronous verdict mismatch, watchdog event, or deadline miss.
-# Artifact: out/firehose.json (CI uploads it). Bench runs the committed
-# 128-group occupancy; the smoke shape defaults to 8 for speed
-# (CSTPU_FIREHOSE_GROUPS overrides).
+# Artifact: out/firehose.json (CI uploads it). The smoke shape defaults
+# to 8 groups for speed (CSTPU_FIREHOSE_GROUPS overrides).
 firehose:
 	$(PYTHON) tools/firehose_smoke.py
 
@@ -188,11 +179,11 @@ firehose:
 # fast test modules. `make contracts`, `make ranges`, `make lifetime`
 # and `make memory` ride here so an op-budget, value-range,
 # buffer-lifetime or memory-budget regression fails at smoke time,
-# before any bench run.
+# before any benchmark run.
 smoke:
-	$(PYTHON) tools/lint.py consensus_specs_tpu tests bench.py chip_smoke.py __graft_entry__.py tools
+	$(PYTHON) tools/lint.py consensus_specs_tpu tests chip_smoke.py __graft_entry__.py tools
 	$(PYTHON) -m tools.analysis --list-rules >/dev/null
-	$(PYTHON) -m tools.analysis consensus_specs_tpu bench.py chip_smoke.py __graft_entry__.py \
+	$(PYTHON) -m tools.analysis consensus_specs_tpu chip_smoke.py __graft_entry__.py \
 		--baseline tools/analysis/baseline.json \
 		--reference-root $(REFERENCE_ROOT)
 	$(MAKE) contracts

@@ -510,13 +510,11 @@ def _epoch_transition_traced(cfg: EpochConfig, cols: ValidatorColumns,
 # input+output copies in HBM (the 1M-validator column set is ~7x8 MB —
 # donation halves its footprint during the epoch program). The twins
 # come from the shared platform_donated_jit helper (utils/donation.py);
-# both halves stay importable — tests assert the donation sticks (no
-# "donated buffer unused" warnings, input buffers consumed) against the
-# donated twin, and bench's recovery drill re-dispatches the undonated.
+# tests assert the donation sticks (no "donated buffer unused" warnings,
+# input buffers consumed) against the donated twin.
 _epoch_transition_pd = platform_donated_jit(
     _epoch_transition_traced, static_argnums=(0,), donate_argnums=(1,))
 _epoch_transition_donated = _epoch_transition_pd.donated
-_epoch_transition_undonated = _epoch_transition_pd.undonated
 
 
 def epoch_transition_device(cfg: EpochConfig, cols: ValidatorColumns,
@@ -1243,8 +1241,8 @@ def synthetic_epoch_state(cfg: EpochConfig, V: int, rng,
                           incl_delay_max: int = 8,
                           random_eligibility: bool = False,
                           random_slashed_balances: bool = False):
-    """Plausible random (cols, scal, inp) for benches/dryruns/mesh tests —
-    the ONE example-state builder shared by bench.py, __graft_entry__, and
+    """Plausible random (cols, scal, inp) for dryruns and mesh tests —
+    the ONE example-state builder shared by __graft_entry__ and
     tests/test_multichip.py so placement/shape drift cannot split them.
     Proposers are a block chain's: a few validators (one an including
     block) listed in the proposer table, att_proposer drawn from them."""

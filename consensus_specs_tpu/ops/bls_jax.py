@@ -675,7 +675,7 @@ def stage_example_groups(n_groups: int, n_distinct: int = 8
                          ) -> Tuple[np.ndarray, np.ndarray]:
     """Host-stage n_groups spec-shaped pair triples (negG1/sig, pk0/H(m,0),
     pk1/H(m,1)) with real signatures so every group verifies true — the
-    grouped-pairing example batch shared by bench.py, the mesh tests, and
+    grouped-pairing example batch shared by the mesh tests and
     dryrun_multichip (one staging source keeps their shapes identical, so
     the jit/persistent cache is shared too).
 
@@ -1039,8 +1039,8 @@ class JaxBackend:
 # traced at the spec shape (G = 1 group x P = 3 pairs) under BOTH
 # reduction backends. The exact lane pins make PR 5's headline cut a
 # standing machine-checked invariant: leaf/coeff whole-path lanes
-# (672 + 3094) / (396 + 967) = 2.76x, the >= 2.5x bound bench.py's
-# pairing_redc_ab row measures at runtime. Plus the cofactor-clearing
+# (672 + 3094) / (396 + 967) = 2.76x, the >= 2.5x bound
+# tests/test_fq_redc.py holds. Plus the cofactor-clearing
 # dependent-add model (PR 4's G2 headline), whose measured counterpart
 # is ops/scalar_mul.py's counted-chain contract.
 

@@ -231,7 +231,7 @@ def pinned_fq_redc_backend(name: str):
 # telemetry metrics registry (`fq.redc.instances` / `fq.redc.lanes`,
 # `always=True`: trace-time accounting that tests assert regardless of the
 # CSTPU_TELEMETRY switch); reset_redc_trace_stats/redc_trace_stats stay as
-# thin shims for bench.py's pairing_redc_ab row and tests/test_fq_redc.py.
+# thin shims for tests/test_fq_redc.py and tests/test_telemetry.py.
 _REDC_INSTANCES = _tele_counter("fq.redc.instances", always=True)
 _REDC_LANES = _tele_counter("fq.redc.lanes", always=True)
 

@@ -366,7 +366,7 @@ def scalar_mul_window() -> int:
 
 def sequential_adds(backend: str, nbits: int, w: Optional[int] = None) -> int:
     """Length of the dependent jac_add chain one scalar mul executes —
-    the critical-path currency bench.py's scalar_mul_ab row reports."""
+    the critical-path currency of the window-vs-double-add choice."""
     if backend == "double_add":
         return nbits
     assert backend == "window" and w is not None

@@ -123,8 +123,8 @@ def _shuffle_rounds_stacked(seed_words: jnp.ndarray, pivots: jnp.ndarray,
     reverse+roll is a single data movement (one kernel, shared shift)
     instead of two. Bytes moved rise slightly (bits as int32, not bool);
     kernel-launch/fusion-boundary count halves. Which effect wins on the
-    Mosaic pipeline is an empirical question — tools/tpu_followup.py A/Bs
-    the two on chip; bit-equality is pinned in tests/test_shuffle_kernel.py.
+    chip is an empirical question nothing has timed yet; bit-equality is
+    pinned in tests/test_shuffle_kernel.py.
     """
     bits = _round_bits(seed_words, n, rounds, jnp.int32)
     pos = jnp.arange(n, dtype=jnp.int32)

@@ -104,9 +104,3 @@ def reset() -> None:
     telemetry registry — telemetry.reset() zeroes those)."""
     ladder().reset()
     faults.set_schedule(None)
-
-
-def snapshot() -> dict:
-    """Alias bench.py embeds per JSON row (next to the telemetry and
-    contract-budget snapshots)."""
-    return health_snapshot()

@@ -8,9 +8,9 @@ ResidentCore / ServingMesh program launch:
     and no integrity check, it is `telemetry.watchdog.dispatch` (a
     cache-size read around the call) inside one try-frame: NO
     `block_until_ready`, so async dispatch is undisturbed, and the cost
-    is the <3% bench bound (`bench.py resilience` stage) / the <20 µs
-    no-op test bound. The error classification + retry still apply when the
-    dispatch itself raises — real weather does not wait for a schedule.
+    is held by the <20 µs no-op bound of tests/test_resilience.py. The
+    error classification + retry still apply when the dispatch itself
+    raises — real weather does not wait for a schedule.
   * **deadline** — when a budget is armed (`deadline_ms` argument or
     `CSTPU_DEADLINE_MS`), the guard measures wall clock around the
     dispatch plus `jax.block_until_ready(out)` — the fork-choice

@@ -32,8 +32,8 @@ def tripwires_enabled() -> bool:
     """CSTPU_TRIPWIRES switch, default ON: the resident epoch boundary
     arms `epoch_output_check` on its guarded dispatch (the boundary
     syncs its outputs immediately anyway, so the one fused reduction is
-    noise next to the epoch program — the `bench.py resilience` row
-    measures it inside the <3% bound)."""
+    noise next to the epoch program: it runs inside the benchmark's
+    `epoch_boundary_s`)."""
     raw = os.environ.get("CSTPU_TRIPWIRES", "").strip().lower()
     if not raw:
         return True

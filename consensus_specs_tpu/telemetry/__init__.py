@@ -10,7 +10,7 @@ The one coherent observability layer for the serving loop (ISSUE 8):
             sp.fence(out)                   # materialized at exit only
             sp.note(lanes=n)                # a work count on the record
     telemetry.counter("fq.redc.lanes").inc(n)
-    telemetry.snapshot()                    # dict for bench JSON rows
+    telemetry.snapshot()                    # one JSON-ready dict of it all
     telemetry.prometheus_text()             # BeaconNodeAPI.get_metrics()
     telemetry.watchdog.dispatch(key, fn, *args)   # retrace watchdog
     telemetry.watchdog.layout_check(key, tree)    # re-layout watchdog

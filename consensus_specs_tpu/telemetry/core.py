@@ -148,9 +148,8 @@ def _leaves(tree) -> Iterator:
 def _materialize(trees) -> None:
     """The fence: fetch one element of every one-device leaf (the
     repo-wide `_sync` idiom — materialized output bytes cannot arrive
-    before the program that makes them; chip_smoke.py prints this fence
-    next to `block_until_ready` for one epoch dispatch), and wait on every
-    shard of a leaf that lies over several devices."""
+    before the program that makes them), and wait on every shard of a
+    leaf that lies over several devices."""
     import numpy as np
     for tree in trees:
         for leaf in _leaves(tree):
@@ -423,10 +422,10 @@ def histogram(name: str, always: bool = False) -> Histogram:
 
 def snapshot() -> dict:
     """One JSON-ready view of everything: counters, gauges, histograms,
-    and per-span-name aggregates. This is the dict bench.py embeds in its
-    JSON row and tools/tpu_followup.py prints per stage — the span names
-    keep the keys the old bespoke `timings` dicts used ("epoch.distill"
-    carries the old "distill" bucket, etc.). Taken under the module lock
+    and per-span-name aggregates (tools/telemetry_smoke.py writes it, the
+    JSONL exporter appends it to every row). The span names keep the keys
+    the old bespoke `timings` dicts used ("epoch.distill" carries the old
+    "distill" bucket, etc.). Taken under the module lock
     so a concurrent scrape (BeaconNodeAPI.get_metrics) never races
     first-use metric creation or a span close on the serving thread."""
     with _lock:

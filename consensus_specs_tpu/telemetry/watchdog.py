@@ -24,8 +24,8 @@ counterparts, watching the programs actually dispatched:
 Both are no-ops when telemetry is off (`CSTPU_TELEMETRY=0`): `dispatch`
 degrades to a plain call, `layout_check` to `None`.
 
-The acceptance contract (ISSUE 8, checked by `bench.py`'s telemetry row
-and tests/test_telemetry.py): four chained resident slot steps plus one
+The acceptance contract (ISSUE 8, checked by tests/test_telemetry.py and,
+as `guard_events`, by every benchmark run): four chained resident slot steps plus one
 epoch boundary on the 8-device mesh report ZERO events of either kind.
 """
 from __future__ import annotations

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives: decided from outside.
 
-Every script that compiles (bench.py, chip_smoke.py, the tools/ smokes,
-tests/conftest.py) calls `configure()` once, before its first compile.
+Every script that compiles (benchmark/run.py, chip_smoke.py, the tools/
+smokes, tests/conftest.py) calls `configure()` once, before its first compile.
 `JAX_COMPILATION_CACHE_DIR` set in the environment wins and nothing in
 `jax.config` is touched (jax reads that variable itself at import);
 otherwise the cache sits at the fixed `<checkout>/.cache/xla` — no temp

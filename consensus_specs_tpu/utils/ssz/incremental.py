@@ -438,7 +438,7 @@ def tree_from_chunks(chunks: np.ndarray,
 # build, and a 2-dirty update re-hashes only the two root paths (11
 # lanes here — they merge two levels below the root). A kernel change
 # that silently rebuilds a level (or the whole forest) on update shows
-# up as a lane jump long before bench.py's incremental_root row moves.
+# up as a lane jump long before the benchmark's `forest_build_ms` moves.
 
 def _forest_lane_measure():
     leaves = np.arange(64 * 8, dtype=np.uint32).reshape(64, 8)
@@ -499,14 +499,22 @@ MEM_CONTRACTS = [
         build=_forest_build_mem_build,
         # all levels live at once (2n rows) plus the leaf level's sha256
         # schedule windows, which the no-fusion model counts at full
-        # width (XLA fuses most of them — hence the wider compiled
-        # tolerance below: model/compiled = ~1.4x at the probe shape)
+        # width and one level at a time, in program order. The compiled
+        # tolerance is wider than the default for what jax 0.9.0's
+        # XLA:CPU holds beyond that (compiled/model = 1.55 at the probe
+        # shape, buffer assignment read in PR 30): the second SHA block
+        # of a pair hash is the constant padding block, so every level's
+        # [64, n_d] window over it depends on no input, and the
+        # scheduler extends all of them at program start and keeps them
+        # until their level runs: 64 * 4 * (n - 1) B live at once
+        # (1,048,320 B at n = 2^12) where the walk holds level 0's
+        # 524,288 B alone. It is a constant factor of the same O(n).
         budget_bytes=384 << 20,
         scaling=dict(ns=[1 << 14, 1 << 17, 1 << 20],
                      build=_forest_build_mem_build,
                      metric="peak_bytes", max_order=1.0),
         compiled=dict(build=lambda: _forest_build_mem_build(1 << 12),
-                      tol=1.5),
+                      tol=1.6),
     ),
     dict(
         name="utils.ssz.incremental.forest_update_dirty",

@@ -1,8 +1,8 @@
 """Test-wide setup: run JAX on a virtual 8-device CPU mesh.
 
 Must run before any jax backend is initialized, so it lives at the top
-of conftest. The chip is chip_smoke.py's and bench.py's; tests validate
-sharding logic on virtual devices per the multi-chip test strategy.
+of conftest. The chip is benchmark/run.py's and chip_smoke.py's; tests
+validate sharding logic on virtual devices per the multi-chip test strategy.
 """
 import os
 

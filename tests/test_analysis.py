@@ -1226,7 +1226,7 @@ def test_repo_is_analysis_clean():
     analyzer still registers."""
     baseline = load_baseline(str(REPO / "tools" / "analysis" / "baseline.json"))
     report = analyze_paths(
-        [str(REPO / "consensus_specs_tpu"), str(REPO / "bench.py"),
+        [str(REPO / "consensus_specs_tpu"), str(REPO / "chip_smoke.py"),
          str(REPO / "__graft_entry__.py")], baseline)
     assert report.findings == []
     assert report.stale_baseline == []

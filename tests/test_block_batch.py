@@ -11,10 +11,10 @@ from copy import deepcopy
 
 import pytest
 
-import bench
 from consensus_specs_tpu.crypto import bls
 from consensus_specs_tpu.models import phase0
 from consensus_specs_tpu.models.phase0 import block as block_mod
+from consensus_specs_tpu.testing.states import build_config3_state_and_block
 from consensus_specs_tpu.utils.ssz.impl import hash_tree_root
 
 N_KEYS = 8
@@ -32,7 +32,7 @@ def _bls_on():
 
 def _build(spec, v, n_atts):
     bls.set_backend("python")  # stage signatures with the bignum oracle
-    return bench.build_config3_state_and_block(spec, v, n_atts, n_keys=N_KEYS)
+    return build_config3_state_and_block(spec, v, n_atts, n_keys=N_KEYS)
 
 
 def test_batched_block_matches_sequential_oracle():
@@ -94,3 +94,17 @@ def test_mainnet_preset_batched_block():
     bls.set_backend("jax")
     spec.state_transition(state, block)
     assert len(state.previous_epoch_attestations) == 4
+
+
+def test_state_builders_live_in_the_package():
+    """The two whole-state builders moved out of the retired bench.py
+    (PR 30): they import from the package, and no top-level script of
+    that name is left for a test to lean on."""
+    import importlib
+
+    from consensus_specs_tpu.testing import states
+    assert states.build_config3_state_and_block is \
+        build_config3_state_and_block
+    assert callable(states.build_baseline_state)
+    with pytest.raises(ModuleNotFoundError):
+        importlib.import_module("bench")

@@ -344,7 +344,7 @@ def test_redc_lane_counts_in_traced_programs(name, leaf_lanes, coeff_lanes):
 
 
 def test_grouped_pairing_traced_lane_cut():
-    """The whole-path bound bench.py's pairing_redc_ab row asserts: the
+    """The whole-path bound: the
     grouped Miller + final-exponentiation traced programs carry >=2.5x
     fewer REDC lanes under coeff than leaf."""
     from consensus_specs_tpu.ops import bls_jax as BJ

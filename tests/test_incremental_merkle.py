@@ -182,8 +182,7 @@ def test_update_work_is_dirty_log_v():
         assert sum(lanes) <= 2 * k * tree.depth, (k, lanes)
         assert all(lane <= 2 * k for lane in lanes), (k, lanes)
     # 16 dirty leaves of 4096: an order of magnitude under the full rebuild
-    # even at this small scale (at 1k dirty of 1M the gap is ~50x — measured
-    # by bench.py's `incremental state-root ms` row)
+    # even at this small scale
     assert sum(tree.last_pairs_per_level) * 10 < full_lanes
 
 

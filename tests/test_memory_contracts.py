@@ -21,6 +21,7 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from consensus_specs_tpu.ops import intmath as _intmath  # noqa: F401 -- x64
 from tools.analysis.memory import engine
@@ -73,6 +74,26 @@ def test_donated_alias_counted_once():
     closed2 = jax.make_jaxpr(g)(_vec(), _vec())
     d2 = L.analyze(closed2, donated={0})
     assert d2.alias_bytes == 0          # uint32 out: nothing congruent
+
+
+def test_a_constant_value_is_resident_once():
+    """Tracing hoists a constant once per array object that carried it,
+    and how many objects that is moves with jax (the pairing ratchet read
+    1,904 B high under 0.9.0 for it); the executable holds a value once,
+    and so does the walk."""
+    table = np.arange(1 << 10, dtype=np.uint64)
+
+    def one(x):
+        return x + jnp.asarray(table)
+
+    def twice(x):
+        return x + jnp.asarray(table) + jnp.asarray(table.copy())
+
+    a = L.analyze(jax.make_jaxpr(one)(_vec(1 << 10)))
+    b_closed = jax.make_jaxpr(twice)(_vec(1 << 10))
+    b = L.analyze(b_closed)
+    assert len(b_closed.jaxpr.constvars) == 2
+    assert a.const_bytes == b.const_bytes == table.nbytes
 
 
 def test_scan_body_transient_contributes_atop_carry():
@@ -179,6 +200,68 @@ def test_compiled_crosscheck_agreement_clears(tmp_path):
     assert "CSA1601" not in _rules(report)
     (res,) = report.results
     assert res.detail["compiled"]["argument_bytes"][2] is True
+
+
+@jax.jit
+def _wide_sums(att, gain, keys):
+    """epoch_soa._proposer_chunk_sums in small: the chip's compiler runs
+    the compare and select inside the reduction, XLA:CPU materialises the
+    [V, K] uint64 operand."""
+    hit = att[:, None] == keys[None, :]
+    return jnp.sum(jnp.where(hit, gain[:, None], jnp.uint64(0)), axis=0)
+
+
+def _fused_contract(tmp_path, fused_calls=(_wide_sums.__name__,)):
+    V, K = 1 << 14, 128
+    S = jax.ShapeDtypeStruct
+
+    def fn(att, gain, keys):
+        held = gain[:, None] << jnp.arange(64, dtype=jnp.uint64)[None, :]
+        return held, _wide_sums(att, gain, keys)
+
+    return V, K, _contract(
+        tmp_path, compiled=True,
+        build=lambda: dict(fn=fn, fused_calls=fused_calls,
+                           args=(S((V,), jnp.int32), S((V,), jnp.uint64),
+                                 S((K,), jnp.int32))))
+
+
+@pytest.mark.parametrize("walk", ["honest", "forgets_a_buffer"])
+def test_compiled_crosscheck_of_a_declared_fused_call(tmp_path, monkeypatch,
+                                                      walk):
+    """`fused_calls` is a claim about the chip's compiler, and the
+    cross-check compiles with XLA:CPU, which holds the call's [V, K]
+    operand. The call is charged what that compiler holds inside it, so
+    the check passes at the default tolerance without the ratchet's peak
+    taking the operand in, and it still catches a walk that loses a
+    buffer held across the call."""
+    V, K, c = _fused_contract(tmp_path)
+    if walk == "forgets_a_buffer":
+        real = L.analyze
+
+        def forgetful(closed, **kw):
+            res = real(closed, **kw)
+            res.peak_bytes -= V * 64 * 8        # the [V, 64] `held`
+            return res
+
+        monkeypatch.setattr(L, "analyze", forgetful)
+    report = engine.run_contracts([c], baseline={})
+    (res,) = report.results
+    model, compiled, ok = res.detail["compiled"]["peak_bytes"]
+    assert compiled > V * K * 8 > res.measured["peak_bytes"] - V * K * 8
+    diverges = [f for f in report.findings
+                if f.rule == "CSA1601" and "`peak_bytes`" in f.message]
+    if walk == "honest":
+        assert ok and not diverges, (model, compiled)
+    else:
+        assert not ok and diverges, (model, compiled)
+
+
+def test_fused_call_that_names_no_call_is_a_finding(tmp_path):
+    _, _, c = _fused_contract(tmp_path, fused_calls=("_renamed_sums",))
+    report = engine.run_contracts([c], baseline={})
+    assert any(f.rule == "CSA1601" and "_renamed_sums" in f.message
+               for f in report.findings)
 
 
 # ---------------------------------------------------------------------------

@@ -379,6 +379,106 @@ def test_suppression_on_contract_line(tmp_path):
     assert [f.rule for f in report.suppressed] == ["CSA1404"] * 3
 
 
+# ---------------------------------------------------------------------------
+# Sites: a finding sits at the line of its own equation
+# ---------------------------------------------------------------------------
+
+_SITE_KERNEL = """\
+import jax
+
+
+def top(x):
+    return x + x  # WRAPS
+
+
+@jax.jit
+def _inner(x):
+    return x + x  # WRAPS-NESTED
+
+
+def nested(x):
+    return _inner(x)
+
+
+RANGE_CONTRACTS = [
+    {"name": "fixture.contract"},
+]
+"""
+
+
+def _site_contract(tmp_path, fn_name, source=_SITE_KERNEL):
+    """A contract over a kernel file written to tmp_path and imported from
+    there, so that jax records that file's lines as the equations' frames
+    and the engine parses that file's suppressions."""
+    import importlib.util
+    path = tmp_path / "kernel_fixture.py"
+    path.write_text(source)
+    mod_spec = importlib.util.spec_from_file_location("kernel_fixture", path)
+    module = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(module)
+    fn = getattr(module, fn_name)
+    return _contract(
+        tmp_path,
+        build=lambda: dict(fn=fn, args=(jnp.zeros(4, jnp.uint64),),
+                           ranges=({"lo": 0, "hi": 1 << 63},)))
+
+
+@pytest.mark.parametrize("fn_name, marker", [("top", "WRAPS"),
+                                             ("nested", "WRAPS-NESTED")])
+def test_finding_sits_at_its_equations_own_line(tmp_path, fn_name, marker):
+    """The add that can wrap is reported in the kernel's file at the
+    add's line, at top level and inside a nested jit, never at the
+    contract's declaration (where every finding landed while the site
+    lookup failed in silence, PR 22 to PR 29)."""
+    c = _site_contract(tmp_path, fn_name)
+    report = engine.run_contracts([c], baseline={})
+    (wrap,) = [f for f in report.findings if f.rule == "CSA1401"]
+    assert wrap.path == c["path"] and wrap.line != c["line"]
+    assert _SITE_KERNEL.splitlines()[wrap.line - 1].endswith("# " + marker)
+
+
+@pytest.mark.parametrize("where, silenced", [("wrap", True),
+                                             ("contract", False)])
+def test_only_a_suppression_at_the_wrapping_line_silences(tmp_path, where,
+                                                          silenced):
+    ignore = "# csa: ignore[CSA1401] -- fixture: declared wrap"
+    source = (_SITE_KERNEL.replace("# WRAPS\n", ignore + "\n")
+              if where == "wrap" else
+              _SITE_KERNEL.replace('{"name": "fixture.contract"},',
+                                   '{"name": "fixture.contract"},  ' + ignore))
+    assert source != _SITE_KERNEL
+    c = _site_contract(tmp_path, "top", source)
+    report = engine.run_contracts([c], baseline={})
+    assert ("CSA1401" in [f.rule for f in report.suppressed]) is silenced
+    assert ("CSA1401" not in _rules(report)) is silenced
+
+
+@pytest.mark.parametrize("mode", ["no_user_frame", "jax_moved"])
+def test_a_site_that_cannot_be_read_is_reported(tmp_path, monkeypatch, mode):
+    """jax records no user frame for an equation: the finding goes to the
+    contract's line and says why it is there. jax's private lookup refuses
+    the call (it changed its argument between 0.4 and 0.9): the contract
+    is reported unproven. Neither is a finding at line 0, and neither is
+    silence."""
+    from jax._src import source_info_util
+
+    def moved(traceback):
+        raise AttributeError("'SourceInfo' object has no attribute "
+                             "'raw_frames'")
+
+    monkeypatch.setattr(source_info_util, "user_frame",
+                        moved if mode == "jax_moved" else lambda tb: None)
+    c = _site_contract(tmp_path, "top")
+    report = engine.run_contracts([c], baseline={})
+    (finding,) = [f for f in report.findings if f.rule == "CSA1401"]
+    assert finding.line == c["line"] != 0
+    if mode == "jax_moved":
+        assert report.results[0].skipped
+        assert "raw_frames" in finding.message
+    else:
+        assert "no user frame" in finding.message
+
+
 def test_stale_baseline_contract_reported(tmp_path):
     base = {"fixture.contract": {"out_lo": 0, "out_hi": 8, "widened": 0},
             "deleted.contract": {"out_hi": 1}}
@@ -427,8 +527,14 @@ def test_committed_registry_proves_clean():
     assert report.findings == [], [
         f"{f.path}:{f.line} {f.rule} {f.message}" for f in report.findings]
     assert report.stale_baseline == []
-    # the FAR-sentinel add is the one declared (inline-suppressed) wrap
-    assert [f.rule for f in report.suppressed] == ["CSA1401"]
+    # the FAR-sentinel add is the one declared (inline-suppressed) wrap,
+    # silenced at its own line and nowhere else
+    (far,) = report.suppressed
+    assert far.rule == "CSA1401"
+    source = (engine.REPO_ROOT / far.path).read_text().splitlines()
+    assert far.path.endswith("models/phase0/epoch_soa.py")
+    assert source[far.line - 1].lstrip().startswith("assigned = base_epoch +")
+    assert "csa: ignore[CSA1401]" in source[far.line - 2]
 
 
 def test_wide_budget_is_proven_not_asserted():

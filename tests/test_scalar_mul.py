@@ -217,7 +217,7 @@ def test_sign_privtopub_parity_both_backends():
 def test_sequential_add_count_measured_through_contract():
     """The windowed_chain contract: an unrolled eager windowed evaluation
     counted op-by-op (every call one dependent step at batch ()), pinned
-    exactly to the analytic model bench.py reports — measured by the
+    exactly to the analytic model (scalar_mul.sequential_adds) — measured by the
     contract engine, value-checked against the host oracle here."""
     from tools.analysis.trace import engine as trace_engine
     contracts = [c for c in trace_engine.discover()

@@ -58,8 +58,7 @@ def test_backend_hook_used_and_cached():
 
 @pytest.mark.parametrize("n", [1, 7, 256, 1000, 2048])
 def test_stacked_variant_bit_equal(n):
-    """The [2, n] stacked-movement A/B variant == the reference kernel
-    (tools/tpu_followup.py picks between them on chip by timing)."""
+    """The [2, n] stacked-movement A/B variant == the reference kernel."""
     import jax.numpy as jnp
 
     from consensus_specs_tpu.ops.shuffle import (

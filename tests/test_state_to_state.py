@@ -1,9 +1,6 @@
-"""Bit-equality gate for the bench's state-to-state config-5 path.
-
-bench.py's bench_state_to_state() times: vectorized distillation ->
-one-program device epoch -> device registry/balances roots from the
-still-resident output columns. This test runs the SAME path (same state
-builder, same calls) at reduced V on the mainnet preset and asserts:
+"""Bit-equality gate for the state-to-state config-5 path: vectorized
+distillation -> one-program device epoch -> device registry/balances roots
+from the still-resident output columns, at reduced V on the mainnet preset:
   1. post-state hash_tree_root == the object-model spec.process_epoch
   2. the device roots from post-transition columns == the recursive oracle
      roots of the written-back registry/balances
@@ -15,10 +12,10 @@ import pytest
 
 pytestmark = pytest.mark.slow  # pairing compiles dominate suite wall-clock
 
-import bench
 from consensus_specs_tpu.crypto import bls
 from consensus_specs_tpu.models import phase0
 from consensus_specs_tpu.models.phase0.epoch_soa import process_epoch_soa
+from consensus_specs_tpu.testing.states import build_baseline_state
 from consensus_specs_tpu.utils.ssz import bulk
 from consensus_specs_tpu.utils.ssz.impl import hash_tree_root
 from consensus_specs_tpu.utils.ssz.typing import List as SSZList, uint64
@@ -34,10 +31,10 @@ def _bls_off():
     bls.bls_active = old
 
 
-def test_bench_state_to_state_path_matches_object_model():
+def test_state_to_state_path_matches_object_model():
     spec = phase0.get_spec("mainnet")
     spec.clear_caches()
-    state = bench.build_baseline_state(spec, V)
+    state = build_baseline_state(spec, V)
     ref = deepcopy(state)
 
     tm = {}

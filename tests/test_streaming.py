@@ -272,10 +272,11 @@ def test_gossip_preverification_feeds_block_path():
     bit-identical to the synchronous path."""
     from copy import deepcopy
 
-    import bench
     from consensus_specs_tpu.models import phase0
     from consensus_specs_tpu.networking.gossip import (GossipRouter,
                                                        TOPIC_BEACON_ATTESTATION)
+    from consensus_specs_tpu.testing.states import (
+        build_config3_state_and_block)
     from consensus_specs_tpu.utils.ssz.impl import hash_tree_root, serialize
 
     spec = phase0.get_spec("minimal")
@@ -283,7 +284,7 @@ def test_gossip_preverification_feeds_block_path():
     bls.bls_active = True
     bls.set_backend("python")   # stage signatures with the bignum oracle
     try:
-        state, block = bench.build_config3_state_and_block(
+        state, block = build_config3_state_and_block(
             spec, 8 * spec.SLOTS_PER_EPOCH, 3, n_keys=8)
         bls.set_backend("jax")
 

@@ -4,8 +4,8 @@ The TPU compiler is installed here and compiles for a v5e that is
 described, not attached (`jax.experimental.topologies`). Nothing runs, so
 these say nothing about results or times; they catch what the chip's
 compiler refuses — a kernel Mosaic cannot lower, a program whose tiling
-blows the 16 GB of HBM — before a chip call is spent on it. chip_smoke.py
-is what runs them.
+blows the 16 GB of HBM — before a chip call is spent on it. The benchmark
+(benchmark/run.py) is what runs them; chip_smoke.py runs the Mosaic one.
 
 Code that asks `jax.default_backend()` sees the CPU here and would take
 its CPU branch, so each test hands the kernel its TPU-side choices itself

@@ -344,7 +344,7 @@ def test_baseline_roundtrip_and_snapshot(tmp_path):
     assert loaded == report.snapshot
     again = engine.run_contracts([c], baseline=loaded)
     assert again.findings == []
-    # the artifact row shape bench.py embeds
+    # the artifact's row shape
     data = json.loads(engine.render_json(report))
     assert data["contracts"][0]["name"] == "fixture.contract"
     assert data["contracts"][0]["measured"]["jaxpr_eqns"] >= 1
@@ -390,7 +390,7 @@ def test_committed_registry_shape():
             assert metric in declared.get("budgets", {}) \
                 or metric not in known_engine_metrics \
                 or declared.get("measure") is not None, (name, metric)
-    # budget_snapshot (the bench.py row) never traces: pure declaration
+    # budget_snapshot never traces: pure declaration
     snap = engine.budget_snapshot(contracts)
     assert snap["ops.fq_tower.fq12_mul[coeff]"] == {"redc_lanes": 12}
 

@@ -58,7 +58,7 @@ Entry points:
 
 This module registers the rule catalog only (stdlib, importable by the
 no-jax lint lane for `--list-rules`); engine.py is loaded lazily by
-the CLI's --lifetime path, tests, and bench.py's lifetime snapshot.
+the CLI's --lifetime path and by tests.
 The lowering cross-check is the only part that imports jax, and it
 degrades to a notice when jax is absent or `--no-lower` is passed.
 """

@@ -56,8 +56,8 @@ DEFAULT_BASELINE = Path(__file__).resolve().parents[1] / \
 
 # The donation-bearing surface: the runtime package plus every entry
 # point PR 3 hand-audited for donated-call reuse.
-DEFAULT_TARGETS = ("consensus_specs_tpu", "bench.py", "__graft_entry__.py",
-                   "tools/tpu_followup.py", "tests/test_multichip.py")
+DEFAULT_TARGETS = ("consensus_specs_tpu", "__graft_entry__.py",
+                   "tests/test_multichip.py")
 
 # Dispatch wrappers that forward `fn(*args)` after two host-side
 # leading arguments (key, fn): telemetry.watchdog.dispatch and

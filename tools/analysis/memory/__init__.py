@@ -58,8 +58,7 @@ Entry points:
 
 This module registers the rule catalog only (stdlib, importable by the
 no-jax lint lane for `--list-rules`); liveness.py and engine.py are
-loaded lazily by the CLI's --memory path, by tests, by bench.py's
-memory-snapshot row, and by tools/tpu_followup.py's roofline stage.
+loaded lazily by the CLI's --memory path and by tests.
 """
 from ..core import register_rule
 

@@ -15,7 +15,12 @@ reports it):
   * an intermediate value is live from its defining eqn to its last
     use; a program output stays live to the end;
   * jaxpr constants are baked into the executable and counted resident
-    for the whole program;
+    for the whole program, each distinct VALUE once: tracing hoists a
+    constant once per array object that carried it (the pairing trace
+    holds 2,436 [28] int64 constvars of 14 values), and how many
+    objects that is moves with jax (0.9.0 hoists 1,904 B more of them
+    there than the version the ratchet was first written under), while
+    the executable holds a value once;
   * an eqn with sub-jaxprs (scan / while / cond / pjit / custom_*)
     contributes its body's TRANSIENT peak (body peak beyond the body's
     own inputs and outputs, which the outer walk already tracks as the
@@ -30,6 +35,9 @@ reports it):
     the build spec): such a call contributes its operands and results
     and no transient. Declared, never inferred, and backed by a compile
     for the chip that holds the compiler to it (tests/test_tpu_compile.py).
+    The declaration is about the chip's compiler; the engine's
+    cross-check compiles with another, so there the walk is given, per
+    name, the transient that compiler holds inside the call.
 
 Per-shard footprints reuse the same walk with a different byte
 function: a leaf whose element count reaches the contract's sharding
@@ -124,6 +132,24 @@ def _sub_jaxprs(eqn):
     return subs
 
 
+def _const_bytes(closed, bytes_fn: Callable) -> List[int]:
+    """Bytes charged to each constvar: a value's first constvar carries
+    it, its repeats nothing. A raw Jaxpr has no values to compare and is
+    charged per constvar."""
+    jaxpr = getattr(closed, "jaxpr", closed)
+    consts = getattr(closed, "consts", None)
+    if consts is None:
+        return [bytes_fn(v.aval) for v in jaxpr.constvars]
+    import numpy as np
+    seen, charged = set(), []
+    for v, value in zip(jaxpr.constvars, consts):
+        key = (tuple(v.aval.shape), str(v.aval.dtype),
+               np.asarray(value).tobytes())
+        charged.append(0 if key in seen else bytes_fn(v.aval))
+        seen.add(key)
+    return charged
+
+
 def _match_donations(invars, outvars, donated: set,
                      bytes_fn: Callable) -> Tuple[set, set, int]:
     """Greedy congruent pairing of donated invars with outputs — the
@@ -153,14 +179,17 @@ def _match_donations(invars, outvars, donated: set,
 
 def analyze(closed, donated: Optional[set] = None,
             bytes_fn: Callable = aval_bytes,
-            fused_calls: Tuple[str, ...] = ()) -> Liveness:
+            fused_calls: Optional[Dict[str, int]] = None) -> Liveness:
     """Walk a ClosedJaxpr and return the modeled peak liveness.
 
     `donated` holds FLAT invar indices (the engine expands jit-level
-    donate_argnums over each argument's leaves); `fused_calls` names the
-    nested jits whose bodies the contract declares fused (no transient)."""
+    donate_argnums over each argument's leaves); `fused_calls` maps the
+    name of each nested jit the contract declares fused to the transient
+    bytes it stands for (0: the declaration as the chip's compiler keeps
+    it)."""
     jaxpr = getattr(closed, "jaxpr", closed)
     donated = donated or set()
+    fused_calls = fused_calls or {}
     res = Liveness(n_eqns=len(jaxpr.eqns))
 
     invars = list(jaxpr.invars)
@@ -169,7 +198,8 @@ def analyze(closed, donated: Optional[set] = None,
     res.arg_bytes = sum(bytes_fn(v.aval) for v in invars)
     res.out_bytes = sum(bytes_fn(v.aval) for v in jaxpr.outvars
                         if getattr(v, "aval", None) is not None)
-    res.const_bytes = sum(bytes_fn(v.aval) for v in jaxpr.constvars)
+    const_bytes = _const_bytes(closed, bytes_fn)
+    res.const_bytes = sum(const_bytes)
 
     aliased_in, aliased_out, res.alias_bytes = _match_donations(
         invars, jaxpr.outvars, donated, bytes_fn)
@@ -192,8 +222,8 @@ def analyze(closed, donated: Optional[set] = None,
         live[id(v)] = bytes_fn(v.aval)
         if i not in donated or id(v) in aliased_in:
             never_free.add(id(v))
-    for v in jaxpr.constvars:
-        live[id(v)] = bytes_fn(v.aval)
+    for v, b in zip(jaxpr.constvars, const_bytes):
+        live[id(v)] = b
         never_free.add(id(v))
 
     live_total = sum(live.values())
@@ -203,8 +233,9 @@ def analyze(closed, donated: Optional[set] = None,
     for i, eqn in enumerate(jaxpr.eqns):
         # transient contribution of sub-jaxpr bodies beyond their own
         # I/O (already tracked as this eqn's operands and results)
-        extra = 0
-        if eqn.params.get("name") not in fused_calls:
+        extra = fused_calls.get(eqn.params.get("name"))
+        if extra is None:
+            extra = 0
             for sub in _sub_jaxprs(eqn):
                 inner = analyze(sub, bytes_fn=bytes_fn,
                                 fused_calls=fused_calls)
@@ -250,8 +281,7 @@ def traffic_bounds(closed, bytes_fn: Callable = aval_bytes
     contracts use: `lo` assumes perfect fusion (each program input read
     once, each output written once); `hi` assumes NO fusion (every eqn
     streams its operands in and its results out). The real machine
-    lands between them — tools/tpu_followup.py's roofline stage prints
-    both instead of a hand-maintained bytes-per-element table."""
+    lands between them."""
     jaxpr = getattr(closed, "jaxpr", closed)
     lo = (sum(aval_bytes(v.aval) for v in jaxpr.invars)
           + sum(bytes_fn(getattr(v, "aval", None))

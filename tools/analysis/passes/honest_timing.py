@@ -32,7 +32,7 @@ calls resolve through the module's own jit map (imported jitted names
 included — callgraph's fixpoint already folds `from m import f_jit` in),
 and attribute calls `mod.f_jit(...)` resolve the base through the
 program's import graph to the defining module's jitted names — the
-dispatch form bench.py and the resident loop actually use, which PR 1's
+dispatch form the resident loop actually uses, which PR 1's
 per-module pass documented as out of scope. Cross-block `t0` captures
 remain out of scope (the goal is catching the pattern the repo itself
 used to hand-roll, at zero false positives on the shipped tree).

@@ -47,8 +47,7 @@ Entry points:
 
 This module registers the rule catalog only (stdlib, importable by the
 no-jax lint lane for `--list-rules`); interval.py, interp.py and
-engine.py are loaded lazily by the CLI's --ranges path, by tests, and
-by bench.py's range-snapshot row.
+engine.py are loaded lazily by the CLI's --ranges path and by tests.
 """
 from ..core import register_rule
 

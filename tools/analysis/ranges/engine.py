@@ -50,7 +50,7 @@ from __future__ import annotations
 import json
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Dict, Iterable, List, Optional
+from typing import Dict, List, Optional
 
 from ..core import Finding, _parse_suppressions
 
@@ -103,15 +103,6 @@ def _name_line(source: str, name: str) -> int:
         if "RANGE_CONTRACTS" in line:
             return i
     return 1
-
-
-def declared_snapshot(contracts: Optional[Iterable[dict]] = None) -> dict:
-    """{contract: declared output spec} without tracing anything — the
-    cheap declaration read bench.py embeds next to the trace-tier budget
-    snapshot."""
-    if contracts is None:
-        contracts = discover()
-    return {c["name"]: c.get("output") for c in contracts}
 
 
 # ---------------------------------------------------------------------------

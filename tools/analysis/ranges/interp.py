@@ -134,20 +134,23 @@ def for_aval(aval, spec: Optional[dict] = None) -> AbsVal:
 class Event:
     rule: str            # CSA1401 / CSA1402 / CSA1403
     message: str
-    path: str            # source site when resolvable, else ""
+    path: str            # the equation's own site; "" where jax recorded
+                         # no user frame (the message then says so)
     line: int
     prim: str
 
 
-def _eqn_site(eqn) -> Tuple[str, int]:
-    try:
-        from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            return str(frame.file_name), int(frame.start_line)
-    except Exception:
-        pass
-    return "", 0
+def _eqn_site(eqn) -> Optional[Tuple[str, int]]:
+    """(file, line) of the innermost frame outside jax that staged `eqn`;
+    None where jax recorded no such frame. `user_frame` is private to jax
+    and has changed its argument before (0.9.0 takes the traceback, not
+    the SourceInfo): a call it refuses raises, and the engine reports the
+    contract as unproven, rather than placing every finding at line 0."""
+    from jax._src import source_info_util
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
+        return None
+    return str(frame.file_name), int(frame.start_line)
 
 
 # ---------------------------------------------------------------------------
@@ -171,7 +174,11 @@ class Interp:
     # -- events -------------------------------------------------------------
 
     def _emit(self, rule, message, eqn):
-        path, line = _eqn_site(eqn)
+        site = _eqn_site(eqn)
+        if site is None:
+            message += (" (jax recorded no user frame for this equation: "
+                        "reported at the contract's line)")
+        path, line = site or ("", 0)
         key = (rule, path, line, eqn.primitive.name, message.split(":")[0])
         if key in self._event_keys:
             return
@@ -187,8 +194,9 @@ class Interp:
     def _wrap_allowed(self, dtype: str, kind: str, eqn) -> bool:
         if dtype in self.wrap_ok or f"{dtype}:{kind}" in self.wrap_ok:
             return True
-        path, _ = _eqn_site(eqn)
-        return bool(path) and any(s in path for s in self.wrap_ok_sources)
+        site = _eqn_site(eqn)
+        return site is not None and any(s in site[0]
+                                        for s in self.wrap_ok_sources)
 
     def _finish(self, eqn, shape, dtype, vec, kind, tainted) -> AbsVal:
         """Clamp an ideal-arithmetic result against its dtype; flag a

@@ -31,8 +31,7 @@ reviewable diffs.  Entry points:
 
 This module registers the rule catalog only (stdlib, importable by the
 no-jax lint lane for `--list-rules`); tracer.py and engine.py import
-jax and are loaded lazily by the CLI's --trace path, by tests, and by
-bench.py's contract-snapshot row.
+jax and are loaded lazily by the CLI's --trace path and by tests.
 """
 from ..core import register_rule
 

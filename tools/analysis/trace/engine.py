@@ -131,10 +131,8 @@ def _name_line(source: str, name: str) -> int:
 
 
 def budget_snapshot(contracts: Optional[Iterable[dict]] = None) -> dict:
-    """{contract: {metric: budget}} without tracing anything — the cheap
-    snapshot bench.py embeds next to its telemetry registry dump so a
-    bench capture and the static budgets it ran under are
-    cross-checkable in one artifact."""
+    """{contract: {metric: budget}} without tracing anything: the
+    declared budgets alone, as plain data."""
     return {c["name"]: dict(c.get("budgets", {}))
             for c in (contracts if contracts is not None else discover())}
 

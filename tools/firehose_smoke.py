@@ -14,7 +14,7 @@ watchdog event, or a deadline miss at the nominal load point.
 
 Usage: python tools/firehose_smoke.py  (from the repo root)
 Env:   CSTPU_FIREHOSE_GROUPS (target batch occupancy, default 8 — the
-       smoke shape; bench.py runs the committed 128),
+       smoke shape; the committed contract shape is 128),
        CSTPU_FIREHOSE_ROUNDS (waves, default 4),
        CSTPU_FIREHOSE_DEADLINE_MS (flush budget, default 600000).
 """
