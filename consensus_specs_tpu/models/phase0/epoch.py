@@ -293,23 +293,17 @@ def final_updates_byte_rooted(spec, state) -> None:
     rotation. Shared by the object-model path and the SoA device path (which
     handles the numeric writes on device). All writes here are independent of
     the numeric ones, so the regrouping preserves reference semantics."""
-    import numpy as np
-
-    from ...utils.ssz.bulk import uint64_list_root_from_column
     current_epoch = spec.get_current_epoch(state)
     next_epoch = current_epoch + 1
     # Reset eth1 data votes
     if (state.slot + 1) % spec.SLOTS_PER_ETH1_VOTING_PERIOD == 0:
         state.eth1_data_votes = []
-    # Set active index root — through the vectorized uint64-list Merkleizer
-    # (== hash_tree_root(list, List[uint64]), equality-gated in
-    # tests/test_bulk_htr.py; the recursive path is seconds per call at
-    # registry scale and this write happens every epoch). Accepts both the
-    # object helper's list and the resident mirrors' ndarray.
+    # Set active index root: asked of the spec, so that whoever owns the
+    # registry answers (helpers.compute_active_index_root on the host for the
+    # object model, a device tree build for the resident core)
     index_root_position = (next_epoch + spec.ACTIVATION_EXIT_DELAY) % spec.LATEST_ACTIVE_INDEX_ROOTS_LENGTH
-    state.latest_active_index_roots[index_root_position] = uint64_list_root_from_column(
-        np.asarray(spec.get_active_validator_indices(state, next_epoch + spec.ACTIVATION_EXIT_DELAY),
-                   dtype=np.uint64))
+    state.latest_active_index_roots[index_root_position] = spec.compute_active_index_root(
+        state, next_epoch + spec.ACTIVATION_EXIT_DELAY)
     # Set randao mix
     state.latest_randao_mixes[next_epoch % spec.LATEST_RANDAO_MIXES_LENGTH] = \
         spec.get_randao_mix(state, current_epoch)

@@ -151,6 +151,21 @@ def get_active_validator_indices(spec, state, epoch: int) -> List[int]:
             if v.activation_epoch <= epoch < v.exit_epoch]
 
 
+def compute_active_index_root(spec, state, epoch: int) -> bytes:
+    """hash_tree_root(get_active_validator_indices(state, epoch),
+    List[uint64]): what process_final_updates writes into
+    latest_active_index_roots every epoch. Through the vectorized
+    uint64-list Merkleizer (equality-gated against the recursive path in
+    tests/test_bulk_htr.py, which is seconds per call at registry scale);
+    accepts the object helper's list and an ndarray alike. An explicit
+    spec method so that the resident pipeline, which holds the registry's
+    columns on the device, can build this tree there
+    (models/phase0/resident.py)."""
+    from ...utils.ssz.bulk import uint64_list_root_from_column
+    return uint64_list_root_from_column(np.asarray(
+        spec.get_active_validator_indices(state, epoch), dtype=np.uint64))
+
+
 def increase_balance(spec, state, index: int, delta: int) -> None:
     state.balances[index] += delta
 

@@ -33,7 +33,11 @@ small byte-rooted fields. This module makes that story real:
     spec's permutation cache. The device program then runs
     on the ALREADY-RESIDENT columns; only the distilled participation
     facts upload, and only the three mirror columns (+ 2x32-byte roots)
-    come back.
+    come back. The boundary's active-index root, the one registry-scale
+    tree besides the two forests, is a third device build by the balances
+    forest's own programs over the zero-filled index column
+    (`_active_index_root`): one upload, one node down, no pair of it
+    hashed on the host.
   * blocks carrying registry-mutating operations (slashings, deposits,
     exits, transfers) take the fallback: exit residency (one writeback),
     process the block through the untouched object path, re-enter
@@ -60,9 +64,11 @@ import numpy as np
 import jax
 
 from ... import telemetry
+from ...ops.sha256 import words_to_bytes
 from ...resilience.errors import (CheckpointCorrupt, DispatchError,
                                   FatalDispatchError)
 from ...telemetry import watchdog as _watchdog
+from ...utils.merkle import tree_depth
 from ...utils.ssz import bulk, host_tree
 from ...utils.ssz import impl as ssz_impl
 from ...utils.ssz.incremental import (IncrementalMerkleTree,
@@ -589,12 +595,19 @@ class ResidentCore:
                 return saved["effective_balance_of"](state, index)
             return int(mirrors["effective_balance"][index])
 
+        def compute_active_index_root(state, epoch):
+            if state is not self.state:
+                return saved["compute_active_index_root"](state, epoch)
+            return self._active_index_root(
+                get_active_validator_indices(state, epoch))
+
         # Proposer sampling and final updates need no clones: the shared
         # implementations read through get_active_validator_indices /
-        # effective_balance_of (helpers.py) and the vectorized uint64-list
-        # Merkleizer (epoch.py), all of which resolve to the overrides here.
+        # effective_balance_of / compute_active_index_root (helpers.py), all
+        # of which resolve to the overrides here.
         overrides = {
             "get_active_validator_indices": get_active_validator_indices,
+            "compute_active_index_root": compute_active_index_root,
             "compute_committee": compute_committee,
             "get_total_balance": get_total_balance,
             "effective_balance_of": effective_balance_of,
@@ -631,6 +644,43 @@ class ResidentCore:
             sp.note(pair_lanes=_FOREST_PAIR_LANES.value - lanes0)
         return self._big_roots
 
+    def _balances_forest(self, column) -> IncrementalMerkleTree:
+        """Every level of the `List[uint64]` tree over `column`, which has
+        the balances column's length, dtype and placement, so that
+        whatever it holds the programs that run are the balances
+        forest's: the chunk program and one level build, sharded under a
+        mesh (level 0 from the mesh's placed chunk program, inert padding
+        rows being the SSZ pack's zero padding)."""
+        if self._mesh is not None:
+            return ShardedIncrementalMerkleTree(
+                self._mesh.balances_forest_chunks(column, self._v),
+                self._mesh, logical_n=max(1, -(-self._v // 4)))
+        return IncrementalMerkleTree(bulk.balances_chunk_words_device(column))
+
+    def _active_index_root(self, indices) -> bytes:
+        """hash_tree_root(indices, List[uint64]) for the ascending active
+        `indices` of the resident registry, by a device tree build: the
+        indices go up once, zero-filled to the registry's length, into the
+        balances column's placement, `_balances_forest` builds over them,
+        and one node comes down. The zeros from n on are SSZ's own padding
+        of the last chunk and of the chunks after it, so the list's root
+        is the first node of the level that spans its ceil(n / 4) chunks,
+        which is the top one (32 bytes down, like a forest's root) while
+        more than half the chunk capacity is in use. No shape depends on
+        n: a registry whose active set moves compiles nothing. The tree
+        is dropped on return."""
+        import jax.numpy as jnp
+        n, bal = len(indices), self.cols.balance
+        column = np.zeros(bal.shape[0], bal.dtype)
+        column[:n] = indices
+        tree = self._balances_forest(
+            jnp.asarray(column) if self._mesh is None
+            else jax.device_put(column, self._mesh.shard_v))
+        # the whole level, as a transfer: a row sliced on the device is a
+        # program a level shape, and the level has 2 V / n rows at most
+        level = jax.device_get(tree.levels[tree_depth(-(-n // 4))])
+        return ssz_impl.mix_in_length(words_to_bytes(level[0]).tobytes(), n)
+
     def _build_forest_roots(self) -> tuple:
         """The build behind `_registry_balances_roots`: both leaf
         programs, both forests' levels, both roots down to the host."""
@@ -654,10 +704,6 @@ class ResidentCore:
                         c.exit_epoch, c.withdrawable_epoch, c.slashed,
                         c.effective_balance, v_count=V),
                     self._mesh, logical_n=V)
-            if self._bal_forest is None:
-                self._bal_forest = ShardedIncrementalMerkleTree(
-                    self._mesh.balances_forest_chunks(c.balance, V),
-                    self._mesh, logical_n=max(1, -(-V // 4)))
         else:
             if self._reg_forest is None:
                 self._reg_forest = IncrementalMerkleTree(
@@ -666,9 +712,8 @@ class ResidentCore:
                         c.activation_eligibility_epoch, c.activation_epoch,
                         c.exit_epoch, c.withdrawable_epoch, c.slashed,
                         c.effective_balance))
-            if self._bal_forest is None:
-                self._bal_forest = IncrementalMerkleTree(
-                    bulk.balances_chunk_words_device(c.balance))
+        if self._bal_forest is None:
+            self._bal_forest = self._balances_forest(c.balance)
         # re-layout watchdog on the resident forests: per-slot root
         # requests must keep every level-0 buffer's placement (a rebuild
         # at the same capacity reproduces it; only a deposit crossing the
@@ -992,7 +1037,9 @@ class ResidentCore:
                           "effective_balance"):
                     self.mirrors[f] = np.asarray(
                         jax.device_get(getattr(dev_cols, f)))[:self._v]
-            with telemetry.span("resident.refresh.final_updates"):
+            with telemetry.span("resident.refresh.final_updates") as sp_fin:
+                lanes0 = _FOREST_PAIR_LANES.value
+                hashed0 = bulk.HOST_PAIRS_HASHED.value
                 _apply_justification(spec, state, new_scal, report,
                                      previous_epoch, current_epoch)
                 # write the entries that moved (one an epoch): assigning
@@ -1003,7 +1050,13 @@ class ResidentCore:
                         new != np.asarray(list(slashed), np.uint64))[0]:
                     slashed[int(i)] = int(new[i])
                 state.latest_start_shard = int(new_scal.latest_start_shard)
-                spec.final_updates_byte_rooted(state)  # the resident override
+                # the active-index root in it is this core's device build
+                # (_install): its lanes, and what the host hashed besides
+                # (a historical batch every 128th epoch, else nothing)
+                spec.final_updates_byte_rooted(state)
+                sp_fin.note(
+                    index_root_lanes=_FOREST_PAIR_LANES.value - lanes0,
+                    host_pairs_hashed=bulk.HOST_PAIRS_HASHED.value - hashed0)
             self._registry_balances_roots()      # recompute + cache the roots
         self.timings = {"stage": sp_stage.duration, "device": sp_dev.duration,
                         "refresh": sp_ref.duration}
