@@ -24,17 +24,21 @@ def process_block_header(spec, state, block) -> None:
         body_root=spec.hash_tree_root(block.body),
     )
     # Proposer must not be slashed, and must have signed the block
-    proposer = state.validator_registry[spec.get_beacon_proposer_index(state)]
-    assert not proposer.slashed
-    assert spec.bls.bls_verify(proposer.pubkey, spec.signing_root(block), block.signature,
-                               spec.get_domain(state, spec.DOMAIN_BEACON_PROPOSER))
+    registry = spec.registry_view(state)
+    proposer_index = spec.get_beacon_proposer_index(state)
+    assert not registry.slashed(proposer_index)
+    # the message is the block's root, a second pass over the whole body:
+    # computed only for a verify that reads it
+    assert not spec.bls.bls_active or spec.bls.bls_verify(
+        registry.pubkey(proposer_index), spec.signing_root(block), block.signature,
+        spec.get_domain(state, spec.DOMAIN_BEACON_PROPOSER))
 
 
 def process_randao(spec, state, body) -> None:
-    proposer = state.validator_registry[spec.get_beacon_proposer_index(state)]
+    proposer_pubkey = spec.registry_view(state).pubkey(spec.get_beacon_proposer_index(state))
     current_epoch = spec.get_current_epoch(state)
     assert spec.bls.bls_verify(
-        proposer.pubkey,
+        proposer_pubkey,
         spec.hash_tree_root(current_epoch),
         body.randao_reveal,
         spec.get_domain(state, spec.DOMAIN_RANDAO),
@@ -45,7 +49,10 @@ def process_randao(spec, state, body) -> None:
 
 def process_eth1_data(spec, state, body) -> None:
     state.eth1_data_votes.append(body.eth1_data)
-    if sum(1 for v in state.eth1_data_votes if v == body.eth1_data) * 2 > spec.SLOTS_PER_ETH1_VOTING_PERIOD:
+    # field by field: Container.__eq__ compares hash_tree_roots, two a vote
+    # over a list that holds up to SLOTS_PER_ETH1_VOTING_PERIOD of them
+    vote = body.eth1_data.get_field_values()
+    if sum(1 for v in state.eth1_data_votes if v.get_field_values() == vote) * 2 > spec.SLOTS_PER_ETH1_VOTING_PERIOD:
         state.latest_eth1_data = body.eth1_data
 
 
@@ -121,7 +128,7 @@ def process_attestations_batched(spec, state, attestations) -> None:
     # sampling recomputations collapse to one)
     if len(attestations) > 1:
         state._proposer_memo = (
-            (int(state.slot), len(state.validator_registry)),
+            (int(state.slot), len(spec.registry_view(state))),
             spec.get_beacon_proposer_index(state))
     try:
         if batch is None or spec._att_verify_sink is not None:

@@ -186,6 +186,43 @@ def get_total_balance(spec, state, indices: Sequence[int]) -> int:
     return max(sum(state.validator_registry[i].effective_balance for i in indices), 1)
 
 
+class ObjectRegistry:
+    """The registry as block processing reads it (`registry_view`), answered
+    by an object state's validator list: how many validators there are, one
+    validator's `slashed` flag and pubkey, the pubkeys of an index set."""
+
+    __slots__ = ("state",)
+
+    def __init__(self, state):
+        self.state = state
+
+    def __len__(self) -> int:
+        return len(self.state.validator_registry)
+
+    def slashed(self, index: int) -> bool:
+        return bool(self.state.validator_registry[index].slashed)
+
+    def pubkey(self, index: int) -> bytes:
+        return self.state.validator_registry[index].pubkey
+
+    def pubkeys(self, indices: Sequence[int]) -> List[bytes]:
+        registry = self.state.validator_registry
+        return [registry[i].pubkey for i in indices]
+
+
+def registry_view(spec, state):
+    """Who answers block processing's reads of `state`'s registry: the view
+    a resident core has registered for its own state (its host mirrors and
+    resident pubkeys; models/phase0/resident.py), else the state's own
+    validator list. Every state is asked the same way, so the block path
+    has one implementation whether the registry lives as objects or as
+    columns."""
+    view = spec._registry_views.get(id(state))
+    if view is not None and view.state is state:
+        return view
+    return ObjectRegistry(state)
+
+
 def get_churn_limit(spec, state) -> int:
     active = len(spec.get_active_validator_indices(state, spec.get_current_epoch(state)))
     return max(spec.MIN_PER_EPOCH_CHURN_LIMIT, active // spec.CHURN_LIMIT_QUOTIENT)
@@ -352,6 +389,23 @@ def get_crosslink_committee(spec, state, epoch: int, shard: int) -> List[int]:
     )
 
 
+def get_crosslink_committee_array(spec, state, epoch: int, shard: int) -> np.ndarray:
+    """`get_crosslink_committee` as the int64 array its members are sliced
+    from: the attestation family works by the member (a bit a member, an
+    index a set bit), which is array work. The active indices are whatever
+    `get_active_validator_indices` answers for this state, the object
+    model's list or a resident core's array."""
+    indices = spec.get_active_validator_indices(state, epoch)
+    count = spec.get_epoch_committee_count(state, epoch)
+    index = (shard + spec.SHARD_COUNT - spec.get_epoch_start_shard(state, epoch)) % spec.SHARD_COUNT
+    start = (len(indices) * index) // count
+    end = (len(indices) * (index + 1)) // count
+    members = spec.get_shuffle_permutation(len(indices), spec.generate_seed(state, epoch))[start:end]
+    if isinstance(indices, np.ndarray):
+        return indices[members].astype(np.int64, copy=False)
+    return np.fromiter((indices[i] for i in members), np.int64, count=end - start)
+
+
 def get_beacon_proposer_index(spec, state) -> int:
     """Balance-weighted rejection sampling over the first committee of the slot
     (reference 0_beacon-chain.md:819-841).
@@ -365,7 +419,7 @@ def get_beacon_proposer_index(spec, state) -> int:
     committee memo (scripts/build_spec.py:78-91)."""
     memo = getattr(state, "_proposer_memo", None)
     if memo is not None and memo[0] == (int(state.slot),
-                                        len(state.validator_registry)):
+                                        len(spec.registry_view(state))):
         return memo[1]
     return _compute_beacon_proposer_index(spec, state)
 
@@ -405,44 +459,71 @@ def verify_bitfield(spec, bitfield: bytes, committee_size: int) -> bool:
     return True
 
 
-def get_attesting_indices(spec, state, attestation_data, bitfield: bytes) -> List[int]:
-    committee = spec.get_crosslink_committee(state, attestation_data.target_epoch, attestation_data.crosslink.shard)
+def _set_bits(bitfield: bytes, size: int) -> np.ndarray:
+    """Positions of the set bits among the first `size` of a bitfield that
+    `verify_bitfield` has passed."""
+    bits = np.unpackbits(np.frombuffer(bytes(bitfield), np.uint8), bitorder="little")
+    return np.flatnonzero(bits[:size])
+
+
+def _attesting_members(spec, committee: np.ndarray, bitfield: bytes) -> np.ndarray:
+    """The members of `committee` whose bit is set, ascending."""
     assert spec.verify_bitfield(bitfield, len(committee))
-    return sorted(index for i, index in enumerate(committee) if spec.get_bitfield_bit(bitfield, i) == 0b1)
+    return np.sort(committee[_set_bits(bitfield, len(committee))])
+
+
+def get_attesting_indices(spec, state, attestation_data, bitfield: bytes) -> List[int]:
+    committee = spec.get_crosslink_committee_array(
+        state, attestation_data.target_epoch, attestation_data.crosslink.shard)
+    return _attesting_members(spec, committee, bitfield).tolist()
 
 
 def convert_to_indexed(spec, state, attestation):
-    attesting_indices = spec.get_attesting_indices(state, attestation.data, attestation.aggregation_bitfield)
-    custody_bit_1_indices = spec.get_attesting_indices(state, attestation.data, attestation.custody_bitfield)
-    custody_bit_0_indices = [i for i in attesting_indices if i not in custody_bit_1_indices]
+    # one committee for both bitfields, each checked and read as an array
+    committee = spec.get_crosslink_committee_array(
+        state, attestation.data.target_epoch, attestation.data.crosslink.shard)
+    attesting_indices = _attesting_members(spec, committee, attestation.aggregation_bitfield)
+    custody_bit_1_indices = _attesting_members(spec, committee, attestation.custody_bitfield)
+    custody_bit_0_indices = attesting_indices[~np.isin(attesting_indices, custody_bit_1_indices)]
     return spec.IndexedAttestation(
-        custody_bit_0_indices=custody_bit_0_indices,
-        custody_bit_1_indices=custody_bit_1_indices,
+        custody_bit_0_indices=custody_bit_0_indices.tolist(),
+        custody_bit_1_indices=custody_bit_1_indices.tolist(),
         data=attestation.data,
         signature=attestation.signature,
     )
 
 
+def _ascending(indices: np.ndarray) -> bool:
+    return bool((indices[1:] >= indices[:-1]).all())
+
+
 def validate_indexed_attestation(spec, state, indexed_attestation) -> None:
-    bit_0_indices = indexed_attestation.custody_bit_0_indices
-    bit_1_indices = indexed_attestation.custody_bit_1_indices
+    bit_0_indices = np.asarray(indexed_attestation.custody_bit_0_indices, dtype=np.uint64)
+    bit_1_indices = np.asarray(indexed_attestation.custody_bit_1_indices, dtype=np.uint64)
 
     # No custody bits set yet [phase 0], bounded size, disjoint, sorted.
     assert len(bit_1_indices) == 0
     assert len(bit_0_indices) + len(bit_1_indices) <= spec.MAX_INDICES_PER_ATTESTATION
-    assert len(set(bit_0_indices) & set(bit_1_indices)) == 0
-    assert list(bit_0_indices) == sorted(bit_0_indices) and list(bit_1_indices) == sorted(bit_1_indices)
-    pubkey_sets = [
-        [state.validator_registry[i].pubkey for i in bit_0_indices],
-        [state.validator_registry[i].pubkey for i in bit_1_indices],
-    ]
+    assert np.intersect1d(bit_0_indices, bit_1_indices).size == 0
+    assert _ascending(bit_0_indices) and _ascending(bit_1_indices)
+    # every index names a validator: the object model's list access raises
+    # here, and so does this, whether or not a signature makes it look
+    registry = spec.registry_view(state)
+    for indices in (bit_0_indices, bit_1_indices):
+        if indices.size and int(indices[-1]) >= len(registry):
+            raise IndexError(f"validator index {int(indices[-1])} outside a registry of {len(registry)}")
+    if not spec.bls.bls_active:
+        # nothing reads the pubkey sets or the message hashes: every
+        # verify answers True unread (crypto/bls.py)
+        return
+    pubkey_sets = [registry.pubkeys(bit_0_indices.tolist()), registry.pubkeys(bit_1_indices.tolist())]
     message_hashes = [
         spec.hash_tree_root(spec.AttestationDataAndCustodyBit(data=indexed_attestation.data, custody_bit=False)),
         spec.hash_tree_root(spec.AttestationDataAndCustodyBit(data=indexed_attestation.data, custody_bit=True)),
     ]
     domain = spec.get_domain(state, spec.DOMAIN_ATTESTATION, indexed_attestation.data.target_epoch)
     sink = spec._att_verify_sink
-    if sink is not None and spec.bls.bls_active:
+    if sink is not None:
         # Deferred: process_operations collects the whole block's checks
         # into one grouped device pipeline (block.py) — the verdict is
         # asserted there, with identical failure semantics.

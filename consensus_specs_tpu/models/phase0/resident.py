@@ -38,8 +38,16 @@ small byte-rooted fields. This module makes that story real:
     forest's own programs over the zero-filled index column
     (`_active_index_root`): one upload, one node down, no pair of it
     hashed on the host.
+  * a block whose operations are attestations only is processed on the
+    resident state (`process_block`): what the spec's block code reads of
+    the registry (the proposer's `slashed` flag and pubkey, the pubkey sets
+    of indexed attestations, the registry's length) is answered by the
+    view the core registers for its state (helpers.registry_view), so a
+    checkpoint-resumed (light) core, which has no Validator objects, takes
+    a chain of attestation-full blocks like an object-entered one.
   * blocks carrying registry-mutating operations (slashings, deposits,
-    exits, transfers) take the fallback: exit residency (one writeback),
+    exits, transfers) take the fallback (a light core refuses them, having
+    no objects to fall back to): exit residency (one writeback),
     process the block through the untouched object path, re-enter
     INCREMENTALLY — the re-entry diffs the columns against the pre-block
     snapshot, scatters only the changed rows back to device, and updates
@@ -112,6 +120,15 @@ _SLOT_ROOT_NOTES = {"pairs_hashed": bulk.HOST_PAIRS_HASHED,
                     "leaves_updated": host_tree.LEAVES_UPDATED,
                     "trees_rebuilt": host_tree.TREE_REBUILDS}
 
+# Blocks that left the served path for the object model (_fallback_block).
+_BLOCK_FALLBACKS = telemetry.counter("resident.block.fallbacks", always=True)
+# The lists of a block's body that touch the registry or the balances: a
+# block with all of them empty is served on the resident state; a
+# checkpoint-resumed core, which has no objects to fall back to, refuses
+# any other (the cut `registry_operations` of the configurations it serves).
+_REGISTRY_OPERATIONS = ("proposer_slashings", "attester_slashings",
+                        "deposits", "voluntary_exits", "transfers")
+
 # Per-core watchdog key prefix: layout fingerprints must not leak between
 # cores (a mesh core and a single-device core in one test process would
 # otherwise trip false re-layout events against each other's placements).
@@ -150,13 +167,34 @@ def _balance_chunk_words_np(bal: np.ndarray, chunk_idx: np.ndarray) -> np.ndarra
     return bytes_to_words(chunks)
 
 
-def _common_path_block(block) -> bool:
-    """True when the block touches no registry/balance state on the host
-    side (header/randao/eth1/attestations only)."""
-    b = block.body
-    return not (len(b.proposer_slashings) or len(b.attester_slashings)
-                or len(b.deposits) or len(b.voluntary_exits)
-                or len(b.transfers))
+class _ColumnsRegistry:
+    """`helpers.registry_view` for a resident core's own state: the reads
+    block processing makes of the registry, answered by the host mirrors
+    and the core's host copy of the resident pubkeys (identity columns
+    never change while resident), on one device and on a mesh alike. A
+    light core's state has no validator list to answer them."""
+
+    __slots__ = ("_core",)
+
+    def __init__(self, core):
+        self._core = core
+
+    @property
+    def state(self):
+        return self._core.state
+
+    def __len__(self) -> int:
+        return self._core._v
+
+    def slashed(self, index: int) -> bool:
+        return bool(self._core.mirrors["slashed"][index])
+
+    def pubkey(self, index: int) -> bytes:
+        return self._core._pk_np[index].tobytes()
+
+    def pubkeys(self, indices) -> list:
+        rows = self._core._pk_np[np.asarray(indices, np.int64)]
+        return [row.tobytes() for row in rows]
 
 
 def _serving_mesh(mesh):
@@ -214,9 +252,12 @@ class ResidentCore:
         entry (`ResidentCore(spec, state)`) exists for states that already
         live as objects.
 
-        A light-resident core drives slots and epoch boundaries; full
-        block processing and exit() need the object registry and are the
-        standard entry's job.
+        A light-resident core drives slots, epoch boundaries and blocks
+        whose operations are attestations only (state_transition /
+        process_block: the registry is read through the core's view, the
+        mirrors and the resident pubkeys); a block that carries a
+        registry-touching operation, and exit(), need the object registry
+        and are the standard entry's job.
 
         Truncated or garbage bytes raise the TYPED `CheckpointCorrupt`
         (resilience/errors.py) up front — never an opaque struct/index
@@ -401,6 +442,7 @@ class ResidentCore:
         exit that moves a handful of validators costs O(dirty * log V)
         compressions, not the ~2M-leaf rebuild the old all-or-nothing
         `_big_roots` cache forced."""
+        _BLOCK_FALLBACKS.inc()
         old_np = self._materialize_np_cols()
         try:
             _apply_validator_columns(self.state, ValidatorColumns(**old_np))
@@ -615,6 +657,9 @@ class ResidentCore:
         for name, fn in overrides.items():
             self._saved_methods[name] = getattr(spec, name)
             setattr(spec, name, fn)
+        # not an override: the block path asks every state for its registry
+        # view (helpers.registry_view), and this state's is the columns
+        spec._registry_views[id(self.state)] = _ColumnsRegistry(self)
         self._saved_root_backend = helpers_mod._state_root_backend
         helpers_mod.set_state_root_backend(self._state_root)
 
@@ -622,6 +667,7 @@ class ResidentCore:
         for name, fn in self._saved_methods.items():
             setattr(self.spec, name, fn)
         self._saved_methods.clear()
+        self.spec._registry_views.pop(id(self.state), None)
         helpers_mod.set_state_root_backend(self._saved_root_backend)
         self._saved_root_backend = None
 
@@ -800,20 +846,61 @@ class ResidentCore:
     # -- transition drive ---------------------------------------------------
 
     def state_transition(self, state, block):
-        if self._light:
-            # fail loudly BEFORE process_slots mutates state (matching the
-            # exit() guard): block processing reads the object registry,
-            # which a checkpoint-resumed core deliberately never built
-            raise NotImplementedError(
-                "a checkpoint-resumed (light) resident core drives slots "
-                "and epoch boundaries only; blocks need the object "
-                "registry — resume via the standard ResidentCore entry")
+        """`process_slots` to the block's slot, then `process_block`: on a
+        checkpoint-resumed (light) core and on an object-entered one
+        alike."""
+        # a block this core refuses fails loudly BEFORE process_slots
+        # mutates state (matching the exit() guard)
+        self._registry_operations(block)
         self.process_slots(state, block.slot)
-        if _common_path_block(block):
-            self.spec.process_block(state, block)
-        else:
-            self._fallback_block(state, block)
+        self.process_block(state, block)
         return state
+
+    def _registry_operations(self, block) -> list:
+        """The registry-touching lists of the block's body that are not
+        empty; none when the block is header, randao, eth1 vote and
+        attestations only. A light core serves only such blocks: any other
+        needs the object registry (_fallback_block), which a
+        checkpoint-resumed core deliberately never built, and is refused
+        here by name: no silent fallback through a million objects."""
+        touched = [name for name in _REGISTRY_OPERATIONS
+                   if len(getattr(block.body, name))]
+        if touched and self._light:
+            raise NotImplementedError(
+                f"registry_operations are cut from what a checkpoint-resumed "
+                f"(light) resident core serves: the block at slot "
+                f"{int(block.slot)} carries {', '.join(touched)}, which "
+                f"need the object registry — resume via the standard "
+                f"ResidentCore entry")
+        return touched
+
+    def process_block(self, state, block) -> None:
+        """The spec's `process_block` on the resident state: its four steps
+        in its order, each under its span, the registry read through this
+        core's view (helpers.registry_view). A block with a
+        registry-touching operation takes `_fallback_block` on an
+        object-entered core and is refused, before anything is written,
+        by a light one."""
+        if self._registry_operations(block):
+            self._fallback_block(state, block)
+            return
+        spec, body = self.spec, block.body
+        with telemetry.span("resident.block", req=int(block.slot)) as sp:
+            with telemetry.span("resident.block.header"):
+                spec.process_block_header(state, block)
+            with telemetry.span("resident.block.randao"):
+                spec.process_randao(state, body)
+            with telemetry.span("resident.block.eth1"):
+                spec.process_eth1_data(state, body)
+            with telemetry.span("resident.block.attestations"):
+                spec.process_operations(state, body)
+            # every bitfield has passed verify_bitfield: a set bit is an
+            # attesting index
+            sp.note(attestations=len(body.attestations),
+                    attesting_indices=sum(
+                        int.from_bytes(bytes(a.aggregation_bitfield),
+                                       "little").bit_count()
+                        for a in body.attestations))
 
     def process_slots(self, state, slot: int) -> None:
         assert state.slot <= slot

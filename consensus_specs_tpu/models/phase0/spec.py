@@ -71,6 +71,11 @@ class Phase0Spec:
         # dispatch (block.process_attestations_batched)
         self._streaming_verifier = None
 
+        # Registry views (helpers.registry_view): id(state) -> the view a
+        # resident core registered for the state it holds as columns; every
+        # other state is answered by its own validator list
+        self._registry_views: Dict[int, object] = {}
+
         # Caches (reference epilogue: build_spec.py:78-105)
         self._hash_cache: Dict[bytes, bytes] = {}
         self._perm_cache: Dict = {}

@@ -32,7 +32,10 @@ roots `resident.slot` / `resident.boundary_slot` (`req` = the slot) over
 `resident.slot_root` with its groups `.forests .attestations .history
 .small .merkleize`, and at a boundary `resident.stage` (`.distill
 .upload`), `resident.device`, `resident.refresh` (`.download
-.final_updates`) and `resident.forests`; `resident.checkpoint_write`
+.final_updates`) and `resident.forests`; `resident.block` (a root of
+its own, `req` = the block's slot: the slot's root span has closed when
+`process_slots` returned) over `.header .randao .eth1 .attestations`;
+`resident.checkpoint_write`
 (`.download .assemble`) and `resident.restore` (`.decode .upload`)),
 `firehose.*` (streaming-verifier pipeline stages: stage/dispatch/flush,
 exit-only fences), `bench.*` / `followup.*` (harnesses); counters
@@ -41,7 +44,8 @@ lanes/launches/builds), `merkle.host.*` (pairs hashed / taken from the
 zero-hash table by the host Merkleizer), `scalar_mul.*`, `bls.grouped.*` (grouped-pairing
 launch occupancy), `firehose.*` (queue depth / batch occupancy /
 deadline misses — always-on: /healthz reads them), `watchdog.*`
-(retrace/re-layout events), `jax.backend_compiles` (global compile
+(retrace/re-layout events), `resident.block.fallbacks` (blocks that left
+the served path for the object model; always-on), `jax.backend_compiles` (global compile
 listener).
 """
 from .core import (Counter, Gauge, Histogram, Span, counter, enabled,

@@ -220,19 +220,21 @@ def test_overrides_delegate_for_foreign_state(spec):
         core.exit()
 
 
-def test_light_core_refuses_state_transition(spec):
-    """A checkpoint-resumed (light) core must fail loudly BEFORE
-    process_slots mutates state: block processing needs the object
-    registry the light entry deliberately never built."""
+def test_light_core_refuses_a_registry_touching_block_before_any_write(spec):
+    """A checkpoint-resumed (light) core takes blocks whose operations are
+    attestations only (tests/test_resident_blocks.py); one that carries a
+    registry-touching operation needs the object registry the light entry
+    deliberately never built, and must fail loudly BEFORE process_slots
+    mutates state, naming the cut."""
     state = factories.seed_genesis_state(spec, 2 * spec.SLOTS_PER_EPOCH)
     data = serialize(state, spec.BeaconState)
     core = ResidentCore.from_checkpoint(spec, data)
     try:
-        block = SimpleNamespace(slot=int(state.slot) + 1)
-        before = int(core.state.slot)
-        with pytest.raises(NotImplementedError):
+        block = spec.BeaconBlock(slot=int(state.slot) + 1)
+        block.body.voluntary_exits.append(spec.VoluntaryExit())
+        with pytest.raises(NotImplementedError, match="registry_operations"):
             core.state_transition(core.state, block)
-        assert int(core.state.slot) == before   # nothing mutated
+        assert core.checkpoint_bytes() == data      # nothing mutated
     finally:
         core._uninstall()
 

@@ -1,0 +1,440 @@
+"""The block path of the resident core: a checkpoint-resumed (light) core,
+which holds no Validator objects, takes a chain of attestation-full blocks.
+
+The differential chain against the unpatched object model is
+tests/test_resident_block_chain.py (a file of its own, so that the workers
+share the two). Here: `state_transition` is `process_slots` then
+`process_block`, a block the spec rejects is rejected at the spec's place, a
+registry-touching block is refused before anything is written, the registry
+view answers an object state as its list does, the array form of the
+attestation family equals the spec's bit-by-bit words, and a block leaves
+its span tree.
+"""
+import sys
+import traceback
+from copy import deepcopy
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+REPO = Path(__file__).resolve().parents[1]
+if str(REPO) not in sys.path:
+    sys.path.insert(0, str(REPO))
+
+from benchmark.block_generator import BlockGenerator, eighths  # noqa: E402
+from consensus_specs_tpu import telemetry  # noqa: E402
+from consensus_specs_tpu.crypto import bls  # noqa: E402
+from consensus_specs_tpu.models import phase0  # noqa: E402
+from consensus_specs_tpu.models.phase0 import helpers  # noqa: E402
+from consensus_specs_tpu.models.phase0.resident import ResidentCore  # noqa: E402
+from consensus_specs_tpu.testing import factories  # noqa: E402
+from consensus_specs_tpu.utils.ssz.impl import hash_tree_root, serialize  # noqa: E402
+
+SEED = 2**31 + 99
+
+
+@pytest.fixture
+def minimal():
+    bls.bls_active = False
+    spec = phase0.get_spec("minimal")
+    spec.clear_caches()
+    state = factories.seed_genesis_state(spec, 64)
+    factories.advance_slots(spec, state, 2 * int(spec.SLOTS_PER_EPOCH) + 2)
+    yield spec, state, serialize(state, spec.BeaconState)
+    spec.clear_caches()
+
+
+def test_state_transition_is_process_slots_then_process_block(minimal):
+    spec, state, data = minimal
+    one = ResidentCore.from_checkpoint(spec, data, mesh=None)
+    generator = BlockGenerator(spec, SEED, aggregates=8)
+    blocks = []
+    try:
+        for _ in range(int(spec.SLOTS_PER_EPOCH) + 2):
+            one.process_slots(one.state, int(one.state.slot) + 1)
+            blocks.append(generator.block(one.state))
+            one.process_block(one.state, blocks[-1])
+        by_parts = one.checkpoint_bytes()
+    finally:
+        one._uninstall()
+    two = ResidentCore.from_checkpoint(spec, data, mesh=None)
+    try:
+        for block in blocks:
+            assert two.state_transition(two.state, block) is two.state
+        assert two.checkpoint_bytes() == by_parts
+    finally:
+        two._uninstall()
+    # and an object-entered core takes the same entry points
+    three = ResidentCore(spec, deepcopy(state), mesh=None)
+    try:
+        for block in blocks:
+            three.state_transition(three.state, block)
+        assert three.checkpoint_bytes() == by_parts
+    finally:
+        three._uninstall()
+
+
+# -- a block the spec rejects is rejected, at the spec's place -------------------
+
+def _first(block):
+    return block.body.attestations[0]
+
+
+def _flip(root, at=0):
+    out = bytearray(bytes(root))
+    out[at] ^= 1
+    return bytes(out)
+
+
+def _wrong_slot(spec, state, block):
+    block.slot += 1
+
+
+def _wrong_parent_root(spec, state, block):
+    block.parent_root = _flip(block.parent_root)
+
+
+def _too_many_attestations(spec, state, block):
+    block.body.attestations.extend(
+        deepcopy(_first(block)) for _ in range(int(spec.MAX_ATTESTATIONS)))
+
+
+def _included_too_early(spec, state, block):
+    # the committee of the slot just before the block's
+    early = BlockGenerator(spec, SEED, 8).attestations(state, int(state.slot) - 1)
+    block.body.attestations[0] = early[0]
+
+
+def _older_than_an_epoch(spec, state, block):
+    old = BlockGenerator(spec, SEED, 8).attestations(
+        state, int(state.slot) - int(spec.SLOTS_PER_EPOCH) - 1)
+    block.body.attestations[0] = old[0]
+
+
+def _wrong_source_epoch(spec, state, block):
+    _first(block).data.source_epoch += 1
+
+
+def _wrong_source_root(spec, state, block):
+    _first(block).data.source_root = _flip(_first(block).data.source_root)
+
+
+def _wrong_crosslink_start(spec, state, block):
+    _first(block).data.crosslink.start_epoch += 1
+
+
+def _wrong_crosslink_end(spec, state, block):
+    _first(block).data.crosslink.end_epoch += 1
+
+
+def _wrong_crosslink_parent_root(spec, state, block):
+    link = _first(block).data.crosslink
+    link.parent_root = _flip(link.parent_root, 7)
+
+
+def _crosslink_data_root_set(spec, state, block):
+    _first(block).data.crosslink.data_root = b"\x01" * 32
+
+
+def _bitfield_a_byte_too_long(spec, state, block):
+    _first(block).aggregation_bitfield += b"\x00"
+
+
+def _bit_past_the_committees_end(spec, state, block):
+    # a committee of 12: the last byte's upper four bits are padding
+    att = _first(block)
+    assert len(spec.get_crosslink_committee(
+        state, att.data.target_epoch, att.data.crosslink.shard)) % 8
+    att.aggregation_bitfield = att.aggregation_bitfield[:-1] \
+        + bytes([att.aggregation_bitfield[-1] | 0x80])
+
+
+def _custody_bit_set(spec, state, block):
+    _first(block).custody_bitfield = _first(block).aggregation_bitfield
+
+
+def _custody_bitfield_too_short(spec, state, block):
+    _first(block).custody_bitfield = _first(block).custody_bitfield[:-1]
+
+
+SPOILS = [_wrong_slot, _wrong_parent_root, _too_many_attestations,
+          _included_too_early, _older_than_an_epoch, _wrong_source_epoch,
+          _wrong_source_root, _wrong_crosslink_start, _wrong_crosslink_end,
+          _wrong_crosslink_parent_root, _crosslink_data_root_set,
+          _bitfield_a_byte_too_long, _bit_past_the_committees_end,
+          _custody_bit_set, _custody_bitfield_too_short]
+
+
+@pytest.fixture
+def odd_committees():
+    """Minimal preset with 96 validators: committees of 12, whose bitfields
+    end in four padding bits; entry two slots into the third epoch."""
+    bls.bls_active = False
+    spec = phase0.get_spec("minimal")
+    spec.clear_caches()
+    state = factories.seed_genesis_state(spec, 96)
+    factories.advance_slots(spec, state, 2 * int(spec.SLOTS_PER_EPOCH) + 2)
+    yield spec, state, serialize(state, spec.BeaconState)
+    spec.clear_caches()
+
+
+def _where_it_raises(fn):
+    """(exception type, function, line) of the innermost frame that raised."""
+    try:
+        fn()
+    except (AssertionError, IndexError) as exc:
+        frame = traceback.extract_tb(exc.__traceback__)[-1]
+        return type(exc).__name__, frame.name, frame.lineno
+    return None
+
+
+@pytest.mark.parametrize("spoil", SPOILS, ids=lambda f: f.__name__.lstrip("_"))
+def test_a_spoiled_block_raises_on_the_light_core_where_the_object_model_raises(
+        odd_committees, spoil):
+    spec, state, data = odd_committees
+    core = ResidentCore.from_checkpoint(spec, data, mesh=None)
+    res = core.state
+    try:
+        core.process_slots(res, int(res.slot) + 1)
+        block = BlockGenerator(spec, SEED, 8).block(res)
+        assert len(block.body.attestations) >= 2
+        spoil(spec, res, block)
+        with core.suspended():
+            ref = _advanced(spec, state)
+            # before the spoil the block is sound: the spoil is what fails
+            spec.process_block(_advanced(spec, state),
+                               BlockGenerator(spec, SEED, 8).block(ref))
+            want = _where_it_raises(lambda: spec.process_block(ref, block))
+        assert want is not None, "the object model takes the spoiled block"
+        assert _where_it_raises(lambda: core.process_block(res, block)) == want
+    finally:
+        core._uninstall()
+
+
+def _advanced(spec, state):
+    fresh = deepcopy(state)
+    spec.process_slots(fresh, int(fresh.slot) + 1)
+    return fresh
+
+
+# -- what the light core refuses -------------------------------------------------
+
+@pytest.mark.parametrize("name,operation", [
+    ("proposer_slashings", "ProposerSlashing"),
+    ("attester_slashings", "AttesterSlashing"),
+    ("deposits", "Deposit"), ("voluntary_exits", "VoluntaryExit"),
+    ("transfers", "Transfer")])
+def test_a_registry_touching_block_is_refused_before_anything_is_written(
+        minimal, name, operation):
+    spec, _, data = minimal
+    core = ResidentCore.from_checkpoint(spec, data, mesh=None)
+    try:
+        block = spec.BeaconBlock(slot=int(core.state.slot) + 3)
+        getattr(block.body, name).append(getattr(spec, operation)())
+        with pytest.raises(NotImplementedError, match="registry_operations") as exc:
+            core.state_transition(core.state, block)
+        assert name in str(exc.value)
+        assert core.checkpoint_bytes() == data
+        with pytest.raises(NotImplementedError, match="registry_operations"):
+            core.process_block(core.state, block)
+        assert core.checkpoint_bytes() == data
+    finally:
+        core._uninstall()
+
+
+# -- the registry view ----------------------------------------------------------
+
+def test_the_view_of_an_object_state_answers_as_its_list_does(minimal):
+    spec, state, _ = minimal
+    state.validator_registry[5].slashed = True
+    view = spec.registry_view(state)
+    assert isinstance(view, helpers.ObjectRegistry) and view.state is state
+    assert len(view) == len(state.validator_registry) == 64
+    assert [view.slashed(i) for i in (4, 5)] == [False, True]
+    assert view.pubkey(7) == state.validator_registry[7].pubkey
+    assert view.pubkeys([3, 1, 2]) == [state.validator_registry[i].pubkey
+                                       for i in (3, 1, 2)]
+    with pytest.raises(IndexError):
+        view.pubkey(64)
+
+
+@pytest.mark.parametrize("light", [False, True], ids=["object-entered", "light"])
+def test_a_resident_cores_view_answers_from_its_columns(minimal, light):
+    spec, state, data = minimal
+    state.validator_registry[5].slashed = True
+    data = serialize(state, spec.BeaconState)
+    core = (ResidentCore.from_checkpoint(spec, data, mesh=None) if light
+            else ResidentCore(spec, state, mesh=None))
+    try:
+        view = spec.registry_view(core.state)
+        assert not isinstance(view, helpers.ObjectRegistry)
+        assert view.state is core.state and len(view) == 64
+        assert len(core.state.validator_registry) == (0 if light else 64)
+        obj = helpers.ObjectRegistry(state)
+        assert [view.slashed(i) for i in range(64)] \
+            == [obj.slashed(i) for i in range(64)]
+        assert bytes(view.pubkey(9)) == bytes(obj.pubkey(9))
+        assert [bytes(k) for k in view.pubkeys([9, 2, 63])] \
+            == [bytes(k) for k in obj.pubkeys([9, 2, 63])]
+        with pytest.raises(IndexError):
+            view.pubkey(64)
+        # another state is never answered from this core's columns
+        other = deepcopy(state)
+        assert isinstance(spec.registry_view(other), helpers.ObjectRegistry)
+        with core.suspended():
+            assert isinstance(spec.registry_view(core.state),
+                              helpers.ObjectRegistry)
+        assert type(spec.registry_view(core.state)) is type(view)
+    finally:
+        core._uninstall()
+    assert spec._registry_views == {}
+
+
+def test_the_proposer_memo_keys_on_the_views_length(minimal):
+    """On a light core `len(state.validator_registry)` is 0; the memo's key
+    takes V from the view, and is dropped when the family returns."""
+    spec, _, data = minimal
+    core = ResidentCore.from_checkpoint(spec, data, mesh=None)
+    res = core.state
+    try:
+        core.process_slots(res, int(res.slot) + 1)
+        block = BlockGenerator(spec, SEED, 8).block(res)
+        seen = []
+        real = spec.process_attestation
+
+        def watching(state, attestation):
+            seen.append(state._proposer_memo)
+            return real(state, attestation)
+        spec.process_attestation = watching
+        try:
+            core.process_block(res, block)
+        finally:
+            spec.process_attestation = real
+        proposer = spec.get_beacon_proposer_index(res)
+        assert seen and all(m == ((int(res.slot), 64), proposer) for m in seen)
+        assert res._proposer_memo is None
+    finally:
+        core._uninstall()
+
+
+# -- the array form against the spec's own words --------------------------------
+
+def _spec_attesting_indices(spec, state, data, bitfield):
+    """get_attesting_indices as v0.6 writes it, bit by bit."""
+    committee = spec.get_crosslink_committee(state, data.target_epoch,
+                                             data.crosslink.shard)
+    assert spec.verify_bitfield(bitfield, len(committee))
+    return sorted(index for i, index in enumerate(committee)
+                  if spec.get_bitfield_bit(bitfield, i) == 0b1)
+
+
+def test_attesting_indices_equal_the_bit_by_bit_form(odd_committees):
+    spec, state, _ = odd_committees
+    rng = np.random.default_rng(SEED)
+    att = factories.new_attestation(spec, state, int(state.slot) - 1)
+    size = len(spec.get_crosslink_committee(
+        state, att.data.target_epoch, att.data.crosslink.shard))
+    assert size == 12
+    for _ in range(40):
+        bits = np.zeros(16, np.uint8)
+        bits[:size] = rng.integers(0, 2, size)
+        bitfield = np.packbits(bits, bitorder="little").tobytes()
+        got = spec.get_attesting_indices(state, att.data, bitfield)
+        assert got == _spec_attesting_indices(spec, state, att.data, bitfield)
+        assert all(type(i) is int for i in got)
+    committee = spec.get_crosslink_committee_array(
+        state, att.data.target_epoch, att.data.crosslink.shard)
+    assert committee.dtype == np.int64 and committee.tolist() \
+        == spec.get_crosslink_committee(state, att.data.target_epoch,
+                                        att.data.crosslink.shard)
+    for bad in (b"\xff", b"\xff\xff\x00", b"\xff\x1f"):
+        with pytest.raises(AssertionError):
+            spec.get_attesting_indices(state, att.data, bad)
+
+
+@pytest.mark.parametrize("bit_0,bit_1,raises", [
+    ([1, 2, 9], [], None), ([], [], None),
+    ([1, 2], [5], AssertionError),              # a custody bit [phase 0]
+    ([2, 1], [], AssertionError),               # out of order
+    ([1, 1, 2], [], None),                      # list == sorted(list) holds
+    ([1, 96], [], IndexError),                  # names no validator
+    ([2**64 - 1], [], IndexError),
+    (list(range(4097)), [], AssertionError),    # MAX_INDICES_PER_ATTESTATION
+])
+def test_validate_indexed_attestation_checks_on_arrays(
+        odd_committees, bit_0, bit_1, raises):
+    spec, state, _ = odd_committees
+    indexed = spec.IndexedAttestation(custody_bit_0_indices=bit_0,
+                                      custody_bit_1_indices=bit_1)
+    if raises is None:
+        spec.validate_indexed_attestation(state, indexed)
+    else:
+        with pytest.raises(raises):
+            spec.validate_indexed_attestation(state, indexed)
+
+
+@pytest.mark.parametrize("size,parts", [(976, 8), (977, 8), (128, 8), (12, 8),
+                                        (5, 8), (1, 8), (32, 3)])
+def test_eighths_partition_the_committee(size, parts):
+    fields = eighths(size, parts)
+    assert len(fields) == min(size, parts)
+    bits = np.stack([np.unpackbits(np.frombuffer(f, np.uint8), bitorder="little")
+                     for f in fields])
+    assert all(len(f) == (size + 7) // 8 for f in fields)
+    assert (bits.sum(axis=0)[:size] == 1).all() and not bits[:, size:].any()
+    runs = bits.sum(axis=1)
+    assert runs.max() - runs.min() <= 1
+
+
+# -- spans and the fallback counter -----------------------------------------------
+
+BLOCK_PARTS = ("header", "randao", "eth1", "attestations")
+
+
+def test_a_block_leaves_one_span_tree_and_counts_no_fallback(minimal):
+    spec, state, data = minimal
+    telemetry.set_enabled(True)
+    telemetry.reset()
+    fallbacks = telemetry.counter("resident.block.fallbacks", always=True)
+    core = ResidentCore.from_checkpoint(spec, data, mesh=None)
+    res = core.state
+    try:
+        before = fallbacks.value
+        core.process_slots(res, int(res.slot) + 1)
+        block = BlockGenerator(spec, SEED, 8).block(res)
+        core.process_block(res, block)
+        records = telemetry.ring()
+        root, = [r for r in records if r["name"] == "resident.block"]
+        assert root["parent_id"] == 0 and root["req"] == int(block.slot)
+        children = [r for r in records if r["parent_id"] == root["id"]]
+        assert [c["name"] for c in children] \
+            == [f"resident.block.{part}" for part in BLOCK_PARTS]
+        assert all(c["req"] == int(block.slot) for c in children)
+        assert sum(c["dur"] for c in children) <= root["dur"]
+        assert root["args"]["attestations"] == len(block.body.attestations) == 8
+        # one committee of 8 in full: the aggregates' bits add up to it
+        assert root["args"]["attesting_indices"] == 8
+        assert fallbacks.value == before
+    finally:
+        core._uninstall()
+        telemetry.set_enabled(None)
+
+
+def test_the_fallback_of_an_object_entered_core_is_counted(minimal):
+    spec, state, _ = minimal
+    fallbacks = telemetry.counter("resident.block.fallbacks", always=True)
+    ref = deepcopy(state)
+    core = ResidentCore(spec, state, mesh=None)
+    try:
+        before = fallbacks.value
+        with core.suspended():
+            block = factories.empty_block_next(spec, ref)
+            block.body.proposer_slashings.append(
+                factories.double_proposal(spec, ref))
+            spec.state_transition(ref, block)
+        core.state_transition(state, block)
+        assert fallbacks.value == before + 1
+        assert hash_tree_root(ref) == core._state_root(state)
+    finally:
+        core.exit()
