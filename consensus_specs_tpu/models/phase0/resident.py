@@ -118,7 +118,8 @@ _FOREST_PAIR_LANES = telemetry.counter("merkle.forest.pair_lanes")
 _SLOT_ROOT_NOTES = {"pairs_hashed": bulk.HOST_PAIRS_HASHED,
                     "pairs_zero_filled": bulk.HOST_PAIRS_ZERO_FILLED,
                     "leaves_updated": host_tree.LEAVES_UPDATED,
-                    "trees_rebuilt": host_tree.TREE_REBUILDS}
+                    "trees_rebuilt": host_tree.TREE_REBUILDS,
+                    "plan_elements": bulk.PLAN_ELEMENTS}
 
 # Blocks that left the served path for the object model (_fallback_block).
 _BLOCK_FALLBACKS = telemetry.counter("resident.block.fallbacks", always=True)

@@ -23,6 +23,8 @@ This module computes the same roots from *columns*:
 shape it cannot vectorize back through the recursive oracle, so it is safe
 to call on arbitrary objects and bit-identical by construction (asserted in
 tests/test_bulk_htr.py). `state_root_bulk` is the BeaconState entry point.
+A single small container (a PendingAttestation, a header) is no column at
+all: it is rooted by its type's root plan (root_plan.py, `plan_roots`).
 """
 from __future__ import annotations
 
@@ -34,6 +36,7 @@ import numpy as np
 from ..hash import ZERO_BYTES32, zerohashes
 from ...telemetry import counter as _tele_counter
 from . import impl
+from .root_plan import Plan, plan_for
 from .typing import (
     is_bool_type, is_bytesn_type, is_container_type, is_list_kind,
     is_list_type, is_uint_type, is_vector_type, read_elem_type,
@@ -50,6 +53,8 @@ _DEVICE_MIN_PAIRS = 1 << 15
 # the zero-hash table. Bumped once a call, never once a pair.
 HOST_PAIRS_HASHED = _tele_counter("merkle.host.pairs_hashed")
 HOST_PAIRS_ZERO_FILLED = _tele_counter("merkle.host.pairs_zero_filled")
+# Container values rooted through their type's root plan (root_plan.py).
+PLAN_ELEMENTS = _tele_counter("merkle.host.plan_elements")
 
 
 # ---------------------------------------------------------------------------
@@ -429,10 +434,36 @@ def container_list_roots(objs: Sequence[Any], elem_type: Any) -> np.ndarray:
 # Generic bulk dispatcher
 # ---------------------------------------------------------------------------
 
+def plan_roots(plan: Plan, values: Sequence[Any]) -> bytes:
+    """The roots of k containers of one type through that type's root plan
+    (root_plan.plan_for), 32 k bytes: the batch form of a container's
+    root. Every pair is hashed by hashlib from the values as they are now;
+    the pairs and the elements are counted once a call."""
+    rows, hashed = [], 0
+    for v in values:
+        root, pairs = plan(v)
+        rows.append(root)
+        hashed += pairs
+    HOST_PAIRS_HASHED.inc(hashed)
+    PLAN_ELEMENTS.inc(len(rows))
+    return b"".join(rows)
+
+
 def hash_tree_root_bulk(obj: Any, typ: Any = None) -> bytes:
     """Same value as impl.hash_tree_root, with device-batched fast paths for
     big homogeneous collections. Falls back to the recursive oracle for
-    anything it can't vectorize."""
+    anything it can't vectorize.
+
+    A container whose type has a root plan (root_plan.py: every field a
+    uint, a bool, a BytesN, `bytes` or such a container: Fork, Eth1Data,
+    Crosslink, AttestationData, PendingAttestation, Attestation,
+    BeaconBlockHeader, Validator and the like) is rooted by the plan. One
+    with a list or vector field (BeaconState, BeaconBlockBody,
+    HistoricalBatch, IndexedAttestation) is walked field by field here, so
+    that its wide fields reach the column paths: a list or vector of
+    `container_list_is_fast` elements goes through numpy columns
+    (container_list_roots), any other composite element comes back here
+    one by one."""
     if typ is None:
         return impl.hash_tree_root(obj)
 
@@ -461,6 +492,9 @@ def hash_tree_root_bulk(obj: Any, typ: Any = None) -> bytes:
         return impl.mix_in_length(root, n) if is_list_kind(typ) else root
 
     if is_container_type(typ):
+        plan = plan_for(typ)
+        if plan is not None:
+            return plan_roots(plan, (obj,))
         roots = [hash_tree_root_bulk(v, t) for v, t in obj.get_typed_values()]
         if len(roots) < _MEMO_MIN_CHUNKS:
             return merkleize_few(roots)

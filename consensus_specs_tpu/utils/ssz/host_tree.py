@@ -18,6 +18,13 @@ inputs did not move.
     learns what changed from the tracked list's record, the second from
     the identity of the elements it has already taken.
 
+What a tree hashes anew is an element's own root first (`_leaf_rows`):
+a container whose type has a root plan (root_plan.py: every field a uint,
+a bool, a BytesN, `bytes` or such a container; PendingAttestation with its
+AttestationData and Crosslink) through the plan's batch form, 64 or more
+`container_list_is_fast` elements at once (a vector of crosslinks assigned
+anew) through bulk's numpy columns, a Bytes32 as it is.
+
 Differential gate: tests/test_host_tree.py (against merkle.merkleize_chunks
 and impl.hash_tree_root).
 """
@@ -33,6 +40,7 @@ import numpy as np
 from ..hash import ZERO_BYTES32, zerohashes
 from ...telemetry import counter as _tele_counter
 from . import bulk, impl
+from .root_plan import plan_for
 from .typing import is_bytesn_type, is_list_kind
 
 # Leaves re-hashed through a tree (update or append) and trees built from
@@ -176,7 +184,14 @@ del _name
 
 
 def _leaf_rows(values: Sequence[Any], elem_type: Any) -> bytes:
-    """The tree leaves of composite or BytesN elements, 32 bytes a value."""
+    """The tree leaves of composite or BytesN elements, 32 bytes a value.
+    Bytes32 values are their own leaves. `container_list_is_fast` elements
+    (Crosslink, Eth1Data) from `_MEMO_MIN_CHUNKS` values up take bulk's
+    numpy columns, where the width pays for them (a vector of 1,024
+    crosslinks assigned anew). Any other container whose type has a root
+    plan (root_plan.py: PendingAttestation, and the few crosslinks an
+    epoch writes) takes the plan's batch form, one call for all of them;
+    an element type without a plan takes bulk's dispatcher value by value."""
     if is_bytesn_type(elem_type) and elem_type.length == 32:
         rows = b"".join(values)
         if len(rows) != 32 * len(values):
@@ -185,6 +200,9 @@ def _leaf_rows(values: Sequence[Any], elem_type: Any) -> bytes:
     if (len(values) >= bulk._MEMO_MIN_CHUNKS
             and bulk.container_list_is_fast(elem_type)):
         return bulk.container_list_roots(values, elem_type).tobytes()
+    plan = plan_for(elem_type)
+    if plan is not None:
+        return bulk.plan_roots(plan, values)
     return b"".join(bulk.hash_tree_root_bulk(v, elem_type) for v in values)
 
 

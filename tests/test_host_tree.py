@@ -47,11 +47,12 @@ def counting():
 
 
 class Work:
-    """Deltas of the four host Merkle counters over a `with` block."""
+    """Deltas of the five host Merkle counters over a `with` block."""
     COUNTERS = {"pairs_hashed": bulk.HOST_PAIRS_HASHED,
                 "pairs_zero_filled": bulk.HOST_PAIRS_ZERO_FILLED,
                 "leaves_updated": host_tree.LEAVES_UPDATED,
-                "trees_rebuilt": host_tree.TREE_REBUILDS}
+                "trees_rebuilt": host_tree.TREE_REBUILDS,
+                "plan_elements": bulk.PLAN_ELEMENTS}
 
     def __enter__(self):
         self._before = {k: c.value for k, c in self.COUNTERS.items()}
@@ -299,6 +300,45 @@ def test_append_only_list_hashes_only_its_new_tail():
     with Work() as work:                # nothing new: nothing hashed
         tree.root()
     assert (work.pairs_hashed, work.leaves_updated) == (0, 0)
+
+
+@pytest.mark.parametrize("k,bitfield_bytes,pairs", [
+    (16, 122, [303, 304, 305, 305]),        # a replay slot's committees
+    (128, 16, [2047, 2048, 2049, 2049])])   # a full block's aggregates
+def test_new_attestations_go_through_the_root_plan(k, bitfield_bytes, pairs):
+    """k new PendingAttestations a root: k elements through the type's
+    root plan, and exactly the pairs the recursive path hashed for the
+    same appends (`pairs`: read off the parent of PR 35, whose _leaf_rows
+    sent each element through bulk.hash_tree_root_bulk's field walk:
+    k x (15 + the bitfield's 3 or 0) element pairs + the new leaves' paths)."""
+    from consensus_specs_tpu.models import phase0
+    spec = phase0.get_spec("mainnet")
+    rng = np.random.default_rng(k)
+
+    def root32():
+        return bytes(_rand_chunks(rng, 1)[0])
+
+    def pending(i):
+        return spec.PendingAttestation(
+            aggregation_bitfield=bytes(
+                rng.integers(0, 256, bitfield_bytes, dtype=np.uint8)),
+            data=spec.AttestationData(
+                beacon_block_root=root32(), source_epoch=i,
+                source_root=root32(), target_epoch=i + 1, target_root=root32(),
+                crosslink=spec.Crosslink(
+                    shard=i % 1024, start_epoch=i, end_epoch=i + 1,
+                    parent_root=root32(), data_root=root32())),
+            inclusion_delay=4, proposer_index=7 * i)
+
+    typ = List[spec.PendingAttestation]
+    atts = []
+    tree = AppendOnlyListTree(atts, typ)
+    for slot, want in enumerate(pairs):
+        atts.extend(pending(k * slot + i) for i in range(k))
+        with Work() as work:
+            assert tree.root() == impl.hash_tree_root(list(atts), typ)
+        assert (work.plan_elements, work.leaves_updated, work.trees_rebuilt,
+                work.pairs_hashed) == (k, k, 0, want)
 
 
 @pytest.mark.parametrize("change", ["replaced", "shorter", "reordered"])

@@ -527,7 +527,8 @@ def test_slot_root_rehashes_only_what_was_written(spec, spans):
              if r["name"] == "resident.slot_root"]
     assert len(noted) == int(ref.slot) - int(state.slot) > 2 * spe
     assert all(set(args) == {"pairs_hashed", "pairs_zero_filled",
-                             "leaves_updated", "trees_rebuilt"}
+                             "leaves_updated", "trees_rebuilt",
+                             "plan_elements"}
                for _, args in noted)
     assert noted[0][1]["trees_rebuilt"] == 10       # the core's first root
     for slot, args in noted[1:]:
@@ -538,6 +539,9 @@ def test_slot_root_rehashes_only_what_was_written(spec, spans):
             continue
         assert args["trees_rebuilt"] == 0, slot
         assert args["leaves_updated"] == 2 + block_leaves.get(slot, 0), slot
+        # through a root plan: the fork, the header and the eth1 data, and
+        # the attestation a block appended
+        assert args["plan_elements"] == 3 + (slot in block_leaves), slot
 
 
 def test_checkpoint_of_a_tracked_state_is_the_plain_one(spec):
