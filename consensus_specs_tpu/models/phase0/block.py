@@ -56,30 +56,44 @@ def process_eth1_data(spec, state, body) -> None:
         state.latest_eth1_data = body.eth1_data
 
 
-def process_operations(spec, state, body) -> None:
+# The six operation lists of a block's body in the spec's fixed order: the
+# body's list -> (the preset's per-block maximum, the per-operation handler).
+# The attestation list is processed as a family (process_attestations_batched
+# collapses its signature checks into one device pipeline).
+OPERATIONS = {
+    "proposer_slashings": ("MAX_PROPOSER_SLASHINGS", "process_proposer_slashing"),
+    "attester_slashings": ("MAX_ATTESTER_SLASHINGS", "process_attester_slashing"),
+    "attestations": ("MAX_ATTESTATIONS", "process_attestation"),
+    "deposits": ("MAX_DEPOSITS", "process_deposit"),
+    "voluntary_exits": ("MAX_VOLUNTARY_EXITS", "process_voluntary_exit"),
+    "transfers": ("MAX_TRANSFERS", "process_transfer"),
+}
+
+
+def check_operations(spec, state, body) -> None:
+    """What `process_operations` asserts of the body before any operation."""
     # Outstanding deposits must be processed up to the per-block maximum
     assert len(body.deposits) == min(spec.MAX_DEPOSITS,
                                      state.latest_eth1_data.deposit_count - state.deposit_index)
     # No duplicate transfers
     assert len(body.transfers) == len(set(body.transfers))
 
-    # family = whole-list processor (the attestation family batches its
-    # signature checks into one device pipeline); handler = per-operation
-    for operations, max_operations, handler, family in (
-        (body.proposer_slashings, spec.MAX_PROPOSER_SLASHINGS, spec.process_proposer_slashing, None),
-        (body.attester_slashings, spec.MAX_ATTESTER_SLASHINGS, spec.process_attester_slashing, None),
-        (body.attestations, spec.MAX_ATTESTATIONS, spec.process_attestation, process_attestations_batched),
-        (body.deposits, spec.MAX_DEPOSITS, spec.process_deposit, None),
-        (body.voluntary_exits, spec.MAX_VOLUNTARY_EXITS, spec.process_voluntary_exit, None),
-        (body.transfers, spec.MAX_TRANSFERS, spec.process_transfer, None),
-    ):
-        assert len(operations) <= max_operations
-        if family is not None:
-            family(spec, state, operations)
-        else:
-            for operation in operations:
-                handler(state, operation)
 
+def process_operation_list(spec, state, body, name: str) -> None:
+    """One of the body's six lists (`OPERATIONS`), whole: its per-block
+    maximum, then every operation through its handler."""
+    max_name, handler = OPERATIONS[name]
+    operations = getattr(body, name)
+    assert len(operations) <= getattr(spec, max_name)
+    if name == "attestations":
+        process_attestations_batched(spec, state, operations)
+    else:
+        handle = getattr(spec, handler)
+        for operation in operations:
+            handle(state, operation)
+
+
+def process_extra_operations(spec, state, body) -> None:
     # Later phases append operation families after all phase-0 ops (the
     # reference appends them via spec-doc ordering, 1_custody-game.md:330+)
     for body_attr, max_operations, handler in spec._extra_block_operations:
@@ -87,6 +101,13 @@ def process_operations(spec, state, body) -> None:
         assert len(operations) <= max_operations
         for operation in operations:
             handler(state, operation)
+
+
+def process_operations(spec, state, body) -> None:
+    spec.check_operations(state, body)
+    for name in OPERATIONS:
+        spec.process_operation_list(state, body, name)
+    spec.process_extra_operations(state, body)
 
 
 _batching_enabled = True
@@ -153,17 +174,22 @@ def process_attestations_batched(spec, state, attestations) -> None:
 
 
 def process_proposer_slashing(spec, state, proposer_slashing) -> None:
-    proposer = state.validator_registry[proposer_slashing.proposer_index]
+    registry = spec.registry_view(state)
+    index = proposer_slashing.proposer_index
     # Same epoch, different headers, slashable proposer, both signatures valid
     assert spec.slot_to_epoch(proposer_slashing.header_1.slot) == \
         spec.slot_to_epoch(proposer_slashing.header_2.slot)
     assert proposer_slashing.header_1 != proposer_slashing.header_2
-    assert spec.is_slashable_validator(proposer, spec.get_current_epoch(state))
-    for header in (proposer_slashing.header_1, proposer_slashing.header_2):
-        domain = spec.get_domain(state, spec.DOMAIN_BEACON_PROPOSER, spec.slot_to_epoch(header.slot))
-        assert spec.bls.bls_verify(proposer.pubkey, spec.signing_root(header), header.signature, domain)
+    assert spec.is_slashable_index(state, index, spec.get_current_epoch(state))
+    if spec.bls.bls_active:
+        # the messages are roots of both headers: computed only for a
+        # verify that reads them
+        pubkey = registry.pubkey(index)
+        for header in (proposer_slashing.header_1, proposer_slashing.header_2):
+            domain = spec.get_domain(state, spec.DOMAIN_BEACON_PROPOSER, spec.slot_to_epoch(header.slot))
+            assert spec.bls.bls_verify(pubkey, spec.signing_root(header), header.signature, domain)
 
-    spec.slash_validator(state, proposer_slashing.proposer_index)
+    spec.slash_validator(state, index)
 
 
 def process_attester_slashing(spec, state, attester_slashing) -> None:
@@ -177,7 +203,7 @@ def process_attester_slashing(spec, state, attester_slashing) -> None:
     attesting_indices_1 = list(attestation_1.custody_bit_0_indices) + list(attestation_1.custody_bit_1_indices)
     attesting_indices_2 = list(attestation_2.custody_bit_0_indices) + list(attestation_2.custody_bit_1_indices)
     for index in sorted(set(attesting_indices_1) & set(attesting_indices_2)):
-        if spec.is_slashable_validator(state.validator_registry[index], spec.get_current_epoch(state)):
+        if spec.is_slashable_index(state, index, spec.get_current_epoch(state)):
             spec.slash_validator(state, index)
             slashed_any = True
     assert slashed_any
@@ -256,16 +282,22 @@ def process_deposit(spec, state, deposit) -> None:
 
 
 def process_voluntary_exit(spec, state, exit) -> None:
-    validator = state.validator_registry[exit.validator_index]
+    registry = spec.registry_view(state)
+    index = exit.validator_index
+    current_epoch = spec.get_current_epoch(state)
+    activation_epoch = registry.activation_epoch(index)
+    exit_epoch = registry.exit_epoch(index)
     # Active, not yet exited, exit epoch reached, active long enough, signed
-    assert spec.is_active_validator(validator, spec.get_current_epoch(state))
-    assert validator.exit_epoch == spec.FAR_FUTURE_EPOCH
-    assert spec.get_current_epoch(state) >= exit.epoch
-    assert spec.get_current_epoch(state) >= validator.activation_epoch + spec.PERSISTENT_COMMITTEE_PERIOD
-    domain = spec.get_domain(state, spec.DOMAIN_VOLUNTARY_EXIT, exit.epoch)
-    assert spec.bls.bls_verify(validator.pubkey, spec.signing_root(exit), exit.signature, domain)
+    assert activation_epoch <= current_epoch < exit_epoch
+    assert exit_epoch == spec.FAR_FUTURE_EPOCH
+    assert current_epoch >= exit.epoch
+    assert current_epoch >= activation_epoch + spec.PERSISTENT_COMMITTEE_PERIOD
+    # the message is the exit's root: computed only for a verify that reads it
+    assert not spec.bls.bls_active or spec.bls.bls_verify(
+        registry.pubkey(index), spec.signing_root(exit), exit.signature,
+        spec.get_domain(state, spec.DOMAIN_VOLUNTARY_EXIT, exit.epoch))
 
-    spec.initiate_validator_exit(state, exit.validator_index)
+    spec.initiate_validator_exit(state, index)
 
 
 def process_transfer(spec, state, transfer) -> None:

@@ -167,11 +167,11 @@ def compute_active_index_root(spec, state, epoch: int) -> bytes:
 
 
 def increase_balance(spec, state, index: int, delta: int) -> None:
-    state.balances[index] += delta
+    spec.registry_view(state).increase_balance(index, delta)
 
 
 def decrease_balance(spec, state, index: int, delta: int) -> None:
-    state.balances[index] = 0 if delta > state.balances[index] else state.balances[index] - delta
+    spec.registry_view(state).decrease_balance(index, delta)
 
 
 def effective_balance_of(spec, state, index: int) -> int:
@@ -187,14 +187,18 @@ def get_total_balance(spec, state, indices: Sequence[int]) -> int:
 
 
 class ObjectRegistry:
-    """The registry as block processing reads it (`registry_view`), answered
-    by an object state's validator list: how many validators there are, one
-    validator's `slashed` flag and pubkey, the pubkeys of an index set."""
+    """The registry as block processing reads and writes it
+    (`registry_view`), answered by an object state's validator list and
+    balances: how many validators there are; one validator's `slashed`
+    flag, pubkey, epochs and effective balance; the pubkeys of an index
+    set; the exit queue; and the writes of an exit, a slashing and a
+    balance move."""
 
-    __slots__ = ("state",)
+    __slots__ = ("state", "far")
 
-    def __init__(self, state):
+    def __init__(self, state, far: int = 2 ** 64 - 1):
         self.state = state
+        self.far = far
 
     def __len__(self) -> int:
         return len(self.state.validator_registry)
@@ -209,18 +213,56 @@ class ObjectRegistry:
         registry = self.state.validator_registry
         return [registry[i].pubkey for i in indices]
 
+    def activation_epoch(self, index: int) -> int:
+        return self.state.validator_registry[index].activation_epoch
+
+    def exit_epoch(self, index: int) -> int:
+        return self.state.validator_registry[index].exit_epoch
+
+    def withdrawable_epoch(self, index: int) -> int:
+        return self.state.validator_registry[index].withdrawable_epoch
+
+    def effective_balance(self, index: int) -> int:
+        return self.state.validator_registry[index].effective_balance
+
+    def exit_queue(self, floor_epoch: int) -> tuple:
+        """(the exit queue's head epoch, how many validators exit in it):
+        the later of `floor_epoch` and the last exit epoch any validator
+        has, by the spec's two scans of the registry."""
+        registry = self.state.validator_registry
+        exit_epochs = [v.exit_epoch for v in registry if v.exit_epoch != self.far]
+        head = max(exit_epochs + [floor_epoch])
+        return head, sum(1 for v in registry if v.exit_epoch == head)
+
+    def initiate_exit(self, index: int, exit_epoch: int, withdrawable_epoch: int) -> None:
+        validator = self.state.validator_registry[index]
+        validator.exit_epoch = exit_epoch
+        validator.withdrawable_epoch = withdrawable_epoch
+
+    def slash(self, index: int, withdrawable_epoch: int) -> None:
+        validator = self.state.validator_registry[index]
+        validator.slashed = True
+        validator.withdrawable_epoch = withdrawable_epoch
+
+    def increase_balance(self, index: int, delta: int) -> None:
+        self.state.balances[index] += delta
+
+    def decrease_balance(self, index: int, delta: int) -> None:
+        balances = self.state.balances
+        balances[index] = 0 if delta > balances[index] else balances[index] - delta
+
 
 def registry_view(spec, state):
-    """Who answers block processing's reads of `state`'s registry: the view
-    a resident core has registered for its own state (its host mirrors and
-    resident pubkeys; models/phase0/resident.py), else the state's own
-    validator list. Every state is asked the same way, so the block path
-    has one implementation whether the registry lives as objects or as
-    columns."""
+    """Who answers block processing's reads and writes of `state`'s
+    registry: the view a resident core has registered for its own state
+    (its host mirrors, resident pubkeys and device columns;
+    models/phase0/resident.py), else the state's own validator list. Every
+    state is asked the same way, so the block path has one implementation
+    whether the registry lives as objects or as columns."""
     view = spec._registry_views.get(id(state))
     if view is not None and view.state is state:
         return view
-    return ObjectRegistry(state)
+    return ObjectRegistry(state, spec.FAR_FUTURE_EPOCH)
 
 
 def get_churn_limit(spec, state) -> int:
@@ -565,27 +607,35 @@ def verify_merkle_branch(spec, leaf: bytes, proof: Sequence[bytes], depth: int, 
 # Validator status mutations
 # ---------------------------------------------------------------------------
 
+def is_slashable_index(spec, state, index: int, epoch: int) -> bool:
+    """`is_slashable_validator` of the validator at `index`, read through
+    the state's registry view."""
+    registry = spec.registry_view(state)
+    return (not registry.slashed(index)) and (
+        registry.activation_epoch(index) <= epoch < registry.withdrawable_epoch(index))
+
+
 def initiate_validator_exit(spec, state, index: int) -> None:
-    validator = state.validator_registry[index]
-    if validator.exit_epoch != spec.FAR_FUTURE_EPOCH:
+    registry = spec.registry_view(state)
+    if registry.exit_epoch(index) != spec.FAR_FUTURE_EPOCH:
         return
 
-    exit_epochs = [v.exit_epoch for v in state.validator_registry if v.exit_epoch != spec.FAR_FUTURE_EPOCH]
-    exit_queue_epoch = max(exit_epochs + [spec.get_delayed_activation_exit_epoch(spec.get_current_epoch(state))])
-    exit_queue_churn = sum(1 for v in state.validator_registry if v.exit_epoch == exit_queue_epoch)
+    # the spec's two scans of the registry are the view's to answer
+    exit_queue_epoch, exit_queue_churn = registry.exit_queue(
+        spec.get_delayed_activation_exit_epoch(spec.get_current_epoch(state)))
     if exit_queue_churn >= spec.get_churn_limit(state):
         exit_queue_epoch += 1
 
-    validator.exit_epoch = exit_queue_epoch
-    validator.withdrawable_epoch = validator.exit_epoch + spec.MIN_VALIDATOR_WITHDRAWABILITY_DELAY
+    registry.initiate_exit(index, exit_queue_epoch,
+                           exit_queue_epoch + spec.MIN_VALIDATOR_WITHDRAWABILITY_DELAY)
 
 
 def slash_validator(spec, state, slashed_index: int, whistleblower_index: Optional[int] = None) -> None:
+    registry = spec.registry_view(state)
     current_epoch = spec.get_current_epoch(state)
     spec.initiate_validator_exit(state, slashed_index)
-    state.validator_registry[slashed_index].slashed = True
-    state.validator_registry[slashed_index].withdrawable_epoch = current_epoch + spec.LATEST_SLASHED_EXIT_LENGTH
-    slashed_balance = state.validator_registry[slashed_index].effective_balance
+    registry.slash(slashed_index, current_epoch + spec.LATEST_SLASHED_EXIT_LENGTH)
+    slashed_balance = registry.effective_balance(slashed_index)
     state.latest_slashed_balances[current_epoch % spec.LATEST_SLASHED_EXIT_LENGTH] += slashed_balance
 
     proposer_index = spec.get_beacon_proposer_index(state)

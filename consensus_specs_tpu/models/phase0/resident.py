@@ -38,16 +38,25 @@ small byte-rooted fields. This module makes that story real:
     forest's own programs over the zero-filled index column
     (`_active_index_root`): one upload, one node down, no pair of it
     hashed on the host.
-  * a block whose operations are attestations only is processed on the
-    resident state (`process_block`): what the spec's block code reads of
-    the registry (the proposer's `slashed` flag and pubkey, the pubkey sets
-    of indexed attestations, the registry's length) is answered by the
-    view the core registers for its state (helpers.registry_view), so a
+  * a block whose operations are attestations, voluntary exits and
+    proposer and attester slashings is processed on the resident state
+    (`process_block`): what the spec's block code reads and writes of the
+    registry (the proposer's `slashed` flag and pubkey, the pubkey sets of
+    indexed attestations, the registry's length; a validator's epochs and
+    effective balance, the exit queue's head; an exit's and a slashing's
+    rows, a slashing's balance moves) goes through the view the core
+    registers for its state (helpers.registry_view), so a
     checkpoint-resumed (light) core, which has no Validator objects, takes
-    a chain of attestation-full blocks like an object-entered one.
-  * blocks carrying registry-mutating operations (slashings, deposits,
-    exits, transfers) take the fallback (a light core refuses them, having
-    no objects to fall back to): exit residency (one writeback),
+    such a chain like an object-entered one. An operation costs its own
+    rows: the host mirrors take a write at once, the device columns take
+    the block's dirty rows when its last operation has passed
+    (`resident.registry_write`), both forests their dirty leaves' paths
+    (`resident.forests.update`: one program a forest), and the next slot's
+    root reads the forests' roots as they then are. A block the spec
+    rejects leaves columns, mirrors and forests as they were.
+  * blocks carrying deposits or transfers take the fallback (a light core
+    refuses them, having no objects to fall back to): exit residency (one
+    writeback),
     process the block through the untouched object path, re-enter
     INCREMENTALLY — the re-entry diffs the columns against the pre-block
     snapshot, scatters only the changed rows back to device, and updates
@@ -80,7 +89,8 @@ from ...utils.merkle import tree_depth
 from ...utils.ssz import bulk, host_tree
 from ...utils.ssz import impl as ssz_impl
 from ...utils.ssz.incremental import (IncrementalMerkleTree,
-                                      ShardedIncrementalMerkleTree)
+                                      ShardedIncrementalMerkleTree,
+                                      bucket_indices)
 from ...utils.ssz.typing import Vector
 from . import helpers as helpers_mod
 from .epoch_soa import (REPLICATED_INPUT_FIELDS, EpochConfig,
@@ -123,12 +133,13 @@ _SLOT_ROOT_NOTES = {"pairs_hashed": bulk.HOST_PAIRS_HASHED,
 
 # Blocks that left the served path for the object model (_fallback_block).
 _BLOCK_FALLBACKS = telemetry.counter("resident.block.fallbacks", always=True)
-# The lists of a block's body that touch the registry or the balances: a
-# block with all of them empty is served on the resident state; a
-# checkpoint-resumed core, which has no objects to fall back to, refuses
-# any other (the cut `registry_operations` of the configurations it serves).
-_REGISTRY_OPERATIONS = ("proposer_slashings", "attester_slashings",
-                        "deposits", "voluntary_exits", "transfers")
+# The lists of a block's body that the resident state does not serve: a
+# deposit of a new validator changes V, the shape of every device column
+# and forest, and a transfer is cut from the preset (MAX_TRANSFERS 0). A
+# block that carries one takes the object model on an object-entered core;
+# a checkpoint-resumed core, which has no objects to fall back to, refuses
+# it. Exits and both kinds of slashing are served (process_block).
+_UNSERVED_OPERATIONS = ("deposits", "transfers")
 
 # Per-core watchdog key prefix: layout fingerprints must not leak between
 # cores (a mesh core and a single-device core in one test process would
@@ -168,12 +179,39 @@ def _balance_chunk_words_np(bal: np.ndarray, chunk_idx: np.ndarray) -> np.ndarra
     return bytes_to_words(chunks)
 
 
+class _BlockWrites:
+    """What the operations of one served block have written through the
+    registry view so far: the `withdrawable_epoch` an exit or a slashing
+    gave each validator it touched (its keys are the block's dirty
+    registry rows: the mirrors have their `exit_epoch` and `slashed`
+    already, the device columns get all three when the block's last
+    operation has passed), the balance moves in the order they were made,
+    and what it takes to put the mirrors and the exit queue back if the
+    block is rejected."""
+
+    __slots__ = ("withdrawable", "balance_moves", "undo", "exit_queue")
+
+    def __init__(self, exit_queue):
+        self.withdrawable: dict = {}     # validator -> withdrawable_epoch
+        self.balance_moves: list = []    # (index, up, down)
+        self.undo: list = []             # (mirror, index, the value it had)
+        self.exit_queue = None if exit_queue is None else list(exit_queue)
+
+    @property
+    def dirty(self) -> bool:
+        return bool(self.balance_moves or self.withdrawable)
+
+
 class _ColumnsRegistry:
     """`helpers.registry_view` for a resident core's own state: the reads
-    block processing makes of the registry, answered by the host mirrors
-    and the core's host copy of the resident pubkeys (identity columns
-    never change while resident), on one device and on a mesh alike. A
-    light core's state has no validator list to answer them."""
+    and writes block processing makes of the registry, answered by the
+    host mirrors, the core's host copy of the resident pubkeys (identity
+    columns never change while resident) and, for what has no mirror (a
+    validator's `withdrawable_epoch`, the balances), the device columns a
+    few rows at a time, on one device and on a mesh alike. A light core's
+    state has no validator list to answer them. Every read and write
+    costs its own rows, never the registry (`exit_queue`: the queue's
+    head epoch and count are kept, `ResidentCore._exit_queue_head`)."""
 
     __slots__ = ("_core",)
 
@@ -187,15 +225,103 @@ class _ColumnsRegistry:
     def __len__(self) -> int:
         return self._core._v
 
+    def _row(self, index) -> int:
+        index = int(index)
+        if not 0 <= index < self._core._v:
+            raise IndexError(f"validator index {index} outside a registry "
+                             f"of {self._core._v}")
+        return index
+
     def slashed(self, index: int) -> bool:
-        return bool(self._core.mirrors["slashed"][index])
+        return bool(self._core.mirrors["slashed"][self._row(index)])
 
     def pubkey(self, index: int) -> bytes:
-        return self._core._pk_np[index].tobytes()
+        return self._core._pk_np[self._row(index)].tobytes()
 
     def pubkeys(self, indices) -> list:
         rows = self._core._pk_np[np.asarray(indices, np.int64)]
         return [row.tobytes() for row in rows]
+
+    def activation_epoch(self, index: int) -> int:
+        return int(self._core.mirrors["activation_epoch"][self._row(index)])
+
+    def exit_epoch(self, index: int) -> int:
+        return int(self._core.mirrors["exit_epoch"][self._row(index)])
+
+    def effective_balance(self, index: int) -> int:
+        return int(self._core.mirrors["effective_balance"][self._row(index)])
+
+    def withdrawable_epoch(self, index: int) -> int:
+        return self._core._withdrawable_epoch(self._row(index))
+
+    def exit_queue(self, floor_epoch: int) -> tuple:
+        return self._core._exit_queue_head(int(floor_epoch))
+
+    def initiate_exit(self, index: int, exit_epoch: int,
+                      withdrawable_epoch: int) -> None:
+        self._core._write_exit(self._row(index), int(exit_epoch),
+                               int(withdrawable_epoch))
+
+    def slash(self, index: int, withdrawable_epoch: int) -> None:
+        self._core._write_slash(self._row(index), int(withdrawable_epoch))
+
+    def increase_balance(self, index: int, delta: int) -> None:
+        self._core._move_balance(self._row(index), int(delta), 0)
+
+    def decrease_balance(self, index: int, delta: int) -> None:
+        self._core._move_balance(self._row(index), 0, int(delta))
+
+
+def _rows_at_traced(column, idx):
+    return column[idx]
+
+
+def _leaves_at_traced(pk_rows, wc_rows, elig, act, exit_ep, withdrawable,
+                      slashed, eff, idx, unroll):
+    """[k, 8] registry leaves (validator roots) of the rows `idx`: their
+    identity bytes as the host holds them (a gather from the [V, 48]
+    pubkey column costs the device a copy of it), the rest from the device
+    columns as they stand."""
+    return bulk._registry_leaf_words(
+        pk_rows, wc_rows, elig[idx], act[idx], exit_ep[idx],
+        withdrawable[idx], slashed[idx], eff[idx], unroll=unroll)
+
+
+def _write_rows_traced(exit_ep, withdrawable, slashed, idx, exit_rows,
+                       withdrawable_rows, slashed_rows):
+    """A block's dirty registry rows into the three columns its operations
+    write, one dispatch."""
+    return (exit_ep.at[idx].set(exit_rows),
+            withdrawable.at[idx].set(withdrawable_rows),
+            slashed.at[idx].set(slashed_rows))
+
+
+def _balance_chunks_at_traced(balance, chunks, count):
+    """[k, 8] words of the balances list's pack chunks `chunks`, from the
+    device column as it stands; positions from `count` (the logical length)
+    on are the pack's zero padding."""
+    import jax.numpy as jnp
+    pos = chunks[:, None] * 4 + jnp.arange(4, dtype=chunks.dtype)[None, :]
+    vals = jnp.where(pos < count,
+                     balance[jnp.minimum(pos, balance.shape[0] - 1)],
+                     jnp.zeros((), dtype=balance.dtype))
+    return bulk._balances_chunk_words(vals.reshape(-1))
+
+
+def _move_balance_traced(balance, index, up, down):
+    """increase_balance by `up`, then decrease_balance by `down` (the
+    spec's: to zero where the balance is smaller), of one row."""
+    import jax.numpy as jnp
+    b = balance[index] + up
+    return balance.at[index].set(jnp.where(down > b, jnp.zeros_like(b),
+                                           b - down))
+
+
+_rows_at = jax.jit(_rows_at_traced)
+_leaves_at = jax.jit(_leaves_at_traced, static_argnames=("unroll",))
+_write_rows = jax.jit(_write_rows_traced)
+_balance_chunks_at = jax.jit(_balance_chunks_at_traced)
+_move_balance = jax.jit(_move_balance_traced)
 
 
 def _serving_mesh(mesh):
@@ -237,6 +363,7 @@ class ResidentCore:
         self._active_idx_memo: Dict[int, np.ndarray] = {}
         self._host_trees: Dict[tuple, object] = {}
         self._light = False
+        self._writes: Optional[_BlockWrites] = None
         self._enter(state)
 
     # -- residency lifecycle ------------------------------------------------
@@ -254,11 +381,12 @@ class ResidentCore:
         live as objects.
 
         A light-resident core drives slots, epoch boundaries and blocks
-        whose operations are attestations only (state_transition /
-        process_block: the registry is read through the core's view, the
-        mirrors and the resident pubkeys); a block that carries a
-        registry-touching operation, and exit(), need the object registry
-        and are the standard entry's job.
+        whose operations are attestations, voluntary exits and proposer
+        and attester slashings (state_transition / process_block: the
+        registry is read and written through the core's view: the mirrors,
+        the resident pubkeys, the device columns' dirty rows); a block
+        that carries a deposit or a transfer, and exit(), need the object
+        registry and are the standard entry's job.
 
         Truncated or garbage bytes raise the TYPED `CheckpointCorrupt`
         (resilience/errors.py) up front — never an opaque struct/index
@@ -312,6 +440,7 @@ class ResidentCore:
         core._active_idx_memo = {}
         core._host_trees = {}
         core._light = True
+        core._writes = None
         with telemetry.span("resident.restore.upload") as sp:
             core._enter(state, np_cols=np_cols)
             sp.fence(core.cols, core.pk_dev)    # the uploads have landed
@@ -366,6 +495,10 @@ class ResidentCore:
         self._reg_forest: Optional[IncrementalMerkleTree] = None
         self._bal_forest: Optional[IncrementalMerkleTree] = None
         self._active_idx_memo.clear()
+        # [the last exit epoch any validator has, how many have it]; None
+        # until an exit asks (_exit_queue_head) and whenever something
+        # other than a served exit may have moved it
+        self._exit_queue: Optional[list] = None
         self._install()
 
     def exit(self):
@@ -385,13 +518,18 @@ class ResidentCore:
                 "registry to materialize into; serialize via "
                 "checkpoint_bytes() instead")
         try:
-            _apply_validator_columns(
-                self.state, ValidatorColumns(**self._materialize_np_cols()))
-            # _apply_validator_columns skips `slashed` (the epoch program
-            # never writes it); the object copy is already authoritative.
+            self._write_back(self._materialize_np_cols())
         finally:
             self._uninstall()
         return self.state
+
+    def _write_back(self, np_cols: Dict[str, np.ndarray]) -> None:
+        """The columns into the object state's registry and balances.
+        `_apply_validator_columns` leaves `slashed` out (the epoch program
+        never writes it); a served slashing does, on the columns."""
+        _apply_validator_columns(self.state, ValidatorColumns(**np_cols))
+        for i in np.nonzero(np_cols["slashed"])[0]:
+            self.state.validator_registry[int(i)].slashed = True
 
     def _materialize_np_cols(self) -> Dict[str, np.ndarray]:
         """One download of the device columns as a host dict (sliced back
@@ -446,7 +584,7 @@ class ResidentCore:
         _BLOCK_FALLBACKS.inc()
         old_np = self._materialize_np_cols()
         try:
-            _apply_validator_columns(self.state, ValidatorColumns(**old_np))
+            self._write_back(old_np)
         finally:
             self._uninstall()
         self.spec.process_block(state, block)
@@ -513,6 +651,7 @@ class ResidentCore:
         self._v = new_n
         self.mirrors = {f: np_cols[f].copy() for f in _MIRROR_FIELDS}
         self._active_idx_memo.clear()
+        self._exit_queue = None
         self._update_forests(np_cols, old_n, dirty)
         self._big_roots = None
         self._install()
@@ -544,38 +683,54 @@ class ResidentCore:
     def _update_forests(self, np_cols: Dict[str, np.ndarray], old_n: int,
                         dirty: Dict[str, np.ndarray]) -> None:
         """Leaf-granularity forest invalidation after an object-path block:
-        recompute only the touched validators' leaves (host-side, O(dirty))
-        and re-hash their root paths; append leaves/chunks for registry
-        growth — the append-grow path crosses padded powers of two exactly
-        like utils/ssz/incremental.py's tests."""
+        the touched validators' leaves and balance chunks take the served
+        path's `_update_forest_paths` (from the device columns, which have
+        the block's rows already); leaves and chunks of registry growth are
+        appended — the append-grow path crosses padded powers of two
+        exactly like utils/ssz/incremental.py's tests."""
         new_n = np_cols["balance"].shape[0]
-        if self._reg_forest is not None:
-            reg_dirty = np.unique(np.concatenate(
-                [dirty[f] for f in self._LEAF_FIELDS]))
-            if reg_dirty.size:
-                self._reg_forest.update(
-                    reg_dirty.astype(np.int32),
-                    self._registry_leaf_words_np(np_cols, reg_dirty))
-            if new_n > old_n:
-                grown_idx = np.arange(old_n, new_n)
-                self._reg_forest.append(
-                    self._registry_leaf_words_np(np_cols, grown_idx))
+        chunks = dirty["balance"] // 4
+        if new_n > old_n and old_n % 4:
+            # growth refills the old partial tail chunk in place
+            chunks = np.concatenate([chunks, [old_n // 4]])
+        self._update_forest_paths(
+            np.unique(np.concatenate([dirty[f] for f in self._LEAF_FIELDS])),
+            np.unique(chunks))
+        if self._reg_forest is not None and new_n > old_n:
+            self._reg_forest.append(self._registry_leaf_words_np(
+                np_cols, np.arange(old_n, new_n)))
         if self._bal_forest is not None:
-            bal = np_cols["balance"]
             old_c = max(1, -(-old_n // 4))
             new_c = max(1, -(-new_n // 4))
-            chunk_dirty = dirty["balance"] // 4
-            if new_n > old_n and old_n % 4:
-                # growth refills the old partial tail chunk in place
-                chunk_dirty = np.concatenate([chunk_dirty, [old_n // 4]])
-            chunk_dirty = np.unique(chunk_dirty)
-            if chunk_dirty.size:
-                self._bal_forest.update(
-                    chunk_dirty.astype(np.int32),
-                    _balance_chunk_words_np(bal, chunk_dirty))
             if new_c > old_c:
                 self._bal_forest.append(_balance_chunk_words_np(
-                    bal, np.arange(old_c, new_c)))
+                    np_cols["balance"], np.arange(old_c, new_c)))
+
+    def _update_forest_paths(self, rows: np.ndarray,
+                             chunks: np.ndarray) -> None:
+        """The per-slot dirty path of both forests: the registry leaves of
+        the validators `rows` and the balances chunks `chunks`, computed on
+        the device from the columns as they stand, scattered into level 0,
+        and their root paths re-hashed, ONE program a forest
+        (IncrementalMerkleTree.update_bucket). Each dirty set is padded to
+        a bucket (`bucket_indices`), so whatever a block dirties meets the
+        programs the first block compiled. Nothing comes back: the next
+        root request fetches the roots as they then are."""
+        unroll = jax.default_backend() != "cpu"     # sha256._unroll_for's reason
+        if self._reg_forest is not None and len(rows):
+            idx = bucket_indices(rows)
+            c = self.cols
+            self._reg_forest.update_bucket(idx, _leaves_at(
+                self._pk_np[idx], self._wc_np[idx],
+                c.activation_eligibility_epoch, c.activation_epoch,
+                c.exit_epoch, c.withdrawable_epoch, c.slashed,
+                c.effective_balance, idx, unroll=unroll))
+            self._big_roots = None
+        if self._bal_forest is not None and len(chunks):
+            idx = bucket_indices(chunks)
+            self._bal_forest.update_bucket(idx, _balance_chunks_at(
+                self.cols.balance, idx, np.int32(self._v)))
+            self._big_roots = None
 
     def _registry_leaf_words_np(self, np_cols: Dict[str, np.ndarray],
                                 idx: np.ndarray):
@@ -592,6 +747,125 @@ class ResidentCore:
             np_cols["effective_balance"][idx])
         roots = bulk.subtree_roots_batch(leaves)
         return bytes_to_words(np.ascontiguousarray(roots))
+
+    # -- the registry view's writes (a served block's operations) -------------
+
+    def _open_writes(self) -> _BlockWrites:
+        """The open block's record: the view's writes are a block's, made
+        inside `process_block`, which commits them or rolls them back."""
+        if self._writes is None:
+            raise RuntimeError(
+                "a registry write through a resident core's view outside "
+                "process_block: nothing would commit it to the device "
+                "columns and the forests")
+        return self._writes
+
+    def _mirror_set(self, writes: _BlockWrites, field: str, index: int,
+                    value) -> None:
+        mirror = self.mirrors[field]
+        if not mirror.flags.writeable:      # a column as device_get left it
+            mirror = self.mirrors[field] = mirror.copy()
+        writes.undo.append((field, index, mirror[index]))
+        mirror[index] = value
+
+    def _withdrawable_epoch(self, index: int) -> int:
+        """One validator's `withdrawable_epoch`: what the open block wrote,
+        else the device column's row (it has no mirror: only a slashing's
+        `is_slashable_validator` reads it)."""
+        written = self._writes.withdrawable.get(index) \
+            if self._writes is not None else None
+        if written is not None:
+            return written
+        return int(jax.device_get(_rows_at(
+            self.cols.withdrawable_epoch, np.array([index], np.int32)))[0])
+
+    def _exit_queue_head(self, floor_epoch: int) -> tuple:
+        """initiate_validator_exit's two scans of the registry: (the later
+        of `floor_epoch` and the last exit epoch any validator has, how
+        many validators exit in it). The last exit epoch and its count are
+        found by two reductions over the mirror when nothing is known (the
+        first exit after an entry or a boundary, whose ejections may have
+        moved the queue) and kept from exit to exit."""
+        queue = self._exit_queue
+        if queue is None:
+            exit_epoch = self.mirrors["exit_epoch"]
+            known = exit_epoch[exit_epoch
+                               != np.uint64(int(self.spec.FAR_FUTURE_EPOCH))]
+            head = int(known.max()) if known.size else -1
+            queue = self._exit_queue = [
+                head, int(np.count_nonzero(exit_epoch == np.uint64(head)))
+                if known.size else 0]
+        return (floor_epoch, 0) if floor_epoch > queue[0] else tuple(queue)
+
+    def _write_exit(self, index: int, exit_epoch: int,
+                    withdrawable_epoch: int) -> None:
+        writes = self._open_writes()
+        self._mirror_set(writes, "exit_epoch", index, exit_epoch)
+        writes.withdrawable[index] = withdrawable_epoch
+        queue = self._exit_queue
+        if queue is not None:
+            if exit_epoch > queue[0]:
+                queue[:] = [exit_epoch, 1]
+            elif exit_epoch == queue[0]:
+                queue[1] += 1
+        # an active set answered for an epoch the validator has now left
+        for epoch in [e for e in self._active_idx_memo if e >= exit_epoch]:
+            del self._active_idx_memo[epoch]
+
+    def _write_slash(self, index: int, withdrawable_epoch: int) -> None:
+        writes = self._open_writes()
+        self._mirror_set(writes, "slashed", index, True)
+        writes.withdrawable[index] = withdrawable_epoch
+
+    def _move_balance(self, index: int, up: int, down: int) -> None:
+        self._open_writes().balance_moves.append((index, up, down))
+
+    def _roll_back_writes(self, writes: _BlockWrites) -> None:
+        """A rejected block: the mirrors and the exit queue as they were
+        (nothing had reached the device columns or the forests)."""
+        for field, index, value in reversed(writes.undo):
+            self.mirrors[field][index] = value
+        self._exit_queue = writes.exit_queue
+        self._active_idx_memo.clear()
+
+    def _commit_writes(self, writes: _BlockWrites) -> None:
+        """The block's dirty rows into the device columns (the mirrors have
+        them), then the dirty leaves and chunks into both forests."""
+        with telemetry.span("resident.registry_write") as sp:
+            c, new = self.cols, {}
+            leaf_rows = sorted(writes.withdrawable)
+            if leaf_rows:
+                # every row an exit or a slashing touched got a
+                # withdrawable_epoch; its exit_epoch and slashed flag are
+                # the mirrors'. One bucket, one dispatch for the three.
+                idx = bucket_indices(np.asarray(leaf_rows, np.int64))
+                new["exit_epoch"], new["withdrawable_epoch"], new["slashed"] = \
+                    _write_rows(
+                        c.exit_epoch, c.withdrawable_epoch, c.slashed, idx,
+                        self.mirrors["exit_epoch"][idx].astype(c.exit_epoch.dtype),
+                        np.array([writes.withdrawable[int(i)] for i in idx],
+                                 dtype=c.withdrawable_epoch.dtype),
+                        self.mirrors["slashed"][idx].astype(c.slashed.dtype))
+            balance = c.balance
+            for index, up, down in writes.balance_moves:
+                balance = _move_balance(balance, np.int32(index),
+                                        np.uint64(up), np.uint64(down))
+            if writes.balance_moves:
+                new["balance"] = balance
+            if self._mesh is not None:      # where the columns lie
+                new = {f: jax.device_put(a, self._mesh.shard_v)
+                       for f, a in new.items()}
+            self.cols = c._replace(**new)
+            balance_rows = sorted({m[0] for m in writes.balance_moves})
+            sp.note(rows=len(set(leaf_rows) | set(balance_rows)))
+        with telemetry.span("resident.forests.update") as sp:
+            lanes0 = _FOREST_PAIR_LANES.value
+            self._update_forest_paths(
+                np.asarray(leaf_rows, np.int64),
+                np.unique(np.asarray(balance_rows, np.int64) // 4))
+            sp.note(registry_leaves=len(leaf_rows),
+                    balance_chunks=len({r // 4 for r in balance_rows}),
+                    pair_lanes=_FOREST_PAIR_LANES.value - lanes0)
 
     # -- spec-method overrides ----------------------------------------------
 
@@ -684,6 +958,16 @@ class ResidentCore:
         or O(dirty * log V) after a fallback block's leaf-level updates —
         never the all-or-nothing ~2M-leaf re-Merkleization."""
         if self._big_roots is not None:
+            return self._big_roots
+        if self._reg_forest is not None and self._bal_forest is not None:
+            # both stand and have taken a block's dirty paths
+            # (_update_forest_paths): their roots as they now are, in one
+            # transfer; nothing is built, so no `resident.forests` span
+            top = jax.device_get((self._reg_forest.levels[-1],
+                                  self._bal_forest.levels[-1]))
+            self._big_roots = tuple(
+                ssz_impl.mix_in_length(words_to_bytes(t[0]).tobytes(), self._v)
+                for t in top)
             return self._big_roots
         with telemetry.span("resident.forests") as sp:
             lanes0 = _FOREST_PAIR_LANES.value
@@ -858,30 +1142,46 @@ class ResidentCore:
         return state
 
     def _registry_operations(self, block) -> list:
-        """The registry-touching lists of the block's body that are not
-        empty; none when the block is header, randao, eth1 vote and
-        attestations only. A light core serves only such blocks: any other
-        needs the object registry (_fallback_block), which a
-        checkpoint-resumed core deliberately never built, and is refused
-        here by name: no silent fallback through a million objects."""
-        touched = [name for name in _REGISTRY_OPERATIONS
+        """The lists of the block's body that the resident state does not
+        serve and that are not empty: deposits (a new validator changes V,
+        the shape of every device column and forest) and transfers. None
+        when the block is header, randao, eth1 vote, attestations, exits
+        and slashings only, which a light core and an object-entered one
+        serve alike. Any other needs the object registry
+        (_fallback_block), which a checkpoint-resumed core deliberately
+        never built: it is refused here by name, before anything is
+        written, with no silent fallback through a million objects."""
+        touched = [name for name in _UNSERVED_OPERATIONS
                    if len(getattr(block.body, name))]
         if touched and self._light:
             raise NotImplementedError(
-                f"registry_operations are cut from what a checkpoint-resumed "
-                f"(light) resident core serves: the block at slot "
-                f"{int(block.slot)} carries {', '.join(touched)}, which "
-                f"need the object registry — resume via the standard "
-                f"ResidentCore entry")
+                f"of the registry_operations a checkpoint-resumed (light) "
+                f"resident core serves voluntary exits and slashings only: "
+                f"the block at slot {int(block.slot)} carries "
+                f"{', '.join(touched)}, which need the object registry — "
+                f"resume via the standard ResidentCore entry")
         return touched
 
     def process_block(self, state, block) -> None:
         """The spec's `process_block` on the resident state: its four steps
-        in its order, each under its span, the registry read through this
-        core's view (helpers.registry_view). A block with a
-        registry-touching operation takes `_fallback_block` on an
-        object-entered core and is refused, before anything is written,
-        by a light one."""
+        in its order, each under its span, the registry read and written
+        through this core's view (helpers.registry_view). The operations
+        run in the spec's order with the spec's every check: proposer and
+        attester slashings (`resident.block.slashings`), attestations
+        (`.attestations`), voluntary exits (`.exits`). An exit or a
+        slashing writes the host mirrors at once, so that the next
+        operation of the block reads it (a second exit of one validator
+        is refused, the exit queue counts the first); when the last
+        operation has passed, the block's dirty rows go into the device
+        columns (`resident.registry_write`) and the dirty leaves and
+        balance chunks into both forests (`resident.forests.update`), and
+        the next slot's root takes the forests' roots as they then are. A
+        block the spec rejects is rejected with columns, mirrors and
+        forests as they were (the small fields it wrote before the check
+        that failed are the caller's to discard, as the spec discards
+        them). A block with a deposit or a transfer takes
+        `_fallback_block` on an object-entered core and is refused, before
+        anything is written, by a light one."""
         if self._registry_operations(block):
             self._fallback_block(state, block)
             return
@@ -893,8 +1193,31 @@ class ResidentCore:
                 spec.process_randao(state, body)
             with telemetry.span("resident.block.eth1"):
                 spec.process_eth1_data(state, body)
-            with telemetry.span("resident.block.attestations"):
-                spec.process_operations(state, body)
+            writes = self._writes = _BlockWrites(self._exit_queue)
+            try:
+                # process_operations, list by list under the spans
+                spec.check_operations(state, body)
+                with telemetry.span("resident.block.slashings") as sp_part:
+                    spec.process_operation_list(state, body, "proposer_slashings")
+                    spec.process_operation_list(state, body, "attester_slashings")
+                    sp_part.note(slashed=sum(
+                        field == "slashed" for field, _, _ in writes.undo))
+                with telemetry.span("resident.block.attestations"):
+                    spec.process_operation_list(state, body, "attestations")
+                with telemetry.span("resident.block.exits") as sp_part:
+                    # deposits and transfers are empty here
+                    spec.process_operation_list(state, body, "deposits")
+                    spec.process_operation_list(state, body, "voluntary_exits")
+                    spec.process_operation_list(state, body, "transfers")
+                    sp_part.note(exits=len(body.voluntary_exits))
+                spec.process_extra_operations(state, body)
+            except BaseException:
+                self._roll_back_writes(writes)
+                raise
+            finally:
+                self._writes = None
+            if writes.dirty:
+                self._commit_writes(writes)
             # every bitfield has passed verify_bitfield: a set bit is an
             # attesting index
             sp.note(attestations=len(body.attestations),
@@ -1090,7 +1413,12 @@ class ResidentCore:
                 facts = build_epoch_inputs_np(spec, state, ctx)
                 # how hard the epoch program's proposer sum works: its
                 # loop runs over this many table rows
-                sp_distill.note(proposer_rows=int(facts.proposer_rows))
+                sp_distill.note(
+                    proposer_rows=int(facts.proposer_rows),
+                    # the active set the boundary ran on: it moves when
+                    # blocks carry exits and slashings
+                    active_validators=len(spec.get_active_validator_indices(
+                        state, current_epoch)))
                 scal, inp = self._stage_epoch_inputs(state, facts)
             with telemetry.span("resident.stage.upload") as sp_up:
                 sp_up.fence(scal, inp)  # uploads land in "resident.stage"
@@ -1115,6 +1443,7 @@ class ResidentCore:
             self._reg_forest = None
             self._bal_forest = None
             self._active_idx_memo.clear()
+            self._exit_queue = None     # the program's ejections move it
             with telemetry.span("resident.refresh.download"):
                 new_scal, report = jax.device_get((dev_scal, dev_report))
                 # refresh ONLY the columns host logic reads; slashed never

@@ -301,20 +301,21 @@ def merkle_reduce_words(chunks: jnp.ndarray) -> jnp.ndarray:
     return level[0]
 
 
-def subtree_roots_words(leaves: jnp.ndarray) -> jnp.ndarray:
+def subtree_roots_words(leaves: jnp.ndarray, unroll=None) -> jnp.ndarray:
     """[V, P, 8]-word per-element subtrees -> [V, 8] roots, on device.
 
     P must be a power of two; all V subtrees descend one level per
     compression call, each level one (V*P/2)-lane batch. Composable inside
-    jit (the bulk state-root program inlines this)."""
+    jit (the bulk state-root program inlines this). `unroll` as in
+    sha256_blocks; None chooses by the level's lanes."""
     V, P, _ = leaves.shape
     assert P & (P - 1) == 0, "pad element chunk count to a power of two"
     level = leaves
     while level.shape[1] > 1:
         pairs = level.reshape(-1, 16)
         level = sha256_pairs_inner(
-            pairs, unroll=_unroll_for(pairs.shape[0])
-        ).reshape(V, level.shape[1] // 2, 8)
+            pairs, unroll=_unroll_for(pairs.shape[0]) if unroll is None
+            else unroll).reshape(V, level.shape[1] // 2, 8)
     return level[:, 0, :]
 
 

@@ -27,8 +27,10 @@ validator registry is millions, not billions; the one-point oracle
 MACHINE-AUDITED at the ceiling: the value-range contract below
 (`make ranges`) walks all 90 rounds at n = 2**30 - 1 and proves every
 index intermediate — `pivot - pos` in (-(n-1), n-1), the `flip + n`
-renormalization peaking at 2n - 1 = 2**31 - 1, the roll/slice starts —
-stays inside int32, and the permutation contents inside [0, n-1]; any
+renormalization peaking at 2n - 1 = 2**31 - 1, the roll/slice starts (the
+wrapped flip's `pivot + 1 + n` among them) — stays inside int32, and the
+stored contents inside [0, capacity - 1] (the permutation's inside
+[0, n-1]: the traced count's padding holds its own positions); any
 widening of `_MAX_N` past 2**30 (where `flip + n` would genuinely wrap)
 trips CSA1401 before it can ship.
 """
@@ -86,27 +88,51 @@ def host_pivots(seed: bytes, n: int, rounds: int) -> np.ndarray:
     return pivots
 
 
-@partial(jax.jit, static_argnames=("n", "rounds"))
-def _shuffle_rounds(seed_words: jnp.ndarray, pivots: jnp.ndarray, n: int, rounds: int) -> jnp.ndarray:
-    """seed_words: [8] uint32 (big-endian seed), pivots: [R] int32 (< n).
+def shuffle_capacity(n: int) -> int:
+    """The length the shuffle's program runs at for `n` positions: `n`
+    rounded up to a multiple of a sixteenth of its power of two, so a
+    count that moves a little (an active set that loses its churn every
+    epoch) stays at one shape, and at most a sixteenth of the positions
+    are padding."""
+    step = 1 << max(int(n).bit_length() - 5, 0)
+    return -(-int(n) // step) * step
 
-    Returns perm [n] int32 with perm[p] = image of index p under the shuffle.
+
+@partial(jax.jit, static_argnames=("capacity", "rounds"))
+def _shuffle_rounds(seed_words: jnp.ndarray, pivots: jnp.ndarray,
+                    n: jnp.ndarray, capacity: int, rounds: int) -> jnp.ndarray:
+    """seed_words: [8] uint32 (big-endian seed), pivots: [R] int32 (< n),
+    n: int32 scalar, TRACED, 0 < n <= capacity (static): every count of a
+    capacity shares one program.
+
+    Returns [capacity] int32 whose first n entries are perm[p] = image of
+    index p under the shuffle of n; what follows them is padding.
     """
+    N = capacity
     with jax.named_scope("shuffle_round_bits"):
-        bits = _round_bits(seed_words, n, rounds, jnp.bool_)
-    pos = jnp.arange(n, dtype=jnp.int32)
+        bits = _round_bits(seed_words, N, rounds, jnp.bool_)
+    pos = jnp.arange(N, dtype=jnp.int32)
     C0 = pos
+
+    def flipped(X, pivot, low):
+        # X[flip(p)] for all p < n. Over the N stored positions,
+        # X[pivot - p] is roll(reverse(X), pivot + 1) and X[pivot + n - p]
+        # is roll(reverse(X), pivot + 1 + n): the first serves p <= pivot,
+        # the second the positions above the pivot, whose flip wraps at n
+        # and not at N. Both read positions below n only.
+        rev = X[::-1]
+        return jnp.where(low, jnp.roll(rev, pivot + 1),
+                         jnp.roll(rev, pivot + 1 + n))
 
     def body(k, C):
         r = rounds - 1 - k  # reverse round order -> forward permutation
         pivot = pivots[r]
         flip = pivot - pos
-        flip = jnp.where(flip < 0, flip + n, flip)
-        # X[flip(p)] for all p == roll(reverse(X), pivot+1)
-        shift = pivot + 1
-        C_flip = jnp.roll(C[::-1], shift)
+        low = flip >= 0
+        flip = jnp.where(low, flip, flip + n)
+        C_flip = flipped(C, pivot, low)
         bits_r = bits[r]
-        bits_flip = jnp.roll(bits_r[::-1], shift)
+        bits_flip = flipped(bits_r, pivot, low)
         # decision bit lives at max(p, flip(p))
         bit_at_max = jnp.where(pos >= flip, bits_r, bits_flip)
         return jnp.where(bit_at_max, C_flip, C)
@@ -142,23 +168,33 @@ def _shuffle_rounds_stacked(seed_words: jnp.ndarray, pivots: jnp.ndarray,
     return jax.lax.fori_loop(0, rounds, body, pos)
 
 
+def _padded_permutation(seed: bytes, index_count: int, rounds: int) -> jnp.ndarray:
+    """`_shuffle_rounds` for (seed, n): [shuffle_capacity(n)] int32 on the
+    device, the permutation in its first n entries."""
+    n = int(index_count)
+    assert 0 < n < _MAX_N
+    seed_words = jnp.asarray(bytes_to_words(np.frombuffer(seed, dtype=np.uint8)))
+    return _shuffle_rounds(
+        seed_words, jnp.asarray(host_pivots(seed, n, rounds)),
+        np.int32(n), shuffle_capacity(n), rounds)
+
+
 def shuffle_permutation_on_device(seed: bytes, index_count: int, rounds: int) -> jnp.ndarray:
     """perm[i] == get_shuffled_index(i, index_count, seed), as a DEVICE array.
 
     The device-resident entry point for jitted pipelines (committee slicing,
     epoch processing): nothing but the 32-byte seed and 90 pivots crosses the
-    host↔device boundary. Use shuffle_permutation_device for a numpy result.
+    host↔device boundary. The cut to index_count entries is a slice
+    program of its own a count; shuffle_permutation_device, which serves
+    the spec's hook, cuts on the host and compiles nothing a count.
     """
-    n = int(index_count)
-    assert 0 < n < _MAX_N
-    seed_words = jnp.asarray(bytes_to_words(np.frombuffer(seed, dtype=np.uint8)))
-    return _shuffle_rounds(seed_words, jnp.asarray(host_pivots(seed, n, rounds)),
-                           n, rounds)
+    return _padded_permutation(seed, index_count, rounds)[:int(index_count)]
 
 
 def shuffle_permutation_device(seed: bytes, index_count: int, rounds: int) -> np.ndarray:
     """Host-facing wrapper: same permutation, materialized as numpy int64."""
-    return np.asarray(shuffle_permutation_on_device(seed, index_count, rounds), dtype=np.int64)
+    padded = np.asarray(_padded_permutation(seed, index_count, rounds))
+    return padded[:int(index_count)].astype(np.int64)
 
 
 # ---------------------------------------------------------------------------
@@ -175,11 +211,14 @@ def _shuffle_ranges_build():
     import jax as _jax
     n, rounds = _MAX_N - 1, 90
     return dict(
-        fn=lambda s, p: _shuffle_rounds(s, p, n=n, rounds=rounds),
+        fn=lambda s, p, count: _shuffle_rounds(
+            s, p, count, capacity=shuffle_capacity(n), rounds=rounds),
         args=(_jax.ShapeDtypeStruct((8,), jnp.uint32),
-              _jax.ShapeDtypeStruct((rounds,), jnp.int32)),
+              _jax.ShapeDtypeStruct((rounds,), jnp.int32),
+              _jax.ShapeDtypeStruct((), jnp.int32)),
         ranges=({"lo": 0, "hi": (1 << 32) - 1},      # seed words
-                {"lo": 0, "hi": _MAX_N - 2}))        # host pivots < n
+                {"lo": 0, "hi": _MAX_N - 2},         # host pivots < n
+                {"lo": 1, "hi": n}))                 # the traced count
 
 
 RANGE_CONTRACTS = [
@@ -187,7 +226,9 @@ RANGE_CONTRACTS = [
         name="ops.shuffle.swap_or_not_ceiling",
         build=_shuffle_ranges_build,
         wrap_ok=("uint32",),
-        output={"lo": 0, "hi": _MAX_N - 2},          # perm values < n
+        # perm values < n in the first n entries; the padding behind them
+        # holds positions < the capacity, which at this n is _MAX_N
+        output={"lo": 0, "hi": _MAX_N - 1},
     ),
 ]
 

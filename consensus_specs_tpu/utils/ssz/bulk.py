@@ -615,10 +615,11 @@ def _length_chunk_words(n: int) -> np.ndarray:
 
 
 def _registry_leaf_words(pubkeys, wc, act_elig, act, exit_ep, withdrawable,
-                         slashed, eff_balance):
+                         slashed, eff_balance, unroll=None):
     """Traced body: SoA validator columns -> [V, 8] per-validator root words
     (the leaves of the registry list tree — the incremental forest builds
-    its level 0 from exactly these)."""
+    its level 0 from exactly these). `unroll` as in sha256_blocks: None
+    chooses by the lanes, a few dirty rows name their own."""
     import jax
     import jax.numpy as jnp
 
@@ -629,7 +630,8 @@ def _registry_leaf_words(pubkeys, wc, act_elig, act, exit_ep, withdrawable,
         # pubkey: Bytes48 -> two chunks -> one pair-hash
         pk_padded = jnp.concatenate(
             [pubkeys, jnp.zeros((V, 16), dtype=pubkeys.dtype)], axis=1)
-        pk_root = sha256_pairs_inner(_u8_mat_words(pk_padded))    # [V, 8]
+        pk_root = sha256_pairs_inner(_u8_mat_words(pk_padded),
+                                     unroll=unroll)               # [V, 8]
         leaves = jnp.stack([
             pk_root,
             _u8_mat_words(wc),
@@ -640,7 +642,7 @@ def _registry_leaf_words(pubkeys, wc, act_elig, act, exit_ep, withdrawable,
             _u64_col_words(slashed.astype(jnp.uint64)),  # bool: byte0 = 0/1
             _u64_col_words(eff_balance),
         ], axis=1)                                                # [V, 8, 8]
-        return subtree_roots_words(leaves)                        # [V, 8]
+        return subtree_roots_words(leaves, unroll=unroll)         # [V, 8]
 
 
 def _registry_root_words(pubkeys, wc, act_elig, act, exit_ep, withdrawable,

@@ -134,6 +134,78 @@ def _update_paths_traced(levels, rows, idx: np.ndarray):
     return tuple(levels)
 
 
+def _update_bucket_traced(levels, idx, rows, unroll: bool):
+    """`update()` with a TRACED dirty set: the leaf scatter plus every
+    level's path re-hash for the `[k]` leaf indices `idx`, in one program
+    whose shapes are the tree's and k alone, so what the dirty leaves are
+    changes no shape and a serving loop compiles one program a tree and a
+    bucket size k. Every level hashes k lanes, lane j the parent on leaf
+    j's path (lanes that share a parent hash and scatter the same value).
+
+    The levels depend on each other through the dirty nodes only, so the
+    program reads every node's stored sibling first (one gather a level,
+    none waits for another), then walks the levels in a `lax.scan` whose
+    body holds ONE pair hash: a lane's own child is the digest it carried
+    up, its sibling the digest of the lane that carried it if the sibling
+    is dirty too (a k x k match), else the stored one. The digests go
+    into their levels afterwards, one scatter a level. A step is a few
+    small kernels, where a pair hash a level in line would be the depth
+    times the hash to compile or, rolled, 224 loop turns a level to run.
+    Returns the new levels."""
+    depth = len(levels) - 1
+    idx = idx.astype(jnp.int32)
+    leaves = levels[0].at[idx].set(rows)
+    if depth == 0:
+        return (leaves,)
+    # a stored sibling is read only where the sibling is not dirty itself,
+    # so the levels as they came serve (the new leaves change dirty rows)
+    stored = []
+    for d in range(depth):
+        level = levels[d]
+        n_d = level.shape[0]
+        sibling = (idx >> d) ^ 1
+        rows_d = level[jnp.minimum(sibling, n_d - 1)]
+        if n_d % 2:     # an odd tail's sibling is the virtual zerohash
+            rows_d = jnp.where((sibling >= n_d)[:, None], _zero_rows(d, 1),
+                               rows_d)
+        stored.append(rows_d)
+
+    def step(carried, xs):
+        d, stored_d = xs
+        nodes = idx >> d
+        sibling = nodes ^ 1
+        match = sibling[:, None] == nodes[None, :]
+        other = jnp.where(match.any(axis=1)[:, None],
+                          carried[jnp.argmax(match, axis=1)], stored_d)
+        odd = ((nodes & 1) == 1)[:, None]
+        pairs = jnp.concatenate([jnp.where(odd, other, carried),
+                                 jnp.where(odd, carried, other)], axis=1)
+        digests = sha256_pairs_inner(pairs, unroll=unroll)
+        return digests, digests
+
+    with jax.named_scope("forest_paths"):
+        _, digests = jax.lax.scan(
+            step, rows, (jnp.arange(depth, dtype=jnp.int32),
+                         jnp.stack(stored)))
+    return (leaves,) + tuple(
+        levels[d + 1].at[idx >> (d + 1)].set(digests[d]) for d in range(depth))
+
+
+# the levels donated on accelerator backends, like the level scatters
+_update_bucket_pd = platform_donated_jit(
+    _update_bucket_traced, donate_argnums=(0,), static_argnames=("unroll",))
+
+
+def bucket_indices(idx: np.ndarray, floor: int = 32) -> np.ndarray:
+    """`idx` as int32, padded by repeating its last entry to the bucket a
+    serving loop updates a forest at: the next power of two that holds it,
+    `floor` at least, so that the dirty sets of a block (16 exits, a few
+    slashings) all meet one program."""
+    idx = np.asarray(idx, np.int32).reshape(-1)
+    m = max(next_power_of_two(idx.shape[0]), floor)
+    return np.concatenate([idx, np.full(m - idx.shape[0], idx[-1], np.int32)])
+
+
 def _pad_pow2_indices(idx: np.ndarray) -> np.ndarray:
     """Pad an index vector to the next power of two by repeating its last
     entry (bounds jit-cache shapes; duplicates are harmless for gather and
@@ -238,6 +310,27 @@ class IncrementalMerkleTree:
         self.levels[0] = _scatter_rows(self.levels[0], jnp.asarray(idx), rows)
         self.last_pairs_per_level = []
         self._rehash_paths(dirty)
+
+    def update_bucket(self, leaf_idx, rows_words) -> None:
+        """`update` for a serving loop: `leaf_idx` is a `bucket_indices`
+        bucket (in-range, repeats allowed where the rows repeat with
+        them), `rows_words` the `[k, 8]` device leaves at them; the
+        scatter and all the path levels are ONE dispatched program
+        (`_update_bucket_traced`), k lanes a level, nothing comes back to
+        the host. The levels keep their placement."""
+        idx = jnp.asarray(np.asarray(leaf_idx, np.int32).reshape(-1))
+        rows = jnp.asarray(rows_words, jnp.uint32).reshape(-1, 8)
+        assert idx.shape[0] == rows.shape[0], (idx.shape, rows.shape)
+        # the pair hash's TPU form off the CPU (sha256._unroll_for's reason)
+        self.levels = list(self._update_bucket_fn()(
+            tuple(self.levels), idx, rows,
+            unroll=jax.default_backend() != "cpu"))
+        self.last_pairs_per_level = []
+        for d in range(self.depth):
+            self._count(d, int(idx.shape[0]))
+
+    def _update_bucket_fn(self):
+        return _update_bucket_pd
 
     def append(self, rows_words) -> None:
         """Append leaves, growing past the padded power of two when needed:
@@ -378,6 +471,20 @@ class ShardedIncrementalMerkleTree(IncrementalMerkleTree):
                 self._placement.row_sharding(pairs.shape[0]))
             self._count(d, pairs.shape[0])
             self.levels.append(level)
+
+    def _update_bucket_fn(self):
+        """The bucket update with every level pinned to the placement it
+        has (a level sharded by row stays so, the cap stays replicated)."""
+        key = tuple(int(l.shape[0]) for l in self.levels)
+        fn = getattr(self, "_bucket_fn", None)
+        if fn is None or fn[0] != key:
+            pdj = platform_donated_jit
+            fn = (key, pdj(
+                _update_bucket_traced, donate_argnums=(0,),
+                static_argnames=("unroll",),
+                out_shardings=tuple(l.sharding for l in self.levels)))
+            self._bucket_fn = fn
+        return fn[1]
 
     # update() is inherited verbatim: with pow2-materialized levels the
     # odd-tail/virtual-row branches of _rehash_paths never trigger, the

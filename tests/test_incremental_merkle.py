@@ -278,3 +278,55 @@ def test_forest_invalidation_evicts_memo_entries():
     # ... and the new content is served fresh, not from a stale entry
     chunks[11] = row
     assert handle.root() == bulk.merkleize_chunk_array(chunks) != r0
+
+
+# ---------------------------------------------------------------------------
+# The serving loop's bucket update: one program a tree, a traced dirty set
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n", [1, 2, 5, 64, 97, 1000, 4097])
+def test_bucket_update_equals_a_rebuild_level_for_level(n):
+    """`update_bucket` (the leaf scatter and every path level in ONE
+    program, the dirty set padded to a bucket of 32) leaves every stored
+    level as a from-scratch build of the new leaves leaves it: odd tails,
+    dirty siblings, repeated indices, a bucket larger than the tree."""
+    from consensus_specs_tpu.utils.ssz.incremental import bucket_indices
+    rng = np.random.default_rng(n)
+    leaves = rng.integers(0, 2 ** 32, (n, 8), dtype=np.uint32)
+    tree = IncrementalMerkleTree(leaves.copy())
+    for k in (1, 3, 16, 21, 40):
+        idx = np.sort(rng.choice(n, min(k, n), replace=False))
+        if n > 2:
+            idx[-1] = n - 1                 # the tail, odd or even
+        idx = np.unique(idx)
+        leaves[idx] = rng.integers(0, 2 ** 32, (len(idx), 8), dtype=np.uint32)
+        bucket = bucket_indices(idx)
+        assert len(bucket) == max(32, 1 << (len(idx) - 1).bit_length())
+        assert (bucket[:len(idx)] == idx).all() and (bucket[len(idx):] == idx[-1]).all()
+        tree.update_bucket(bucket, leaves[bucket])
+        assert tree.last_pairs_per_level == [len(bucket)] * tree.depth
+        rebuilt = IncrementalMerkleTree(leaves.copy())
+        assert tree.root() == rebuilt.root(), (n, k)
+        for got, want in zip(tree.levels, rebuilt.levels):
+            assert (np.asarray(got) == np.asarray(want)).all(), (n, k)
+    assert tree.builds == 1
+
+
+def test_bucket_updates_of_one_tree_share_one_program():
+    """What the dirty leaves are changes no shape: every block's dirty set
+    (16 exits, 17 with a slashing, 21 with a double vote) meets the
+    program the first one compiled."""
+    from consensus_specs_tpu.utils.ssz.incremental import (_update_bucket_pd,
+                                                          bucket_indices)
+    rng = np.random.default_rng(36)
+    leaves = rng.integers(0, 2 ** 32, (3000, 8), dtype=np.uint32)
+    tree = IncrementalMerkleTree(leaves.copy())
+    tree.update_bucket(bucket_indices(np.array([7])), leaves[[7] * 32])
+    programs = _update_bucket_pd.resolve()._cache_size()
+    for k in (16, 17, 21, 1, 32):
+        idx = np.sort(rng.choice(3000, k, replace=False))
+        leaves[idx] = rng.integers(0, 2 ** 32, (k, 8), dtype=np.uint32)
+        bucket = bucket_indices(idx)
+        tree.update_bucket(bucket, leaves[bucket])
+        assert tree.root() == IncrementalMerkleTree(leaves.copy()).root()
+    assert _update_bucket_pd.resolve()._cache_size() == programs

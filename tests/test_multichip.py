@@ -168,6 +168,21 @@ def test_sharded_forest_matches_single(serving_mesh):
     assert sum(shard.last_pairs_per_level) <= 2 * 4 * shard.depth
     assert shard.levels[0].sharding.is_equivalent_to(mesh.shard_v, 2)
 
+    # the serving loop's bucket update: one program, every level stays on
+    # the placement it had, the roots the single-device tree's
+    from consensus_specs_tpu.utils.ssz.incremental import bucket_indices
+    placed = [level.sharding for level in shard.levels]
+    for dirty in ([99], [0, 1, 64, 65, 98], list(range(20, 41))):
+        bucket = bucket_indices(np.array(dirty))
+        rows = rng.integers(0, 2 ** 32, (len(bucket), 8), dtype=np.uint32)
+        rows[len(dirty):] = rows[len(dirty) - 1]    # repeats repeat their rows
+        single.update_bucket(bucket, rows.copy())
+        shard.update_bucket(bucket, rows)
+        assert shard.root() == single.root()
+        assert shard.last_pairs_per_level == [len(bucket)] * shard.depth
+        for level, was in zip(shard.levels, placed):
+            assert level.sharding.is_equivalent_to(was, 2)
+
     # append-grow: 100 -> 140 crosses the 128 pow2 (and, at 8 devices,
     # the per-shard row boundary); the new capacity 256 rounds to a mesh
     # multiple by construction

@@ -78,7 +78,10 @@ def test_resident_multi_epoch_bit_equality(spec):
     assert serialize(ref, spec.BeaconState) == serialize(res, spec.BeaconState)
 
 
-def test_resident_fallback_on_registry_mutating_block(spec):
+def test_resident_serves_a_registry_mutating_block(spec):
+    """A proposer slashing mid-drive on an object-entered core: served on the
+    resident state since PR 36 (no fallback), byte-identical to the object
+    model through the boundary that follows."""
     state = factories.seed_genesis_state(spec, 4 * spec.SLOTS_PER_EPOCH)
     factories.advance_slots(spec, state, 2)
     ref, res = deepcopy(state), deepcopy(state)
@@ -99,8 +102,8 @@ def test_resident_fallback_on_registry_mutating_block(spec):
 
 def test_fallback_is_incremental_and_grows_forest(spec):
     """A registry-mutating block must NOT throw the registry-scale trees
-    away: the same incremental forests survive the fallback with
-    O(dirty·log V) pair-hash lanes, and a deposit block append-grows them
+    away: a slashing is served on the same incremental forests (one bucket
+    of path lanes), and a deposit block, which falls back, append-grows them
     across the padded power-of-two boundary — roots bit-equal to the
     object model throughout."""
     from consensus_specs_tpu.utils.merkle import tree_depth
@@ -126,9 +129,10 @@ def test_fallback_is_incremental_and_grows_forest(spec):
         core.state_transition(res, block)
         assert core._reg_forest is f_reg and core._bal_forest is f_bal
         assert f_reg.builds == 1                 # updated in place, no rebuild
-        # the slashing touches one validator's registry leaf (plus pow2
-        # index padding); nowhere near the V-leaf rebuild
-        assert 0 < sum(f_reg.last_pairs_per_level) <= 2 * 2 * f_reg.depth
+        # the slashing is served on the resident state: its one dirty
+        # registry leaf takes the per-slot path program, one bucket of 32
+        # lanes a level whatever a block dirties (nothing is rebuilt)
+        assert f_reg.last_pairs_per_level == [32] * f_reg.depth
         assert hash_tree_root(ref) == core._state_root(res)
 
         # -- deposit: grows V -> V+1 across the padded power of two ----------
@@ -220,21 +224,31 @@ def test_overrides_delegate_for_foreign_state(spec):
         core.exit()
 
 
-def test_light_core_refuses_a_registry_touching_block_before_any_write(spec):
+def test_light_core_refuses_a_deposit_before_any_write_and_serves_an_exit(spec):
     """A checkpoint-resumed (light) core takes blocks whose operations are
-    attestations only (tests/test_resident_blocks.py); one that carries a
-    registry-touching operation needs the object registry the light entry
-    deliberately never built, and must fail loudly BEFORE process_slots
-    mutates state, naming the cut."""
+    attestations, exits and slashings (tests/test_resident_blocks.py,
+    tests/test_resident_operations.py); one that carries a deposit needs
+    the object registry the light entry deliberately never built, and must
+    fail loudly BEFORE process_slots mutates state, naming the cut. An
+    exit is served: at genesis the spec's own check rejects it
+    (PERSISTENT_COMMITTEE_PERIOD), after process_slots has run."""
     state = factories.seed_genesis_state(spec, 2 * spec.SLOTS_PER_EPOCH)
     data = serialize(state, spec.BeaconState)
     core = ResidentCore.from_checkpoint(spec, data)
     try:
         block = spec.BeaconBlock(slot=int(state.slot) + 1)
-        block.body.voluntary_exits.append(spec.VoluntaryExit())
+        block.body.deposits.append(spec.Deposit())
         with pytest.raises(NotImplementedError, match="registry_operations"):
             core.state_transition(core.state, block)
         assert core.checkpoint_bytes() == data      # nothing mutated
+        with core.suspended():
+            block = factories.empty_block_next(spec, state)
+        block.body.voluntary_exits.append(spec.VoluntaryExit())
+        with pytest.raises(AssertionError):
+            core.state_transition(core.state, block)
+        assert int(core.state.slot) == int(state.slot) + 1
+        assert (core.mirrors["exit_epoch"]
+                == int(spec.FAR_FUTURE_EPOCH)).all()
     finally:
         core._uninstall()
 
@@ -311,8 +325,9 @@ def test_resident_sharded_serving_loop(spec, serving_mesh):
 
 
 def test_resident_sharded_fallback_and_deposit_growth(spec, serving_mesh):
-    """Under sharding, a registry-mutating block re-enters INCREMENTALLY
-    (same forests, scatter-only updates, no rebuild) and a deposit
+    """Under sharding, a slashing is served where the columns lie (same
+    forests, the dirty rows and paths alone, every column and level on the
+    placement it had), a deposit block re-enters INCREMENTALLY and
     append-grows the padded columns and forests across a shard boundary
     (V 32 -> 33: columns 32 -> 40 rows, forest capacity 32 -> 64), all
     bit-equal to the object model."""
@@ -329,6 +344,7 @@ def test_resident_sharded_fallback_and_deposit_growth(spec, serving_mesh):
         V = len(ref.validator_registry)
         assert V % mesh.size == 0, "seed V must already tile the mesh"
         assert f_reg.n == V and f_reg.builds == 1
+        reg_placed = [level.sharding for level in f_reg.levels]
 
         # -- slashing: incremental re-entry, forests survive -----------------
         with core.suspended():
@@ -340,9 +356,14 @@ def test_resident_sharded_fallback_and_deposit_growth(spec, serving_mesh):
         core.state_transition(res, block)
         assert core._reg_forest is f_reg and core._bal_forest is f_bal
         assert f_reg.builds == 1
-        assert 0 < sum(f_reg.last_pairs_per_level) <= 2 * 2 * f_reg.depth
+        # served on the sharded columns: one bucket of 32 lanes a level
+        assert f_reg.last_pairs_per_level == [32] * f_reg.depth
         assert hash_tree_root(ref) == core._state_root(res)
-        assert core.cols.balance.sharding.is_equivalent_to(mesh.shard_v, 1)
+        for column in (core.cols.balance, core.cols.slashed,
+                       core.cols.exit_epoch, core.cols.withdrawable_epoch):
+            assert column.sharding.is_equivalent_to(mesh.shard_v, 1)
+        for level, was in zip(f_reg.levels, reg_placed):
+            assert level.sharding.is_equivalent_to(was, level.ndim)
 
         # -- deposit: V -> V+1 crosses padding AND capacity ------------------
         with core.suspended():

@@ -4,9 +4,10 @@ which holds no Validator objects, takes a chain of attestation-full blocks.
 The differential chain against the unpatched object model is
 tests/test_resident_block_chain.py (a file of its own, so that the workers
 share the two). Here: `state_transition` is `process_slots` then
-`process_block`, a block the spec rejects is rejected at the spec's place, a
-registry-touching block is refused before anything is written, the registry
-view answers an object state as its list does, the array form of the
+`process_block`, a block the spec rejects is rejected at the spec's place
+(an unsound exit or slashing with columns, mirrors and forests as they
+stood), a block with a deposit or a transfer is refused before anything is
+written, the registry view answers an object state as its list does, the array form of the
 attestation family equals the spec's bit-by-bit words, and a block leaves
 its span tree.
 """
@@ -218,14 +219,11 @@ def _advanced(spec, state):
     return fresh
 
 
-# -- what the light core refuses -------------------------------------------------
+# -- what the light core refuses, and what it serves ------------------------------
 
 @pytest.mark.parametrize("name,operation", [
-    ("proposer_slashings", "ProposerSlashing"),
-    ("attester_slashings", "AttesterSlashing"),
-    ("deposits", "Deposit"), ("voluntary_exits", "VoluntaryExit"),
-    ("transfers", "Transfer")])
-def test_a_registry_touching_block_is_refused_before_anything_is_written(
+    ("deposits", "Deposit"), ("transfers", "Transfer")])
+def test_a_block_with_a_deposit_or_a_transfer_is_refused_before_anything_is_written(
         minimal, name, operation):
     spec, _, data = minimal
     core = ResidentCore.from_checkpoint(spec, data, mesh=None)
@@ -239,6 +237,51 @@ def test_a_registry_touching_block_is_refused_before_anything_is_written(
         with pytest.raises(NotImplementedError, match="registry_operations"):
             core.process_block(core.state, block)
         assert core.checkpoint_bytes() == data
+    finally:
+        core._uninstall()
+
+
+def _served_state(core) -> tuple:
+    """What a rejected block must leave as it was: the device columns, the
+    host mirrors, the kept exit queue and both forests' roots."""
+    cols = core._materialize_np_cols()
+    return ({f: a.copy() for f, a in cols.items()},
+            {f: a.copy() for f, a in core.mirrors.items()},
+            None if core._exit_queue is None else list(core._exit_queue),
+            tuple(bytes(r) for r in core._registry_balances_roots()))
+
+
+def _same_served_state(was: tuple, now: tuple) -> bool:
+    return (all((was[0][f] == now[0][f]).all() for f in was[0])
+            and all((was[1][f] == now[1][f]).all() for f in was[1])
+            and was[2:] == now[2:])
+
+
+@pytest.mark.parametrize("name,operation", [
+    ("proposer_slashings", "ProposerSlashing"),
+    ("attester_slashings", "AttesterSlashing"),
+    ("voluntary_exits", "VoluntaryExit")])
+def test_an_unsound_operation_is_rejected_by_the_spec_with_nothing_written(
+        minimal, name, operation):
+    """The three kinds a light core serves: the sound block of the slot
+    with one default-built operation is rejected where the object model
+    rejects it (equal headers; no double vote; an exit before
+    PERSISTENT_COMMITTEE_PERIOD), not refused by name, and columns,
+    mirrors, exit queue and forests stand as they stood."""
+    spec, state, data = minimal
+    core = ResidentCore.from_checkpoint(spec, data, mesh=None)
+    res = core.state
+    try:
+        core.process_slots(res, int(res.slot) + 1)
+        block = BlockGenerator(spec, SEED, 8).block(res)
+        getattr(block.body, name).append(getattr(spec, operation)())
+        with core.suspended():
+            want = _where_it_raises(
+                lambda: spec.process_block(_advanced(spec, state), block))
+        assert want is not None and want[0] == "AssertionError"
+        was = _served_state(core)
+        assert _where_it_raises(lambda: core.process_block(res, block)) == want
+        assert _same_served_state(was, _served_state(core))
     finally:
         core._uninstall()
 
@@ -389,7 +432,8 @@ def test_eighths_partition_the_committee(size, parts):
 
 # -- spans and the fallback counter -----------------------------------------------
 
-BLOCK_PARTS = ("header", "randao", "eth1", "attestations")
+# a block that dirties nothing has no registry_write / forests.update child
+BLOCK_PARTS = ("header", "randao", "eth1", "slashings", "attestations", "exits")
 
 
 def test_a_block_leaves_one_span_tree_and_counts_no_fallback(minimal):
@@ -421,8 +465,15 @@ def test_a_block_leaves_one_span_tree_and_counts_no_fallback(minimal):
         telemetry.set_enabled(None)
 
 
-def test_the_fallback_of_an_object_entered_core_is_counted(minimal):
+def test_a_slashing_on_an_object_entered_core_is_served_and_a_deposit_falls_back(
+        minimal):
+    """One path for an operation's write: a proposer slashing takes the
+    served path on an object-entered core too (no fallback counted, the
+    block's span tree gains the registry write and the forests' update);
+    a deposit still leaves for the object model and is counted."""
     spec, state, _ = minimal
+    telemetry.set_enabled(True)
+    telemetry.reset()
     fallbacks = telemetry.counter("resident.block.fallbacks", always=True)
     ref = deepcopy(state)
     core = ResidentCore(spec, state, mesh=None)
@@ -434,7 +485,31 @@ def test_the_fallback_of_an_object_entered_core_is_counted(minimal):
                 factories.double_proposal(spec, ref))
             spec.state_transition(ref, block)
         core.state_transition(state, block)
+        assert fallbacks.value == before
+        assert hash_tree_root(ref) == core._state_root(state)
+        records = telemetry.ring()
+        root, = [r for r in records if r["name"] == "resident.block"]
+        children = [r for r in records if r["parent_id"] == root["id"]]
+        assert [c["name"] for c in children] \
+            == [f"resident.block.{part}" for part in BLOCK_PARTS] \
+            + ["resident.registry_write", "resident.forests.update"]
+        notes = {c["name"]: c["args"] for c in children if c["args"]}
+        assert notes["resident.block.slashings"] == {"slashed": 1}
+        assert notes["resident.block.exits"] == {"exits": 0}
+        # the slashed validator and the proposer it pays
+        assert notes["resident.registry_write"] == {"rows": 2}
+        assert notes["resident.forests.update"]["registry_leaves"] == 1
+        assert 1 <= notes["resident.forests.update"]["balance_chunks"] <= 2
+        with core.suspended():
+            deposit = factories.stage_deposit(
+                spec, ref, len(ref.validator_registry), spec.MAX_EFFECTIVE_BALANCE)
+            state.latest_eth1_data = deepcopy(ref.latest_eth1_data)
+            block = factories.empty_block_next(spec, ref)
+            block.body.deposits.append(deposit)
+            spec.state_transition(ref, block)
+        core.state_transition(state, block)
         assert fallbacks.value == before + 1
         assert hash_tree_root(ref) == core._state_root(state)
     finally:
         core.exit()
+        telemetry.set_enabled(None)

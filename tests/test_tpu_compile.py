@@ -121,12 +121,42 @@ def test_forest_dirty_update_level_compiles(one_chip):
         "the level scatter no longer updates the resident level in place"
 
 
+@pytest.mark.parametrize("leaves", [V, V // 4], ids=["registry", "balances"])
+def test_forest_bucket_update_compiles_and_updates_in_place(one_chip, leaves):
+    """The serving loop's per-slot forest update at the 1M shapes (the
+    registry forest's 20 levels, the balances forest's 18): the scatter of
+    a bucket of 32 dirty leaves and every level of their paths in one
+    program, the unrolled pair hash inside its scan, every level donated."""
+    from consensus_specs_tpu.utils.ssz.incremental import _update_bucket_traced
+    rows, shapes = leaves, []
+    while True:
+        shapes.append(jax.ShapeDtypeStruct((rows, 8), jnp.uint32,
+                                           sharding=one_chip))
+        if rows == 1:
+            break
+        rows = -(-rows // 2)
+    compiled = jax.jit(
+        _update_bucket_traced, static_argnames=("unroll",),
+        donate_argnums=(0,)).lower(
+        tuple(shapes),
+        jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((32, 8), jnp.uint32, sharding=one_chip),
+        unroll=True).compile()
+    ma = compiled.memory_analysis()
+    stored = sum(s.shape[0] for s in shapes) * 32
+    assert ma.alias_size_in_bytes >= stored, \
+        "the bucket update no longer rewrites the resident levels in place"
+    assert ma.temp_size_in_bytes < HBM_BYTES // 16
+
+
 def test_shuffle_program_compiles(one_chip):
     """shuffle_permutation_on_device's program: the whole registry, the
     mainnet round count."""
-    from consensus_specs_tpu.ops.shuffle import _shuffle_rounds
+    from consensus_specs_tpu.ops.shuffle import (_shuffle_rounds,
+                                                 shuffle_capacity)
     _compile(_shuffle_rounds, one_chip,
-             ((8,), jnp.uint32), ((90,), jnp.int32), n=V, rounds=90)
+             ((8,), jnp.uint32), ((90,), jnp.int32), ((), jnp.int32),
+             capacity=shuffle_capacity(V), rounds=90)
 
 
 def test_proposer_sum_holds_no_wide_buffer(one_chip):
