@@ -19,8 +19,9 @@ from typing import Any, List, Optional, Sequence
 import numpy as np
 
 from ...utils import merkle
+from ...utils.ssz import bulk
 from ...utils.ssz.impl import hash_tree_root as ssz_hash_tree_root
-from ...utils.ssz.impl import signing_root as ssz_signing_root
+from ...utils.ssz.typing import Container
 
 
 # ---------------------------------------------------------------------------
@@ -62,8 +63,6 @@ def install_bulk_state_root(min_validators: int = 0) -> None:
     and differential-check against the recursive path. Below min_validators
     the recursive oracle (with its hash cache) is kept.
     """
-    from ...utils.ssz import bulk
-
     def backend(state):
         if len(state.validator_registry) < min_validators:
             return None
@@ -73,16 +72,32 @@ def install_bulk_state_root(min_validators: int = 0) -> None:
 
 
 def hash_tree_root(spec, obj: Any, typ: Any = None) -> bytes:
-    if (_state_root_backend is not None and typ is None
-            and obj.__class__ is getattr(spec, "BeaconState", None)):
+    """The spec's `hash_tree_root`, keyed by the value's type alone.
+
+    A container value goes to bulk.hash_tree_root_bulk: its type's root
+    plan where it has one (root_plan.py: a Crosslink, an Attestation, a
+    header), the field walk where a field is a list or a vector (a
+    BeaconBlockBody, whose attestation list then reaches the plans as one
+    batch; a HistoricalBatch). A BeaconState keeps the path it had: the
+    installed backend, else the recursive oracle. A basic value, and
+    anything asked with an explicit `typ`, goes to the oracle as before.
+    impl.hash_tree_root is what the tests compare with
+    (tests/test_root_plans.py)."""
+    if typ is not None or not isinstance(obj, Container):
+        return ssz_hash_tree_root(obj, typ)
+    if obj.__class__ is not getattr(spec, "BeaconState", None):
+        return bulk.hash_tree_root_bulk(obj, obj.__class__)
+    if _state_root_backend is not None:
         root = _state_root_backend(obj)
         if root is not None:
             return root
-    return ssz_hash_tree_root(obj, typ)
+    return ssz_hash_tree_root(obj)
 
 
 def signing_root(spec, obj: Any) -> bytes:
-    return ssz_signing_root(obj)
+    """The root of a container without its last field, the fields' roots
+    taken as `hash_tree_root` above takes a container's."""
+    return bulk.signing_root_bulk(obj)
 
 
 def int_to_bytes(spec, integer: int, length: int) -> bytes:

@@ -1187,8 +1187,14 @@ class ResidentCore:
             return
         spec, body = self.spec, block.body
         with telemetry.span("resident.block", req=int(block.slot)) as sp:
-            with telemetry.span("resident.block.header"):
+            with telemetry.span("resident.block.header") as sp_part:
+                # the body's root: its attestations through their root plan
+                elements = bulk.PLAN_ELEMENTS.value
+                pairs = bulk.HOST_PAIRS_HASHED.value
                 spec.process_block_header(state, block)
+                sp_part.note(
+                    plan_elements=bulk.PLAN_ELEMENTS.value - elements,
+                    pairs_hashed=bulk.HOST_PAIRS_HASHED.value - pairs)
             with telemetry.span("resident.block.randao"):
                 spec.process_randao(state, body)
             with telemetry.span("resident.block.eth1"):
@@ -1202,8 +1208,12 @@ class ResidentCore:
                     spec.process_operation_list(state, body, "attester_slashings")
                     sp_part.note(slashed=sum(
                         field == "slashed" for field, _, _ in writes.undo))
-                with telemetry.span("resident.block.attestations"):
+                with telemetry.span("resident.block.attestations") as sp_part:
+                    # one parent crosslink's root an attestation
+                    elements = bulk.PLAN_ELEMENTS.value
                     spec.process_operation_list(state, body, "attestations")
+                    sp_part.note(
+                        plan_elements=bulk.PLAN_ELEMENTS.value - elements)
                 with telemetry.span("resident.block.exits") as sp_part:
                     # deposits and transfers are empty here
                     spec.process_operation_list(state, body, "deposits")

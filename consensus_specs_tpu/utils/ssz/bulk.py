@@ -434,11 +434,11 @@ def container_list_roots(objs: Sequence[Any], elem_type: Any) -> np.ndarray:
 # Generic bulk dispatcher
 # ---------------------------------------------------------------------------
 
-def plan_roots(plan: Plan, values: Sequence[Any]) -> bytes:
+def _plan_rows(plan: Plan, values: Sequence[Any]) -> list:
     """The roots of k containers of one type through that type's root plan
-    (root_plan.plan_for), 32 k bytes: the batch form of a container's
-    root. Every pair is hashed by hashlib from the values as they are now;
-    the pairs and the elements are counted once a call."""
+    (root_plan.plan_for), one by one. Every pair is hashed by hashlib from
+    the values as they are now; the pairs and the elements are counted
+    once a call."""
     rows, hashed = [], 0
     for v in values:
         root, pairs = plan(v)
@@ -446,7 +446,12 @@ def plan_roots(plan: Plan, values: Sequence[Any]) -> bytes:
         hashed += pairs
     HOST_PAIRS_HASHED.inc(hashed)
     PLAN_ELEMENTS.inc(len(rows))
-    return b"".join(rows)
+    return rows
+
+
+def plan_roots(plan: Plan, values: Sequence[Any]) -> bytes:
+    """The batch form of a container's root: `_plan_rows` as 32 k bytes."""
+    return b"".join(_plan_rows(plan, values))
 
 
 def hash_tree_root_bulk(obj: Any, typ: Any = None) -> bytes:
@@ -460,10 +465,21 @@ def hash_tree_root_bulk(obj: Any, typ: Any = None) -> bytes:
     BeaconBlockHeader, Validator and the like) is rooted by the plan. One
     with a list or vector field (BeaconState, BeaconBlockBody,
     HistoricalBatch, IndexedAttestation) is walked field by field here, so
-    that its wide fields reach the column paths: a list or vector of
-    `container_list_is_fast` elements goes through numpy columns
-    (container_list_roots), any other composite element comes back here
-    one by one."""
+    that its wide fields reach the column paths. A list or vector follows
+    its element type, never its length: `container_list_is_fast` elements
+    (Validator, VoluntaryExit, a vector's Crosslinks) go through numpy
+    columns (container_list_roots), so that a registry never costs a
+    Python call an element; elements that are not but have a plan
+    (Attestation, PendingAttestation, ProposerSlashing) through the plan
+    as one batch and a tree hashed pair by pair, nothing kept from one
+    call to the next; any other composite element (AttesterSlashing,
+    Deposit) comes back here one by one.
+
+    Entered by the resident core's state root (ResidentCore._field_root)
+    and by `spec.hash_tree_root` / `spec.signing_root` for every container
+    value but a BeaconState (helpers.hash_tree_root): impl.hash_tree_root
+    is the oracle the tests compare with and this function's fall-back,
+    not a served path."""
     if typ is None:
         return impl.hash_tree_root(obj)
 
@@ -484,6 +500,11 @@ def hash_tree_root_bulk(obj: Any, typ: Any = None) -> bytes:
             leaves = container_list_roots(list(obj), elem)
         elif is_bytesn_type(elem):
             leaves = bytesn_column_leaves([bytes(x) for x in obj], elem.length)
+        elif plan_for(elem) is not None:
+            # one batch, and its tree pair by pair: no content memo stands
+            # between a block's attestations and their list's root
+            root = merkleize_few(_plan_rows(plan_for(elem), obj))
+            return impl.mix_in_length(root, n) if is_list_kind(typ) else root
         else:
             leaves = np.stack([
                 np.frombuffer(hash_tree_root_bulk(v, elem), np.uint8)
@@ -502,6 +523,14 @@ def hash_tree_root_bulk(obj: Any, typ: Any = None) -> bytes:
             [np.frombuffer(r, np.uint8) for r in roots]))
 
     return impl.hash_tree_root(obj, typ)
+
+
+def signing_root_bulk(obj: Any) -> bytes:
+    """Same value as impl.signing_root: the root of container `obj` over
+    every field but its last (the signature), each field's root taken as
+    hash_tree_root_bulk takes it."""
+    return merkleize_few([hash_tree_root_bulk(v, t)
+                          for v, t in obj.get_typed_values()[:-1]])
 
 
 def state_root_bulk(state: Any) -> bytes:

@@ -14,7 +14,15 @@ A type gets a plan when every field is a uint, a bool, a `BytesN`, `bytes`
 length mix are `_bytes_root`), or a container that has a plan itself.
 A field that is a list or a vector (BeaconState, BeaconBlockBody,
 HistoricalBatch, IndexedAttestation) leaves the type without one, and its
-callers on the path they had.
+callers on bulk.hash_tree_root_bulk's field walk.
+
+Who enters a plan, always through bulk.plan_roots: hash_tree_root_bulk's
+container branch (one value: a state's small fields, and since PR 37
+every container the spec roots, helpers.hash_tree_root: the parent
+crosslink of each attestation of a block), its list branch (a list or
+vector of planned elements that are not column-fast, as one batch: the
+attestations of a block's body under process_block_header), and
+host_tree._leaf_rows (the leaves a persistent tree hashes anew).
 
 A plan is `plan(value) -> (root, pairs)`: the 32-byte root and the SHA-256
 pair hashes of the tree that produced it (a `bytes` field's length mix is
@@ -23,7 +31,8 @@ counted it). Nothing is kept between calls. The counters are the caller's
 (bulk.plan_roots).
 
 Differential gate: tests/test_root_plans.py (every phase-0 container of
-both presets against impl.hash_tree_root).
+both presets against impl.hash_tree_root; the spec's entry on every
+phase-0 and phase-1 container and on the bodies a block brings).
 """
 from __future__ import annotations
 

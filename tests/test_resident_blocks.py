@@ -465,6 +465,54 @@ def test_a_block_leaves_one_span_tree_and_counts_no_fallback(minimal):
         telemetry.set_enabled(None)
 
 
+def test_a_blocks_container_roots_go_through_the_plans_and_are_hashed_every_block(
+        odd_committees):
+    """The header step roots the body with its attestations through their
+    plan, the attestation step one parent crosslink an attestation: both
+    noted on their spans, hashed anew for each block, the root written
+    into the header the oracle's; and the check they feed still refuses."""
+    spec, state, data = odd_committees
+    telemetry.set_enabled(True)
+    telemetry.reset()
+    core = ResidentCore.from_checkpoint(spec, data, mesh=None)
+    res = core.state
+    generator = BlockGenerator(spec, SEED, 8)
+    try:
+        blocks = []
+        for _ in range(2):
+            core.process_slots(res, int(res.slot) + 1)
+            blocks.append(generator.block(res))
+            core.process_block(res, blocks[-1])
+            assert bytes(res.latest_block_header.body_root) \
+                == hash_tree_root(blocks[-1].body)
+        assert hash_tree_root(blocks[0].body) != hash_tree_root(blocks[1].body)
+        records = telemetry.ring()
+        headers = [r["args"] for r in records
+                   if r["name"] == "resident.block.header"]
+        families = [r["args"] for r in records
+                    if r["name"] == "resident.block.attestations"]
+        assert len(headers) == len(families) == 2
+        for block, header, family in zip(blocks, headers, families):
+            n = len(block.body.attestations)
+            assert n >= 2
+            # the attestations and the body's eth1 data; 18 pairs or more an
+            # attestation and its list's tree above them: no answer from the
+            # block before
+            assert header["plan_elements"] == n + 1
+            assert header["pairs_hashed"] >= 19 * n - 1
+            assert family == {"plan_elements": n}
+        assert headers[0]["pairs_hashed"] == headers[1]["pairs_hashed"]
+        # a third block whose first attestation names another parent root
+        core.process_slots(res, int(res.slot) + 1)
+        spoiled = generator.block(res)
+        _wrong_crosslink_parent_root(spec, res, spoiled)
+        assert _where_it_raises(lambda: core.process_block(res, spoiled))[:2] \
+            == ("AssertionError", "process_attestation")
+    finally:
+        core._uninstall()
+        telemetry.set_enabled(None)
+
+
 def test_a_slashing_on_an_object_entered_core_is_served_and_a_deposit_falls_back(
         minimal):
     """One path for an operation's write: a proposer slashing takes the
