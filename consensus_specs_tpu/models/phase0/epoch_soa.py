@@ -37,6 +37,7 @@ import numpy as np
 from ... import telemetry
 from ...ops import intmath  # enables jax_enable_x64 on import
 from ...utils.donation import platform_donated_jit
+from .helpers import PERMUTATIONS_COMPUTED
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -851,26 +852,40 @@ def _decode_participants(spec, layouts: dict, atts) -> list:
 
 
 def build_epoch_context(spec, state, np_cols: dict = None) -> EpochContext:
-    np_cols = np_cols if np_cols is not None else columns_np_from_state(state)
-    current_epoch = spec.get_current_epoch(state)
-    previous_epoch = spec.get_previous_epoch(state)
-    prev_atts = list(spec.get_matching_source_attestations(state, previous_epoch))
-    curr_atts = list(spec.get_matching_source_attestations(state, current_epoch))
-    layouts = {}
-    for e in {previous_epoch, current_epoch}.union(
-            int(a.data.target_epoch) for a in prev_atts + curr_atts):
-        layouts[e] = _epoch_layout(spec, state, np_cols, e)
-    ctx = EpochContext(
-        # column length, not len(validator_registry): identical for object
-        # states, and checkpoint-resumed resident states keep the registry
-        # as columns without materializing objects (resident.py)
-        n=len(np_cols["slashed"]), np_cols=np_cols, layouts=layouts,
-        prev_atts=prev_atts, curr_atts=curr_atts,
-        prev_parts=_decode_participants(spec, layouts, prev_atts),
-        curr_parts=_decode_participants(spec, layouts, curr_atts),
-        cl_roots={},
-    )
-    _prefill_crosslink_roots(spec, ctx, state)
+    """The epoch's layouts, decoded participants and crosslink roots, under
+    "distill.context" with a span a part (".layouts", which notes the
+    permutations the shuffle really computed inside it, ".participants",
+    ".crosslink_roots")."""
+    with telemetry.span("distill.context"):
+        np_cols = np_cols if np_cols is not None else columns_np_from_state(state)
+        current_epoch = spec.get_current_epoch(state)
+        previous_epoch = spec.get_previous_epoch(state)
+        prev_atts = list(spec.get_matching_source_attestations(state, previous_epoch))
+        curr_atts = list(spec.get_matching_source_attestations(state, current_epoch))
+        layouts = {}
+        with telemetry.span("distill.layouts") as sp_lay:
+            computed0 = PERMUTATIONS_COMPUTED.value
+            for e in {previous_epoch, current_epoch}.union(
+                    int(a.data.target_epoch) for a in prev_atts + curr_atts):
+                layouts[e] = _epoch_layout(spec, state, np_cols, e)
+            # a permutation the cache did not hold is a shuffle (on the
+            # device, where the kernel serves) waited for inside distill;
+            # the counter is the process's: one host thread is assumed
+            sp_lay.note(shuffles=PERMUTATIONS_COMPUTED.value - computed0)
+        with telemetry.span("distill.participants"):
+            prev_parts = _decode_participants(spec, layouts, prev_atts)
+            curr_parts = _decode_participants(spec, layouts, curr_atts)
+        ctx = EpochContext(
+            # column length, not len(validator_registry): identical for object
+            # states, and checkpoint-resumed resident states keep the registry
+            # as columns without materializing objects (resident.py)
+            n=len(np_cols["slashed"]), np_cols=np_cols, layouts=layouts,
+            prev_atts=prev_atts, curr_atts=curr_atts,
+            prev_parts=prev_parts, curr_parts=curr_parts,
+            cl_roots={},
+        )
+        with telemetry.span("distill.crosslink_roots"):
+            _prefill_crosslink_roots(spec, ctx, state)
     return ctx
 
 
@@ -915,63 +930,66 @@ def _crosslink_winners(spec, state, ctx: EpochContext, epoch: int):
     get_winning_crosslink_and_attesting_indices (:1308-1322), evaluated
     against the CURRENT state.current_crosslinks (callers control ordering
     vs record mutation, exactly like the reference's sequential loops)."""
-    current_epoch = spec.get_current_epoch(state)
-    atts = ctx.curr_atts if epoch == current_epoch else ctx.prev_atts
-    parts = ctx.curr_parts if epoch == current_epoch else ctx.prev_parts
-    lay = ctx.layouts[epoch]
+    with telemetry.span("distill.winners"):
+        current_epoch = spec.get_current_epoch(state)
+        atts = ctx.curr_atts if epoch == current_epoch else ctx.prev_atts
+        parts = ctx.curr_parts if epoch == current_epoch else ctx.prev_parts
+        lay = ctx.layouts[epoch]
 
-    def htr(c):
-        return _crosslink_root(spec, ctx, c)
+        def htr(c):
+            return _crosslink_root(spec, ctx, c)
 
-    default_cl = spec.Crosslink()
-    default_root = htr(default_cl)
+        default_cl = spec.Crosslink()
+        default_root = htr(default_cl)
 
-    by_shard: dict = {}
-    for j, a in enumerate(atts):
-        by_shard.setdefault(int(a.data.crosslink.shard), []).append(j)
+        by_shard: dict = {}
+        for j, a in enumerate(atts):
+            by_shard.setdefault(int(a.data.crosslink.shard), []).append(j)
 
-    out = []
-    for off in range(lay.count):
-        shard = (lay.start_shard + off) % spec.SHARD_COUNT
-        js = by_shard.get(shard, ())
-        current_root = htr(state.current_crosslinks[shard])
-        # Candidate crosslinks grouped by root, first-occurrence order; the
-        # root filter is `current_root in (c.parent_root, hash_tree_root(c))`
-        groups: dict = {}
-        order = []
-        cl_of = {}
-        for j in js:
-            c = atts[j].data.crosslink
-            r = htr(c)
-            if current_root != bytes(c.parent_root) and current_root != r:
+        out = []
+        for off in range(lay.count):
+            shard = (lay.start_shard + off) % spec.SHARD_COUNT
+            js = by_shard.get(shard, ())
+            current_root = htr(state.current_crosslinks[shard])
+            # Candidate crosslinks grouped by root, first-occurrence order; the
+            # root filter is `current_root in (c.parent_root, hash_tree_root(c))`
+            groups: dict = {}
+            order = []
+            cl_of = {}
+            for j in js:
+                c = atts[j].data.crosslink
+                r = htr(c)
+                if current_root != bytes(c.parent_root) and current_root != r:
+                    continue
+                if r not in groups:
+                    groups[r] = []
+                    order.append(r)
+                    cl_of[r] = c
+                groups[r].append(j)
+            if not order:
+                # max(..., default=Crosslink()): the default still collects
+                # attestations whose crosslink equals it (:1318-1321)
+                win_js = [j for j in js if htr(atts[j].data.crosslink) == default_root]
+                win_idx = _unslashed_union(ctx, [parts[j] for j in win_js])
+                out.append((default_cl, win_idx, _balance_of(ctx, win_idx)))
                 continue
-            if r not in groups:
-                groups[r] = []
-                order.append(r)
-                cl_of[r] = c
-            groups[r].append(j)
-        if not order:
-            # max(..., default=Crosslink()): the default still collects
-            # attestations whose crosslink equals it (:1318-1321)
-            win_js = [j for j in js if htr(atts[j].data.crosslink) == default_root]
-            win_idx = _unslashed_union(ctx, [parts[j] for j in win_js])
-            out.append((default_cl, win_idx, _balance_of(ctx, win_idx)))
-            continue
-        best = None
-        for r in order:
-            idx = _unslashed_union(ctx, [parts[j] for j in groups[r]])
-            key = (_balance_of(ctx, idx), bytes(cl_of[r].data_root))
-            if best is None or key > best[0]:  # strict: first max wins, like max()
-                best = (key, cl_of[r], idx)
-        out.append((best[1], best[2], best[0][0]))
-    return out
+            best = None
+            for r in order:
+                idx = _unslashed_union(ctx, [parts[j] for j in groups[r]])
+                key = (_balance_of(ctx, idx), bytes(cl_of[r].data_root))
+                if best is None or key > best[0]:  # strict: first max wins, like max()
+                    best = (key, cl_of[r], idx)
+            out.append((best[1], best[2], best[0][0]))
+        return out
 
 
 def _committee_balances(ctx: EpochContext, lay: _Layout) -> np.ndarray:
     """[count] committee effective-balance sums via one cumsum (>=1 each)."""
-    eff = ctx.np_cols["effective_balance"][lay.shuffled].astype(np.int64)
-    cs = np.concatenate([[0], np.cumsum(eff)])
-    return np.maximum(cs[lay.bounds[1:]] - cs[lay.bounds[:-1]], 1).astype(np.uint64)
+    with telemetry.span("distill.committee_balances"):
+        eff = ctx.np_cols["effective_balance"][lay.shuffled].astype(np.int64)
+        cs = np.concatenate([[0], np.cumsum(eff)])
+        return np.maximum(cs[lay.bounds[1:]] - cs[lay.bounds[:-1]],
+                          1).astype(np.uint64)
 
 
 def process_crosslinks_vectorized(spec, state, ctx: EpochContext) -> None:
@@ -984,15 +1002,17 @@ def process_crosslinks_vectorized(spec, state, ctx: EpochContext) -> None:
     batch-computed before its updates. Across epochs the sequencing is
     preserved: the current epoch's winners are selected against the
     previous epoch's updated records."""
-    state.previous_crosslinks = [c for c in state.current_crosslinks]
-    for epoch in (spec.get_previous_epoch(state), spec.get_current_epoch(state)):
-        lay = ctx.layouts[epoch]
-        comm_bal = _committee_balances(ctx, lay)
-        winners = _crosslink_winners(spec, state, ctx, epoch)
-        for off, (winner, _, att_bal) in enumerate(winners):
-            shard = (lay.start_shard + off) % spec.SHARD_COUNT
-            if 3 * att_bal >= 2 * int(comm_bal[off]):
-                state.current_crosslinks[shard] = winner
+    with telemetry.span("distill.crosslinks"):
+        state.previous_crosslinks = [c for c in state.current_crosslinks]
+        for epoch in (spec.get_previous_epoch(state),
+                      spec.get_current_epoch(state)):
+            lay = ctx.layouts[epoch]
+            comm_bal = _committee_balances(ctx, lay)
+            winners = _crosslink_winners(spec, state, ctx, epoch)
+            for off, (winner, _, att_bal) in enumerate(winners):
+                shard = (lay.start_shard + off) % spec.SHARD_COUNT
+                if 3 * att_bal >= 2 * int(comm_bal[off]):
+                    state.current_crosslinks[shard] = winner
 
 
 def build_epoch_inputs(spec, state, ctx: EpochContext = None) -> EpochInputs:
@@ -1012,67 +1032,70 @@ def build_epoch_inputs_np(spec, state,
     reference's process_epoch ordering :1251-1262).
     """
     ctx = ctx if ctx is not None else build_epoch_context(spec, state)
-    n = ctx.n
-    current_epoch = spec.get_current_epoch(state)
-    previous_epoch = spec.get_previous_epoch(state)
-    prev_lay = ctx.layouts[previous_epoch]
+    with telemetry.span("distill.inputs"):
+        n = ctx.n
+        current_epoch = spec.get_current_epoch(state)
+        previous_epoch = spec.get_previous_epoch(state)
+        prev_lay = ctx.layouts[previous_epoch]
 
-    # Matching filters (:1266-1290) — cheap per-attestation byte compares
-    prev_target_root = spec.get_block_root(state, previous_epoch)
-    prev_src = _union_flags(n, ctx.prev_parts)
-    prev_tgt = _union_flags(n, (
-        p for a, p in zip(ctx.prev_atts, ctx.prev_parts)
-        if bytes(a.data.target_root) == prev_target_root))
-    prev_head = _union_flags(n, (
-        p for a, p in zip(ctx.prev_atts, ctx.prev_parts)
-        if bytes(a.data.beacon_block_root) == spec.get_block_root_at_slot(
-            state, _attestation_data_slot(
-                spec, ctx.layouts[int(a.data.target_epoch)], a.data))))
-    curr_target_root = spec.get_block_root(state, current_epoch)
-    curr_tgt = _union_flags(n, (
-        p for a, p in zip(ctx.curr_atts, ctx.curr_parts)
-        if bytes(a.data.target_root) == curr_target_root))
+        # Matching filters (:1266-1290) — cheap per-attestation byte compares
+        with telemetry.span("distill.inputs.flags"):
+            prev_target_root = spec.get_block_root(state, previous_epoch)
+            prev_src = _union_flags(n, ctx.prev_parts)
+            prev_tgt = _union_flags(n, (
+                p for a, p in zip(ctx.prev_atts, ctx.prev_parts)
+                if bytes(a.data.target_root) == prev_target_root))
+            prev_head = _union_flags(n, (
+                p for a, p in zip(ctx.prev_atts, ctx.prev_parts)
+                if bytes(a.data.beacon_block_root) == spec.get_block_root_at_slot(
+                    state, _attestation_data_slot(
+                        spec, ctx.layouts[int(a.data.target_epoch)], a.data))))
+            curr_target_root = spec.get_block_root(state, current_epoch)
+            curr_tgt = _union_flags(n, (
+                p for a, p in zip(ctx.curr_atts, ctx.curr_parts)
+                if bytes(a.data.target_root) == curr_target_root))
 
-    # Min-inclusion-delay attestation per source attester (:1423-1429);
-    # python min() keeps the first minimum, so strict < preserves tie order.
-    incl_delay = np.ones(n, dtype=np.uint64)
-    best = np.full(n, np.iinfo(np.uint64).max, dtype=np.uint64)
-    att_proposer = np.zeros(n, dtype=np.int32)
-    for a, idxs in zip(ctx.prev_atts, ctx.prev_parts):
-        better = a.inclusion_delay < best[idxs]
-        upd = idxs[better]
-        best[upd] = a.inclusion_delay
-        incl_delay[upd] = a.inclusion_delay
-        att_proposer[upd] = a.proposer_index
+        # Min-inclusion-delay attestation per source attester (:1423-1429);
+        # python min() keeps the first minimum, so strict < preserves tie order.
+        with telemetry.span("distill.inputs.inclusion"):
+            incl_delay = np.ones(n, dtype=np.uint64)
+            best = np.full(n, np.iinfo(np.uint64).max, dtype=np.uint64)
+            att_proposer = np.zeros(n, dtype=np.int32)
+            for a, idxs in zip(ctx.prev_atts, ctx.prev_parts):
+                better = a.inclusion_delay < best[idxs]
+                upd = idxs[better]
+                best[upd] = a.inclusion_delay
+                incl_delay[upd] = a.inclusion_delay
+                att_proposer[upd] = a.proposer_index
 
-    # Crosslink-committee layout + winners for the previous epoch (:1445-1463)
-    v_shard = np.full(n, -1, dtype=np.int32)
-    shards = ((prev_lay.start_shard + np.arange(prev_lay.count))
-              % spec.SHARD_COUNT).astype(np.int32)
-    v_shard[prev_lay.shuffled] = np.repeat(shards, np.diff(prev_lay.bounds))
-    in_winning = np.zeros(n, dtype=bool)
-    shard_att_balance = np.ones(spec.SHARD_COUNT, dtype=np.uint64)
-    shard_comm_balance = np.ones(spec.SHARD_COUNT, dtype=np.uint64)
-    comm_bal = _committee_balances(ctx, prev_lay)
-    winners = _crosslink_winners(spec, state, ctx, previous_epoch)
-    for off, (_, win_idx, att_bal) in enumerate(winners):
-        shard = int(shards[off])
-        in_winning[win_idx] = True
-        shard_att_balance[shard] = att_bal
-        shard_comm_balance[shard] = comm_bal[off]
+        # Crosslink-committee layout + winners for the previous epoch (:1445-1463)
+        v_shard = np.full(n, -1, dtype=np.int32)
+        shards = ((prev_lay.start_shard + np.arange(prev_lay.count))
+                  % spec.SHARD_COUNT).astype(np.int32)
+        v_shard[prev_lay.shuffled] = np.repeat(shards, np.diff(prev_lay.bounds))
+        in_winning = np.zeros(n, dtype=bool)
+        shard_att_balance = np.ones(spec.SHARD_COUNT, dtype=np.uint64)
+        shard_comm_balance = np.ones(spec.SHARD_COUNT, dtype=np.uint64)
+        comm_bal = _committee_balances(ctx, prev_lay)
+        winners = _crosslink_winners(spec, state, ctx, previous_epoch)
+        for off, (_, win_idx, att_bal) in enumerate(winners):
+            shard = int(shards[off])
+            in_winning[win_idx] = True
+            shard_att_balance[shard] = att_bal
+            shard_comm_balance[shard] = comm_bal[off]
 
-    # every value att_proposer holds is some attestation's proposer_index
-    proposer_table, proposer_rows = proposer_table_np(
-        [a.proposer_index for a in ctx.prev_atts],
-        proposer_table_capacity(spec))
+        # every value att_proposer holds is some attestation's proposer_index
+        proposer_table, proposer_rows = proposer_table_np(
+            [a.proposer_index for a in ctx.prev_atts],
+            proposer_table_capacity(spec))
 
-    return EpochInputs(
-        prev_src=prev_src, prev_tgt=prev_tgt, prev_head=prev_head,
-        curr_tgt=curr_tgt, incl_delay=incl_delay, att_proposer=att_proposer,
-        v_shard=v_shard, in_winning=in_winning,
-        shard_att_balance=shard_att_balance,
-        shard_comm_balance=shard_comm_balance,
-        proposer_table=proposer_table, proposer_rows=proposer_rows)
+        return EpochInputs(
+            prev_src=prev_src, prev_tgt=prev_tgt, prev_head=prev_head,
+            curr_tgt=curr_tgt, incl_delay=incl_delay, att_proposer=att_proposer,
+            v_shard=v_shard, in_winning=in_winning,
+            shard_att_balance=shard_att_balance,
+            shard_comm_balance=shard_comm_balance,
+            proposer_table=proposer_table, proposer_rows=proposer_rows)
 
 
 def process_epoch_soa(spec, state, timings: dict = None):
@@ -1087,7 +1110,8 @@ def process_epoch_soa(spec, state, timings: dict = None):
     Returns the post-transition device columns (still device-resident) so
     production callers can chain the device state root without a re-upload.
     Stages run under telemetry spans ("epoch.distill", "epoch.perm",
-    "epoch.device", "epoch.writeback") with honest fences at span exit
+    "epoch.device", "epoch.writeback"; the second "epoch.distill" holds
+    the builders' own "distill.*" spans) with honest fences at span exit
     only; when `timings` is given, the span durations are mirrored into it
     under the historical keys ("distill", "perm", "device", "writeback")
     so bench JSON stays comparable — zeros when CSTPU_TELEMETRY=0

@@ -18,6 +18,7 @@ from typing import Any, List, Optional, Sequence
 
 import numpy as np
 
+from ... import telemetry
 from ...utils import merkle
 from ...utils.ssz import bulk
 from ...utils.ssz.impl import hash_tree_root as ssz_hash_tree_root
@@ -373,6 +374,9 @@ def get_shuffled_index(spec, index: int, index_count: int, seed: bytes) -> int:
 
 
 _shuffle_backend = None
+# Permutations really computed: bumped on every miss of `spec._perm_cache`,
+# whichever backend then serves it.
+PERMUTATIONS_COMPUTED = telemetry.counter("shuffle.permutations_computed")
 
 
 def set_shuffle_backend(backend) -> None:
@@ -399,6 +403,7 @@ def get_shuffle_permutation(spec, index_count: int, seed: bytes) -> np.ndarray:
     cached = spec._perm_cache.get(key)
     if cached is not None:
         return cached
+    PERMUTATIONS_COMPUTED.inc()
     perm = None
     if _shuffle_backend is not None:
         perm = _shuffle_backend(bytes(seed), index_count, spec.SHUFFLE_ROUND_COUNT)

@@ -357,7 +357,6 @@ class ResidentCore:
         self.spec = spec
         self.cfg = EpochConfig.from_spec(spec)
         self.state = state
-        self.timings: Dict[str, float] = {}
         self._saved_methods: Dict[str, object] = {}
         self._saved_root_backend = None
         self._active_idx_memo: Dict[int, np.ndarray] = {}
@@ -434,7 +433,6 @@ class ResidentCore:
         core._tkey = f"resident{next(_CORE_SEQ)}"
         core.spec = spec
         core.cfg = EpochConfig.from_spec(spec)
-        core.timings = {}
         core._saved_methods = {}
         core._saved_root_backend = None
         core._active_idx_memo = {}
@@ -1398,19 +1396,20 @@ class ResidentCore:
     def process_epoch_resident(self, state) -> None:
         """The boundary transition on resident columns, under telemetry
         spans ("resident.stage" — host distillation off the mirrors
-        (".distill") and the wait for its uploads (".upload"),
-        "resident.device" — the epoch program on resident columns,
-        "resident.refresh" — scalars, report and mirror columns down
-        (".download"), byte-rooted final updates (".final_updates") and
-        the forest rebuild ("resident.forests")). self.timings keeps the
-        historical
-        {"stage", "device", "refresh"} view, now derived from the spans
-        (zeros under CSTPU_TELEMETRY=0). The retrace and re-layout
+        (".distill": the builders' own "distill.context", ".crosslinks"
+        and ".inputs" with their parts (epoch_soa.py), then ".place", the
+        dispatch of the facts to where the program takes them) and the
+        wait for its uploads (".upload"), "resident.device" — the epoch
+        program on resident columns, "resident.refresh" — scalars, report
+        and mirror columns down (".download"), byte-rooted final updates
+        (".final_updates") and the forest rebuild ("resident.forests")).
+        The span records are the one view of the boundary's times
+        (telemetry.ring(), snapshot()["spans"]). The retrace and re-layout
         watchdogs cover the dispatch: the epoch program must neither
         recompile nor change the columns' placement between chained
         boundaries."""
         spec = self.spec
-        with telemetry.span("resident.stage") as sp_stage:
+        with telemetry.span("resident.stage"):
             with telemetry.span("resident.stage.distill") as sp_distill:
                 current_epoch = spec.get_current_epoch(state)
                 previous_epoch = spec.get_previous_epoch(state)
@@ -1419,6 +1418,7 @@ class ResidentCore:
                     activation_eligibility_epoch=None,  # unused by the context
                     withdrawable_epoch=None,
                     balance=None))
+                prefilled = len(ctx.cl_roots)
                 process_crosslinks_vectorized(spec, state, ctx)
                 facts = build_epoch_inputs_np(spec, state, ctx)
                 # how hard the epoch program's proposer sum works: its
@@ -1428,8 +1428,15 @@ class ResidentCore:
                     # the active set the boundary ran on: it moves when
                     # blocks carry exits and slashings
                     active_validators=len(spec.get_active_validator_indices(
-                        state, current_epoch)))
-                scal, inp = self._stage_epoch_inputs(state, facts)
+                        state, current_epoch)),
+                    # what distill's row slope is per
+                    pending_rows=len(ctx.prev_atts) + len(ctx.curr_atts),
+                    # Crosslink roots the three winner passes hashed one by
+                    # one because the context's batch did not hold them
+                    crosslink_roots_hashed_singly=(len(ctx.cl_roots)
+                                                   - prefilled))
+                with telemetry.span("resident.stage.distill.place"):
+                    scal, inp = self._stage_epoch_inputs(state, facts)
             with telemetry.span("resident.stage.upload") as sp_up:
                 sp_up.fence(scal, inp)  # uploads land in "resident.stage"
 
@@ -1445,7 +1452,7 @@ class ResidentCore:
                         else self._mesh.size)
             sp_dev.fence(dev_cols.balance)
 
-        with telemetry.span("resident.refresh") as sp_ref:
+        with telemetry.span("resident.refresh"):
             self.cols = dev_cols
             self._big_roots = None
             # the boundary dirties every leaf (rewards touch all balances):
@@ -1485,5 +1492,3 @@ class ResidentCore:
                     index_root_lanes=_FOREST_PAIR_LANES.value - lanes0,
                     host_pairs_hashed=bulk.HOST_PAIRS_HASHED.value - hashed0)
             self._registry_balances_roots()      # recompute + cache the roots
-        self.timings = {"stage": sp_stage.duration, "device": sp_dev.duration,
-                        "refresh": sp_ref.duration}

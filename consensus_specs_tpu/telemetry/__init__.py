@@ -27,11 +27,18 @@ session shows the program's spans in its `/host:CPU` plane on the device
 trace's clock (core.py binds it lazily; the package never imports jax).
 
 Naming scheme (dot-separated `subsystem.stage`): spans `epoch.*`
-(process_epoch_soa stages), `resident.*` (the resident serving loop: the
+(process_epoch_soa stages), `distill.*` (the host distillation's own
+parts, opened by epoch_soa's builders under whichever stage calls them,
+`epoch.distill` or `resident.stage.distill`: `distill.context` over
+`.layouts .participants .crosslink_roots`, `distill.crosslinks`,
+`distill.inputs` over `.flags .inclusion`, and `distill.winners` /
+`distill.committee_balances` once a pass, three a boundary),
+`resident.*` (the resident serving loop: the
 roots `resident.slot` / `resident.boundary_slot` (`req` = the slot) over
 `resident.slot_root` with its groups `.forests .attestations .history
-.small .merkleize`, and at a boundary `resident.stage` (`.distill
-.upload`), `resident.device`, `resident.refresh` (`.download
+.small .merkleize`, and at a boundary `resident.stage` (`.distill`, which
+ends in `resident.stage.distill.place`, and `.upload`),
+`resident.device`, `resident.refresh` (`.download
 .final_updates`) and `resident.forests`; `resident.block` (a root of
 its own, `req` = the block's slot: the slot's root span has closed when
 `process_slots` returned) over `.header .randao .eth1 .attestations`;
@@ -42,7 +49,8 @@ exit-only fences), `bench.*` / `followup.*` (harnesses); counters
 `fq.redc.*` (trace-time REDC accounting), `merkle.forest.*` (pair-hash
 lanes/launches/builds), `merkle.host.*` (pairs hashed / taken from the
 zero-hash table by the host Merkleizer), `scalar_mul.*`, `bls.grouped.*` (grouped-pairing
-launch occupancy), `firehose.*` (queue depth / batch occupancy /
+launch occupancy), `shuffle.permutations_computed` (misses of the spec's
+permutation cache: shuffles really run), `firehose.*` (queue depth / batch occupancy /
 deadline misses — always-on: /healthz reads them), `watchdog.*`
 (retrace/re-layout events), `resident.block.fallbacks` (blocks that left
 the served path for the object model; always-on), `jax.backend_compiles` (global compile

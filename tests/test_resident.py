@@ -502,6 +502,19 @@ def test_one_epoch_leaves_one_span_tree_a_slot(spec, spans):
     by_name = {k["name"]: k for k in boundary}
     assert [k["name"] for k in _children(records, by_name["resident.stage"])] \
         == ["resident.stage.distill", "resident.stage.upload"]
+    # distill from inside: the builders' own spans, then the placement
+    distill = _children(records, by_name["resident.stage"])[0]
+    parts = _children(records, distill)
+    assert [k["name"] for k in parts] == [
+        "distill.context", "distill.crosslinks", "distill.inputs",
+        "resident.stage.distill.place"]
+    assert [k["name"] for k in _children(records, parts[0])] == [
+        "distill.layouts", "distill.participants", "distill.crosslink_roots"]
+    for parent in (distill, parts[0]):
+        kids = _children(records, parent)
+        assert sum(k["dur"] for k in kids) <= parent["dur"]
+        assert all(parent["ts"] <= k["ts"] and k["ts"] + k["dur"]
+                   <= parent["ts"] + parent["dur"] for k in kids)
     assert [k["name"] for k in _children(records, by_name["resident.refresh"])] \
         == ["resident.refresh.download", "resident.refresh.final_updates",
             "resident.forests"]

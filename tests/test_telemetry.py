@@ -241,9 +241,18 @@ def test_profiler_session_holds_the_resident_spans(spec, tmp_path):
         "resident.slot_root.forests", "resident.slot_root.attestations",
         "resident.slot_root.history", "resident.slot_root.small",
         "resident.slot_root.merkleize", "resident.stage",
-        "resident.stage.distill", "resident.stage.upload", "resident.device",
+        "resident.stage.distill", "resident.stage.distill.place",
+        "resident.stage.upload", "resident.device",
         "resident.refresh", "resident.refresh.download",
         "resident.refresh.final_updates", "resident.forests"}
+    # the builders' spans carry no `resident.` prefix: one boundary, so one
+    # of each part and three of each of the two passes
+    parts = [e.name for e in reduce.annotations(planes, prefix="distill.")]
+    assert sorted(parts) == sorted([
+        "distill.context", "distill.layouts", "distill.participants",
+        "distill.crosslink_roots", "distill.crosslinks", "distill.inputs",
+        "distill.inputs.flags", "distill.inputs.inclusion"]
+        + ["distill.winners", "distill.committee_balances"] * 3)
     assert sum(e.name == "resident.slot_root" for e in events) == spe
     assert all(lo <= e.start_ns and e.end_ns <= hi for e in events)
     # the trace's extents agree with the ring's durations
@@ -535,10 +544,12 @@ def test_watchdogs_silent_on_layout_stable_resident_loop(spec):
         core.process_slots(state, target + spe)   # >= 4 slots + 1 boundary
         assert T.counter("watchdog.retrace_events").value == retrace0
         assert T.counter("watchdog.relayout_events").value == relayout0
-        # the boundary ran, span-derived timings carry the historic keys
-        assert set(core.timings) == {"stage", "device", "refresh"}
-        assert all(v > 0 for v in core.timings.values())
+        # the boundary ran: its three terms are span records, the one view
+        # of the boundary's times
         spans = T.snapshot()["spans"]
+        boundary = {"resident.stage", "resident.device", "resident.refresh"}
+        assert set(spans) >= boundary
+        assert all(spans[name]["last_ms"] > 0 for name in boundary)
         assert spans["resident.device"]["count"] >= 2
         assert spans["resident.slot_root"]["count"] >= spe + 4
     finally:

@@ -73,11 +73,12 @@ def main() -> int:
         for i in range(epochs):
             t0 = time.perf_counter()
             core.process_slots(state, target + (i + 1) * spe)
-            tm = core.timings
+            last = {name: agg["last_ms"] for name, agg
+                    in telemetry.snapshot()["spans"].items()}
             print(f"epoch {i}: {time.perf_counter() - t0:.2f}s "
-                  f"(stage {tm['stage'] * 1e3:.0f} ms, device "
-                  f"{tm['device'] * 1e3:.0f} ms, refresh "
-                  f"{tm['refresh'] * 1e3:.0f} ms)", flush=True)
+                  f"(stage {last['resident.stage']:.0f} ms, device "
+                  f"{last['resident.device']:.0f} ms, refresh "
+                  f"{last['resident.refresh']:.0f} ms)", flush=True)
             telemetry.write_jsonl(jsonl_path, extra={"epoch": i})
         retrace = telemetry.counter("watchdog.retrace_events").value - retrace0
         relayout = (telemetry.counter("watchdog.relayout_events").value
