@@ -74,6 +74,7 @@ serialized states and per-slot roots vs the object model.
 from __future__ import annotations
 
 import itertools
+import time
 from typing import Dict, Optional
 
 import numpy as np
@@ -946,7 +947,7 @@ class ResidentCore:
 
     # -- state roots --------------------------------------------------------
 
-    def _registry_balances_roots(self):
+    def _registry_balances_roots(self, dispatched: Optional[tuple] = None):
         """(registry_root, balances_root) from the incremental forests.
 
         First request after an (epoch-boundary or entry) invalidation builds
@@ -954,23 +955,33 @@ class ResidentCore:
         batched pair-hash launch per level, the same O(V) the old one-shot
         device root paid. Every request between boundaries is O(1) (cached)
         or O(dirty * log V) after a fallback block's leaf-level updates —
-        never the all-or-nothing ~2M-leaf re-Merkleization."""
+        never the all-or-nothing ~2M-leaf re-Merkleization.
+
+        `resident.forests` is the span of a build, and it notes the build's
+        `pair_lanes` and `ahead_ms`. A boundary's refresh dispatches the
+        build first (`_dispatch_forests`, under the download and the final
+        updates) and hands `dispatched` = (its pair lanes, the clock when
+        the dispatch ended): the span is then the wait for the two top
+        rows, and `ahead_ms` how long the build ran under other host work
+        before it opened. Every other caller gets dispatch and wait in
+        one place, inside the span, `ahead_ms` 0."""
         if self._big_roots is not None:
             return self._big_roots
-        if self._reg_forest is not None and self._bal_forest is not None:
+        if dispatched is None and self._reg_forest is not None \
+                and self._bal_forest is not None:
             # both stand and have taken a block's dirty paths
-            # (_update_forest_paths): their roots as they now are, in one
-            # transfer; nothing is built, so no `resident.forests` span
-            top = jax.device_get((self._reg_forest.levels[-1],
-                                  self._bal_forest.levels[-1]))
-            self._big_roots = tuple(
-                ssz_impl.mix_in_length(words_to_bytes(t[0]).tobytes(), self._v)
-                for t in top)
+            # (_update_forest_paths), or were dispatched by a refresh that
+            # raised before its wait: their roots as they now are;
+            # nothing is built, so no `resident.forests` span
+            self._big_roots = self._forest_roots()
             return self._big_roots
         with telemetry.span("resident.forests") as sp:
-            lanes0 = _FOREST_PAIR_LANES.value
-            self._big_roots = self._build_forest_roots()
-            sp.note(pair_lanes=_FOREST_PAIR_LANES.value - lanes0)
+            opened = time.perf_counter()
+            if dispatched is None:
+                dispatched = (self._dispatch_forests(), opened)
+            lanes, since = dispatched
+            sp.note(pair_lanes=lanes, ahead_ms=(opened - since) * 1e3)
+            self._big_roots = self._forest_roots()
         return self._big_roots
 
     def _balances_forest(self, column) -> IncrementalMerkleTree:
@@ -1010,17 +1021,18 @@ class ResidentCore:
         level = jax.device_get(tree.levels[tree_depth(-(-n // 4))])
         return ssz_impl.mix_in_length(words_to_bytes(level[0]).tobytes(), n)
 
-    def _build_forest_roots(self) -> tuple:
-        """The build behind `_registry_balances_roots`: both leaf
-        programs, both forests' levels, both roots down to the host."""
+    def _dispatch_forests(self) -> int:
+        """The build behind `_registry_balances_roots`, as far as the host
+        need not wait: both forests dispatched from the device columns as
+        they stand (a forest that stands is kept), the leaf programs and
+        the level builds queued on the device, nothing fetched, so the
+        host goes on while they run (`_forest_roots` is the wait).
+        Returns the pair-hash lanes launched."""
         c = self.cols
         V = self._v
         if V == 0 or self.pk_dev.shape[0] == 0:
-            # degenerate metadata-only state: the numpy oracle short-circuit
-            return bulk.registry_and_balances_roots_device(
-                self.pk_dev, self.wc_dev, c.activation_eligibility_epoch,
-                c.activation_epoch, c.exit_epoch, c.withdrawable_epoch,
-                c.slashed, c.effective_balance, c.balance)
+            return 0        # degenerate: no forest (`_forest_roots`)
+        lanes0 = _FOREST_PAIR_LANES.value
         if self._mesh is not None:
             # sharded forests: level 0 built by the mesh's placed leaf
             # programs (inert padding rows masked to the SSZ virtual-zero
@@ -1051,8 +1063,24 @@ class ResidentCore:
                                self._reg_forest.levels[0])
         _watchdog.layout_check(f"{self._tkey}.forest.bal.l0",
                                self._bal_forest.levels[0])
-        return (ssz_impl.mix_in_length(self._reg_forest.root(), V),
-                ssz_impl.mix_in_length(self._bal_forest.root(), V))
+        return _FOREST_PAIR_LANES.value - lanes0
+
+    def _forest_roots(self) -> tuple:
+        """(registry_root, balances_root) of the forests as they stand:
+        the wait for whatever is still queued on them, both top rows down
+        in one transfer, the lengths mixed in."""
+        if self._reg_forest is None or self._bal_forest is None:
+            # degenerate metadata-only state: the numpy oracle short-circuit
+            c = self.cols
+            return bulk.registry_and_balances_roots_device(
+                self.pk_dev, self.wc_dev, c.activation_eligibility_epoch,
+                c.activation_epoch, c.exit_epoch, c.withdrawable_epoch,
+                c.slashed, c.effective_balance, c.balance)
+        top = jax.device_get((self._reg_forest.levels[-1],
+                              self._bal_forest.levels[-1]))
+        return tuple(
+            ssz_impl.mix_in_length(words_to_bytes(t[0]).tobytes(), self._v)
+            for t in top)
 
     def _state_root(self, state):
         """Full BeaconState root: device roots for the two registry-scale
@@ -1400,9 +1428,15 @@ class ResidentCore:
         and ".inputs" with their parts (epoch_soa.py), then ".place", the
         dispatch of the facts to where the program takes them) and the
         wait for its uploads (".upload"), "resident.device" — the epoch
-        program on resident columns, "resident.refresh" — scalars, report
-        and mirror columns down (".download"), byte-rooted final updates
-        (".final_updates") and the forest rebuild ("resident.forests")).
+        program on resident columns, "resident.refresh" — the forest
+        rebuild dispatched (".forests_dispatch": nothing fenced, the device
+        builds while the host goes on), scalars, report and mirror columns
+        down (".download": their host copies were started first, ahead of
+        the forests' programs), byte-rooted final updates
+        (".final_updates": its index tree queues behind the forests, so
+        the fetch of its level is what first waits for them) and last the
+        wait for the forests' two roots ("resident.forests", fetched
+        before this call returns: no root is deferred to a later slot)).
         The span records are the one view of the boundary's times
         (telemetry.ring(), snapshot()["spans"]). The retrace and re-layout
         watchdogs cover the dispatch: the epoch program must neither
@@ -1461,14 +1495,26 @@ class ResidentCore:
             self._bal_forest = None
             self._active_idx_memo.clear()
             self._exit_queue = None     # the program's ejections move it
+            # refresh ONLY the columns host logic reads; slashed never
+            # changes in the epoch program, balances stay device-only
+            mirrored = ("activation_epoch", "exit_epoch", "effective_balance")
+            # what the download will read, on its way to the host before
+            # anything else is queued on the device
+            for leaf in jax.tree_util.tree_leaves(
+                    (dev_scal, dev_report,
+                     [getattr(dev_cols, f) for f in mirrored])):
+                leaf.copy_to_host_async()
+            # the forest rebuild runs on the device under the download and
+            # the final updates, which read and write nothing of it: it is
+            # dispatched first and waited for last
+            with telemetry.span("resident.refresh.forests_dispatch"):
+                lanes = self._dispatch_forests()
+            dispatched = (lanes, time.perf_counter())
             with telemetry.span("resident.refresh.download"):
                 new_scal, report = jax.device_get((dev_scal, dev_report))
-                # refresh ONLY the columns host logic reads; slashed never
-                # changes in the epoch program, balances stay device-only
                 # (the [:_v] slice drops the sharded layout's inert padding
                 # rows)
-                for f in ("activation_epoch", "exit_epoch",
-                          "effective_balance"):
+                for f in mirrored:
                     self.mirrors[f] = np.asarray(
                         jax.device_get(getattr(dev_cols, f)))[:self._v]
             with telemetry.span("resident.refresh.final_updates") as sp_fin:
@@ -1485,10 +1531,12 @@ class ResidentCore:
                     slashed[int(i)] = int(new[i])
                 state.latest_start_shard = int(new_scal.latest_start_shard)
                 # the active-index root in it is this core's device build
-                # (_install): its lanes, and what the host hashed besides
-                # (a historical batch every 128th epoch, else nothing)
+                # (_install), queued behind the forests: its lanes, and
+                # what the host hashed besides (a historical batch every
+                # 128th epoch, else nothing)
                 spec.final_updates_byte_rooted(state)
                 sp_fin.note(
                     index_root_lanes=_FOREST_PAIR_LANES.value - lanes0,
                     host_pairs_hashed=bulk.HOST_PAIRS_HASHED.value - hashed0)
-            self._registry_balances_roots()      # recompute + cache the roots
+            # the wait, the two roots down, cached: inside this boundary
+            self._registry_balances_roots(dispatched)

@@ -38,8 +38,9 @@ roots `resident.slot` / `resident.boundary_slot` (`req` = the slot) over
 `resident.slot_root` with its groups `.forests .attestations .history
 .small .merkleize`, and at a boundary `resident.stage` (`.distill`, which
 ends in `resident.stage.distill.place`, and `.upload`),
-`resident.device`, `resident.refresh` (`.download
-.final_updates`) and `resident.forests`; `resident.block` (a root of
+`resident.device`, `resident.refresh` (`.forests_dispatch .download
+.final_updates`) and `resident.forests` (at a boundary the wait for the
+forests the refresh dispatched first); `resident.block` (a root of
 its own, `req` = the block's slot: the slot's root span has closed when
 `process_slots` returned) over `.header .randao .eth1 .attestations`;
 `resident.checkpoint_write`

@@ -207,12 +207,21 @@ def test_three_boundaries_over_a_moving_active_set(spec, spans, devices):
     assert [r["args"] for r in final] == [
         {"index_root_lanes": index_lanes, "host_pairs_hashed": 0}] * 3
     # the third build stays outside `resident.forests`, whose lanes are the
-    # two forests' and nothing else, as before
+    # two forests' and nothing else, as before: the forests are dispatched
+    # before the final updates open (their lanes are counted there and
+    # carried to the wait), the index tree is built inside them
     forests = [r for r in records if r["name"] == "resident.forests"
                and r["parent"] == "resident.refresh"]
     assert [r["args"]["pair_lanes"] for r in forests] \
         == [_lanes(V, devices) + index_lanes] * 3
     assert all(f["ts"] >= r["ts"] + r["dur"] for f, r in zip(forests, final))
+    dispatched = [r for r in records
+                  if r["name"] == "resident.refresh.forests_dispatch"]
+    assert len(dispatched) == 3
+    assert all(d["ts"] + d["dur"] <= r["ts"]
+               for d, r in zip(dispatched, final))
+    assert all(f["args"]["ahead_ms"] >= r["dur"] * 1e3
+               for f, r in zip(forests, final))
 
 
 # -- 3. a state the core does not hold -----------------------------------------

@@ -516,15 +516,121 @@ def test_one_epoch_leaves_one_span_tree_a_slot(spec, spans):
         assert all(parent["ts"] <= k["ts"] and k["ts"] + k["dur"]
                    <= parent["ts"] + parent["dur"] for k in kids)
     assert [k["name"] for k in _children(records, by_name["resident.refresh"])] \
-        == ["resident.refresh.download", "resident.refresh.final_updates",
-            "resident.forests"]
+        == ["resident.refresh.forests_dispatch", "resident.refresh.download",
+            "resident.refresh.final_updates", "resident.forests"]
     assert sum(k["dur"] for k in boundary) <= roots[-1]["dur"]
     # the forests are built twice in the epoch: at entry, under the first
-    # slot's `slot_root.forests`, and in the boundary's refresh
+    # slot's `slot_root.forests`, build and wait in one place, and in the
+    # boundary's refresh, dispatched first and waited for last: the same
+    # build (the lanes are counted at the dispatch and noted at the wait),
+    # which ran under the download and the final updates
     forests = [r for r in records if r["name"] == "resident.forests"]
     assert [f["parent"] for f in forests] == ["resident.slot_root.forests",
                                               "resident.refresh"]
-    assert all(f["args"]["pair_lanes"] > 0 for f in forests)
+    assert all(set(f["args"]) == {"pair_lanes", "ahead_ms"} for f in forests)
+    first, waited = (f["args"] for f in forests)
+    assert first["pair_lanes"] == waited["pair_lanes"] > 0
+    assert first["ahead_ms"] == 0 < waited["ahead_ms"]
+    dispatch, download, final_updates, _ = _children(
+        records, by_name["resident.refresh"])
+    assert dispatch["args"] is None             # it notes and fences nothing
+    ahead_s = waited["ahead_ms"] / 1e3
+    assert download["dur"] + final_updates["dur"] <= ahead_s \
+        <= by_name["resident.refresh"]["dur"] - dispatch["dur"]
+
+
+LAYOUTS = ["one-device", "serving-mesh"]
+
+
+def _layout(request, layout):
+    return None if layout == "one-device" \
+        else request.getfixturevalue("serving_mesh")
+
+
+def _object_forest_roots(spec, ref) -> tuple:
+    from consensus_specs_tpu.utils.ssz.typing import List, uint64
+    return (hash_tree_root(ref.validator_registry, List[spec.Validator]),
+            hash_tree_root(ref.balances, List[uint64]))
+
+
+def _forest_roots_from_scratch(core) -> tuple:
+    """A root request with no forest standing: both built anew from the
+    columns the core holds, dispatch and wait in one place."""
+    core._reg_forest = core._bal_forest = core._big_roots = None
+    return tuple(bytes(r) for r in core._registry_balances_roots())
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_boundary_forest_roots_equal_a_build_from_scratch(spec, spans, request,
+                                                          layout):
+    """The forests a boundary dispatched ahead of its download are the
+    forests of the columns the epoch program returned: the two roots the
+    boundary's call has fetched by the time it returns equal a build from
+    scratch on the same columns and the object model's roots, two
+    boundaries running."""
+    spe = spec.SLOTS_PER_EPOCH
+    state = factories.seed_genesis_state(spec, 4 * spe)
+    ref, res = deepcopy(state), deepcopy(state)
+    core = ResidentCore(spec, res, mesh=_layout(request, layout))
+    try:
+        for boundary in (1, 2):
+            with core.suspended():
+                spec.process_slots(ref, boundary * spe)
+            core.process_slots(res, boundary * spe)
+            # fetched inside the boundary's call: nothing is left to wait
+            # for, no later slot pays for it
+            assert core._big_roots is not None
+            got = tuple(bytes(r) for r in core._big_roots)
+            assert got == _object_forest_roots(spec, ref)
+            assert got == _forest_roots_from_scratch(core)
+            assert hash_tree_root(ref) == core._state_root(res)
+        records = spans()
+    finally:
+        core.exit()
+    assert serialize(ref, spec.BeaconState) == serialize(res, spec.BeaconState)
+    waits = [r["args"] for r in records if r["name"] == "resident.forests"
+             and r["parent"] == "resident.refresh"]
+    assert len(waits) == 2
+    assert all(w["pair_lanes"] > 0 and w["ahead_ms"] > 0 for w in waits)
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_a_download_that_raises_leaves_the_next_root_correct(
+        spec, spans, request, monkeypatch, layout):
+    """A transfer that fails between the forests' dispatch and their wait:
+    the forests stand on the new columns, and the next request for their
+    roots fetches them as they are."""
+    import jax
+    from consensus_specs_tpu.telemetry import core as telemetry_core
+    spe = spec.SLOTS_PER_EPOCH
+    state = factories.seed_genesis_state(spec, 4 * spe)
+    ref, res = deepcopy(state), deepcopy(state)
+    core = ResidentCore(spec, res, mesh=_layout(request, layout))
+    device_get, raised = jax.device_get, []
+
+    def failing_once(tree):
+        stack = telemetry_core._stack()
+        if not raised and stack \
+                and stack[-1].name == "resident.refresh.download":
+            raised.append(True)
+            raise RuntimeError("transfer failed")
+        return device_get(tree)
+
+    try:
+        with core.suspended():
+            spec.process_slots(ref, spe)
+        monkeypatch.setattr(jax, "device_get", failing_once)
+        with pytest.raises(RuntimeError, match="transfer failed"):
+            core.process_slots(res, spe)
+        assert raised and telemetry_core._stack() == []
+        # dispatched, never waited for: nothing cached, both stand
+        assert core._big_roots is None
+        assert core._reg_forest is not None and core._bal_forest is not None
+        got = tuple(bytes(r) for r in core._registry_balances_roots())
+        assert got == _object_forest_roots(spec, ref)
+        assert got == _forest_roots_from_scratch(core)
+    finally:
+        core._uninstall()
 
 
 def test_slot_root_rehashes_only_what_was_written(spec, spans):
@@ -638,6 +744,9 @@ def test_checkpoint_round_trip_leaves_its_two_span_trees(spec, spans):
     # the resumed core's first root request builds the forests
     (forests,) = [r for r in records if r["name"] == "resident.forests"]
     assert forests["parent"] == "resident.slot_root.forests"
+    # in one place, with nothing to run under
+    assert forests["args"]["pair_lanes"] > 0
+    assert forests["args"]["ahead_ms"] == 0
 
 
 def test_corrupt_checkpoint_closes_its_spans(spec, spans):

@@ -243,8 +243,9 @@ def test_profiler_session_holds_the_resident_spans(spec, tmp_path):
         "resident.slot_root.merkleize", "resident.stage",
         "resident.stage.distill", "resident.stage.distill.place",
         "resident.stage.upload", "resident.device",
-        "resident.refresh", "resident.refresh.download",
-        "resident.refresh.final_updates", "resident.forests"}
+        "resident.refresh", "resident.refresh.forests_dispatch",
+        "resident.refresh.download", "resident.refresh.final_updates",
+        "resident.forests"}
     # the builders' spans carry no `resident.` prefix: one boundary, so one
     # of each part and three of each of the two passes
     parts = [e.name for e in reduce.annotations(planes, prefix="distill.")]
