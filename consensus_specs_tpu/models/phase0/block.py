@@ -257,8 +257,11 @@ def process_deposit(spec, state, deposit) -> None:
 
     pubkey = deposit.data.pubkey
     amount = deposit.data.amount
-    validator_pubkeys = [v.pubkey for v in state.validator_registry]
-    if pubkey not in validator_pubkeys:
+    # the registry through the state's view: a lookup of one key and an
+    # append or a top-up, each costing its own rows, never a scan
+    registry = spec.registry_view(state)
+    index = registry.index_of_pubkey(pubkey)
+    if index is None:
         # New validator: the deposit signature (proof of possession) must be
         # valid — but an invalid one just skips the deposit (the contract
         # can't filter them), it does not invalidate the block.
@@ -266,7 +269,7 @@ def process_deposit(spec, state, deposit) -> None:
                                    spec.bls_domain(spec.DOMAIN_DEPOSIT)):
             return
 
-        state.validator_registry.append(spec.Validator(
+        registry.append(spec.Validator(
             pubkey=pubkey,
             withdrawal_credentials=deposit.data.withdrawal_credentials,
             activation_eligibility_epoch=spec.FAR_FUTURE_EPOCH,
@@ -275,10 +278,9 @@ def process_deposit(spec, state, deposit) -> None:
             withdrawable_epoch=spec.FAR_FUTURE_EPOCH,
             effective_balance=min(amount - amount % spec.EFFECTIVE_BALANCE_INCREMENT,
                                   spec.MAX_EFFECTIVE_BALANCE),
-        ))
-        state.balances.append(amount)
+        ), amount)
     else:
-        spec.increase_balance(state, validator_pubkeys.index(pubkey), amount)
+        registry.increase_balance(index, amount)
 
 
 def process_voluntary_exit(spec, state, exit) -> None:

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import itertools
 import operator
+import threading
 from functools import partial
 from typing import NamedTuple
 
@@ -983,11 +984,30 @@ def _crosslink_winners(spec, state, ctx: EpochContext, epoch: int):
         return out
 
 
+_cumsum_scratch = threading.local()
+
+
 def _committee_balances(ctx: EpochContext, lay: _Layout) -> np.ndarray:
-    """[count] committee effective-balance sums via one cumsum (>=1 each)."""
+    """[count] committee effective-balance sums via one cumsum (>=1 each).
+
+    The gather and the cumsum run in ONE int64 buffer that the thread
+    keeps: four fresh V-row temporaries a call (gather, cast, cumsum,
+    concatenate; three calls a boundary) were given back to the system
+    and faulted in again or not as the heap happened to lie, 5 ms a call
+    or 12.5 at 1M, which made `epoch_boundary_s` read in steps."""
     with telemetry.span("distill.committee_balances"):
-        eff = ctx.np_cols["effective_balance"][lay.shuffled].astype(np.int64)
-        cs = np.concatenate([[0], np.cumsum(eff)])
+        # (uint64 Gwei, far below 2**63: read in place as the sums' type)
+        eff = ctx.np_cols["effective_balance"].view(np.int64)
+        n = len(lay.shuffled)
+        cs = getattr(_cumsum_scratch, "buf", None)
+        if cs is None or len(cs) <= n:
+            cs = _cumsum_scratch.buf = np.empty(n + 1, np.int64)
+        cs = cs[:n + 1]
+        cs[0] = 0
+        # (`clip`: with `raise` numpy gathers into a temporary of its own;
+        # a layout's rows are this registry's by construction)
+        np.take(eff, lay.shuffled, out=cs[1:], mode="clip")
+        np.cumsum(cs[1:], out=cs[1:])
         return np.maximum(cs[lay.bounds[1:]] - cs[lay.bounds[:-1]],
                           1).astype(np.uint64)
 

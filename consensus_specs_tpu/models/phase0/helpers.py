@@ -207,14 +207,23 @@ class ObjectRegistry:
     (`registry_view`), answered by an object state's validator list and
     balances: how many validators there are; one validator's `slashed`
     flag, pubkey, epochs and effective balance; the pubkeys of an index
-    set; the exit queue; and the writes of an exit, a slashing and a
-    balance move."""
+    set; the row that holds a pubkey; the exit queue; and the writes of an
+    exit, a slashing, a balance move and a deposit's new validator.
 
-    __slots__ = ("state", "far")
+    `pubkey_index` is the spec's one-entry memo of the last object
+    registry a deposit looked a key up in ([the list, pubkey -> first
+    row, how many rows are indexed]): the index is built once a registry
+    and caught up with rows appended since, so a deposit costs its own
+    row where the spec's text scans the registry."""
 
-    def __init__(self, state, far: int = 2 ** 64 - 1):
+    __slots__ = ("state", "far", "pubkey_index")
+
+    def __init__(self, state, far: int = 2 ** 64 - 1,
+                 pubkey_index: Optional[list] = None):
         self.state = state
         self.far = far
+        self.pubkey_index = [None, {}, 0] if pubkey_index is None \
+            else pubkey_index
 
     def __len__(self) -> int:
         return len(self.state.validator_registry)
@@ -228,6 +237,21 @@ class ObjectRegistry:
     def pubkeys(self, indices: Sequence[int]) -> List[bytes]:
         registry = self.state.validator_registry
         return [registry[i].pubkey for i in indices]
+
+    def index_of_pubkey(self, pubkey) -> Optional[int]:
+        """The first row whose validator has `pubkey`, None if none has."""
+        registry = self.state.validator_registry
+        memo = self.pubkey_index
+        if memo[0] is not registry or memo[2] > len(registry):
+            memo[:] = [registry, {}, 0]
+        for row in range(memo[2], len(registry)):
+            memo[1].setdefault(bytes(registry[row].pubkey), row)
+        memo[2] = len(registry)
+        return memo[1].get(bytes(pubkey))
+
+    def append(self, validator, amount: int) -> None:
+        self.state.validator_registry.append(validator)
+        self.state.balances.append(amount)
 
     def activation_epoch(self, index: int) -> int:
         return self.state.validator_registry[index].activation_epoch
@@ -278,7 +302,7 @@ def registry_view(spec, state):
     view = spec._registry_views.get(id(state))
     if view is not None and view.state is state:
         return view
-    return ObjectRegistry(state, spec.FAR_FUTURE_EPOCH)
+    return ObjectRegistry(state, spec.FAR_FUTURE_EPOCH, spec._pubkey_index)
 
 
 def get_churn_limit(spec, state) -> int:

@@ -38,32 +38,44 @@ small byte-rooted fields. This module makes that story real:
     forest's own programs over the zero-filled index column
     (`_active_index_root`): one upload, one node down, no pair of it
     hashed on the host.
-  * a block whose operations are attestations, voluntary exits and
-    proposer and attester slashings is processed on the resident state
-    (`process_block`): what the spec's block code reads and writes of the
-    registry (the proposer's `slashed` flag and pubkey, the pubkey sets of
-    indexed attestations, the registry's length; a validator's epochs and
-    effective balance, the exit queue's head; an exit's and a slashing's
-    rows, a slashing's balance moves) goes through the view the core
-    registers for its state (helpers.registry_view), so a
-    checkpoint-resumed (light) core, which has no Validator objects, takes
-    such a chain like an object-entered one. An operation costs its own
-    rows: the host mirrors take a write at once, the device columns take
-    the block's dirty rows when its last operation has passed
-    (`resident.registry_write`), both forests their dirty leaves' paths
-    (`resident.forests.update`: one program a forest), and the next slot's
-    root reads the forests' roots as they then are. A block the spec
-    rejects leaves columns, mirrors and forests as they were.
-  * blocks carrying deposits or transfers take the fallback (a light core
-    refuses them, having no objects to fall back to): exit residency (one
-    writeback),
-    process the block through the untouched object path, re-enter
-    INCREMENTALLY — the re-entry diffs the columns against the pre-block
-    snapshot, scatters only the changed rows back to device, and updates
-    the forests at leaf granularity (deposit growth append-grows them,
-    crossing padded powers of two included). Correctness is the object
-    path's by construction; the re-Merkleization cost is now proportional
-    to the block, not the registry.
+  * a block whose operations are attestations, deposits, voluntary exits
+    and proposer and attester slashings (everything but transfers) is
+    processed on the resident state (`process_block`): what the spec's
+    block code reads and writes of the registry (the proposer's `slashed`
+    flag and pubkey, the pubkey sets of indexed attestations, the
+    registry's length; a validator's epochs and effective balance, the
+    exit queue's head; an exit's and a slashing's rows, a slashing's
+    balance moves; a deposit's lookup of its pubkey, its top-up or its
+    new row) goes through the view the core registers for its state
+    (helpers.registry_view), so a checkpoint-resumed (light) core, which
+    has no Validator objects, takes such a chain like an object-entered
+    one. An operation costs its own rows: the host mirrors take a write at
+    once, the device columns take the block's dirty and appended rows when
+    its last operation has passed (`resident.registry_write`), both
+    forests their dirty and new leaves' paths (`resident.forests.update`:
+    one program a forest), and the next slot's root reads the forests'
+    roots as they then are. A block the spec rejects leaves the registry's
+    length, columns, mirrors, pubkey index and forests as they were.
+  * the registry has a CAPACITY: `capacity` rows of storage (an argument
+    of both entries; none given, it is the registry's length V and every
+    shape is V's) for the seven device columns, the identity matrices, the
+    host mirrors and identity copies, and both forests. The rows from V to
+    the capacity are inert in the columns (epoch_soa.inert_column_tail:
+    what the mesh's padding is) and zero chunks in the forests; V is a
+    host integer, mixed into the lists' roots on the host, and a traced
+    scalar where a device program needs it, never a shape. So a deposit
+    that appends a validator inside the capacity changes no shape and
+    compiles nothing; one that would pass it re-lays the core out at the
+    next power of two (`resident.registry.capacity_grown`: one re-layout
+    and its compiles).
+  * blocks carrying transfers take the fallback (a light core refuses
+    them, having no objects to fall back to): exit residency (one
+    writeback), process the block through the untouched object path,
+    re-enter INCREMENTALLY — the re-entry diffs the columns against the
+    pre-block snapshot, scatters only the changed rows back to device, and
+    updates the forests at leaf granularity. Correctness is the object
+    path's by construction; the re-Merkleization cost is proportional to
+    the block, not the registry.
 
 Reference semantics covered: per-slot root caching (0_beacon-chain.md
 :1173-1191), process_epoch ordering (:1251-1262), final updates
@@ -86,7 +98,8 @@ from ...ops.sha256 import words_to_bytes
 from ...resilience.errors import (CheckpointCorrupt, DispatchError,
                                   FatalDispatchError)
 from ...telemetry import watchdog as _watchdog
-from ...utils.merkle import tree_depth
+from ...utils.donation import platform_donated_jit
+from ...utils.merkle import next_power_of_two, tree_depth
 from ...utils.ssz import bulk, host_tree
 from ...utils.ssz import impl as ssz_impl
 from ...utils.ssz.incremental import (IncrementalMerkleTree,
@@ -135,12 +148,18 @@ _SLOT_ROOT_NOTES = {"pairs_hashed": bulk.HOST_PAIRS_HASHED,
 # Blocks that left the served path for the object model (_fallback_block).
 _BLOCK_FALLBACKS = telemetry.counter("resident.block.fallbacks", always=True)
 # The lists of a block's body that the resident state does not serve: a
-# deposit of a new validator changes V, the shape of every device column
-# and forest, and a transfer is cut from the preset (MAX_TRANSFERS 0). A
-# block that carries one takes the object model on an object-entered core;
-# a checkpoint-resumed core, which has no objects to fall back to, refuses
-# it. Exits and both kinds of slashing are served (process_block).
-_UNSERVED_OPERATIONS = ("deposits", "transfers")
+# transfer is cut from the preset (MAX_TRANSFERS 0). A block that carries
+# one takes the object model on an object-entered core; a
+# checkpoint-resumed core, which has no objects to fall back to, refuses
+# it. Deposits, exits and both kinds of slashing are served (process_block).
+_UNSERVED_OPERATIONS = ("transfers",)
+# Re-layouts at a larger capacity (_grow_capacity): each is a new shape for
+# every program of the serving path.
+_CAPACITY_GROWN = telemetry.counter("resident.registry.capacity_grown",
+                                    always=True)
+# A block's appended rows go to the device in one bucket of this size
+# (MAX_DEPOSITS of both presets), so how many a block appended is no shape.
+_APPEND_BUCKET = 16
 
 # Per-core watchdog key prefix: layout fingerprints must not leak between
 # cores (a mesh core and a single-device core in one test process would
@@ -168,18 +187,6 @@ def light_state_from_bytes(spec, data: bytes):
     return state
 
 
-def _balance_chunk_words_np(bal: np.ndarray, chunk_idx: np.ndarray) -> np.ndarray:
-    """[k, 8] words of the balances list's SSZ pack chunks at `chunk_idx`
-    (4 uint64 per 32-byte chunk, zero-padded past the list end)."""
-    from ...ops.sha256 import bytes_to_words
-    n = bal.shape[0]
-    k = chunk_idx.shape[0]
-    pos = np.asarray(chunk_idx, np.int64)[:, None] * 4 + np.arange(4)[None, :]
-    vals = np.where(pos < n, bal[np.minimum(pos, max(n - 1, 0))], np.uint64(0))
-    chunks = vals.astype("<u8").view(np.uint8).reshape(k, 32)
-    return bytes_to_words(chunks)
-
-
 class _BlockWrites:
     """What the operations of one served block have written through the
     registry view so far: the `withdrawable_epoch` an exit or a slashing
@@ -187,27 +194,34 @@ class _BlockWrites:
     registry rows: the mirrors have their `exit_epoch` and `slashed`
     already, the device columns get all three when the block's last
     operation has passed), the balance moves in the order they were made,
-    and what it takes to put the mirrors and the exit queue back if the
-    block is rejected."""
+    the rows its deposits appended (the registry's length, the mirrors,
+    the host identity copies and the pubkey index have them already; the
+    device columns and the forests get them with the dirty rows), and
+    what it takes to put the length, the mirrors and the exit queue back
+    if the block is rejected."""
 
-    __slots__ = ("withdrawable", "balance_moves", "undo", "exit_queue")
+    __slots__ = ("withdrawable", "balance_moves", "undo", "exit_queue",
+                 "v0", "appended")
 
-    def __init__(self, exit_queue):
+    def __init__(self, exit_queue, v0: int):
         self.withdrawable: dict = {}     # validator -> withdrawable_epoch
         self.balance_moves: list = []    # (index, up, down)
         self.undo: list = []             # (mirror, index, the value it had)
         self.exit_queue = None if exit_queue is None else list(exit_queue)
+        self.v0 = v0                     # the registry's length at the open
+        self.appended: list = []         # the new rows' first balances
 
     @property
     def dirty(self) -> bool:
-        return bool(self.balance_moves or self.withdrawable)
+        return bool(self.balance_moves or self.withdrawable or self.appended)
 
 
 class _ColumnsRegistry:
     """`helpers.registry_view` for a resident core's own state: the reads
     and writes block processing makes of the registry, answered by the
-    host mirrors, the core's host copy of the resident pubkeys (identity
-    columns never change while resident) and, for what has no mirror (a
+    host mirrors, the core's host copy of the resident pubkeys (an identity
+    row never changes while resident; a deposit appends one) and, for what
+    has no mirror (a
     validator's `withdrawable_epoch`, the balances), the device columns a
     few rows at a time, on one device and on a mesh alike. A light core's
     state has no validator list to answer them. Every read and write
@@ -242,6 +256,12 @@ class _ColumnsRegistry:
     def pubkeys(self, indices) -> list:
         rows = self._core._pk_np[np.asarray(indices, np.int64)]
         return [row.tobytes() for row in rows]
+
+    def index_of_pubkey(self, pubkey) -> Optional[int]:
+        return self._core._pubkey_lookup().get(bytes(pubkey))
+
+    def append(self, validator, amount: int) -> None:
+        self._core._append_row(validator, int(amount))
 
     def activation_epoch(self, index: int) -> int:
         return int(self._core.mirrors["activation_epoch"][self._row(index)])
@@ -288,6 +308,46 @@ def _leaves_at_traced(pk_rows, wc_rows, elig, act, exit_ep, withdrawable,
         withdrawable[idx], slashed[idx], eff[idx], unroll=unroll)
 
 
+def _masked_leaves_traced(pk, wc, elig, act, exit_ep, withdrawable, slashed,
+                          eff, count):
+    """Level 0 of the registry forest of a core with room to grow: the
+    validator roots of the rows below `count` (the logical length, traced),
+    zero chunks from there to the capacity: an inert row's root is a hash,
+    the SSZ list's padding is zero."""
+    import jax.numpy as jnp
+    leaves = bulk._registry_leaf_words(pk, wc, elig, act, exit_ep,
+                                       withdrawable, slashed, eff)
+    rows = jnp.arange(leaves.shape[0], dtype=jnp.int32)[:, None]
+    return jnp.where(rows < count, leaves, jnp.uint32(0))
+
+
+def _pending_activations_traced(elig, act, far):
+    """Rows with an eligibility epoch and no activation epoch: the
+    activation queue as a boundary finds it."""
+    import jax.numpy as jnp
+    return jnp.sum(((elig != far) & (act == far)).astype(jnp.int32))
+
+
+def _append_rows_traced(pk, wc, cols, idx, pk_rows, wc_rows, eff_rows,
+                        balance_rows, far):
+    """A block's appended validators into the free rows `idx` (a bucket of
+    _APPEND_BUCKET: the last repeated) of the identity matrices and the
+    seven columns, one dispatch: never eligible, active, exiting or
+    withdrawable yet, not slashed, the deposit's effective balance and
+    amount."""
+    import jax.numpy as jnp
+    epochs = jnp.full(idx.shape, far, dtype=cols.exit_epoch.dtype)
+    return pk.at[idx].set(pk_rows), wc.at[idx].set(wc_rows), ValidatorColumns(
+        activation_eligibility_epoch=cols.activation_eligibility_epoch
+        .at[idx].set(epochs),
+        activation_epoch=cols.activation_epoch.at[idx].set(epochs),
+        exit_epoch=cols.exit_epoch.at[idx].set(epochs),
+        withdrawable_epoch=cols.withdrawable_epoch.at[idx].set(epochs),
+        slashed=cols.slashed.at[idx].set(False),
+        effective_balance=cols.effective_balance.at[idx].set(eff_rows),
+        balance=cols.balance.at[idx].set(balance_rows))
+
+
 def _write_rows_traced(exit_ep, withdrawable, slashed, idx, exit_rows,
                        withdrawable_rows, slashed_rows):
     """A block's dirty registry rows into the three columns its operations
@@ -323,6 +383,11 @@ _leaves_at = jax.jit(_leaves_at_traced, static_argnames=("unroll",))
 _write_rows = jax.jit(_write_rows_traced)
 _balance_chunks_at = jax.jit(_balance_chunks_at_traced)
 _move_balance = jax.jit(_move_balance_traced)
+_masked_leaves = jax.jit(_masked_leaves_traced)
+_pending_activations = jax.jit(_pending_activations_traced)
+# the identity matrices are donated off the CPU (80 MB at 1M, rewritten in
+# place), the columns are not (`_write_rows`' reason: 8 MB a column)
+_append_rows = platform_donated_jit(_append_rows_traced, donate_argnums=(0, 1))
 
 
 def _serving_mesh(mesh):
@@ -346,9 +411,13 @@ class ResidentCore:
     with a replicated cap tree, and every jitted program dispatches with
     matched in/out shardings so chained slot and epoch steps never
     re-lay-out. Roots and serialized states stay bit-identical to the
-    single-device core (tests/test_resident.py)."""
+    single-device core (tests/test_resident.py).
 
-    def __init__(self, spec, state, mesh="env"):
+    `capacity` is the rows of storage the registry is laid out with (the
+    module docstring's CAPACITY): at least its length, which it is when
+    none is given."""
+
+    def __init__(self, spec, state, mesh="env", capacity: int = None):
         if spec._insert_after_registry_updates or spec._insert_after_final_updates:
             raise NotImplementedError(
                 "resident mode covers the phase-0 fused epoch program; "
@@ -364,13 +433,14 @@ class ResidentCore:
         self._host_trees: Dict[tuple, object] = {}
         self._light = False
         self._writes: Optional[_BlockWrites] = None
+        self._capacity = int(capacity or 0)
         self._enter(state)
 
     # -- residency lifecycle ------------------------------------------------
 
     @classmethod
-    def from_checkpoint(cls, spec, state_bytes: bytes,
-                        mesh="env") -> "ResidentCore":
+    def from_checkpoint(cls, spec, state_bytes: bytes, mesh="env",
+                        capacity: int = None) -> "ResidentCore":
         """Resume a serialized BeaconState straight into residency without
         materializing the registry: the big fields parse as strided-view
         columns (utils/ssz/columns.py), everything else deserializes into
@@ -381,22 +451,32 @@ class ResidentCore:
         live as objects.
 
         A light-resident core drives slots, epoch boundaries and blocks
-        whose operations are attestations, voluntary exits and proposer
-        and attester slashings (state_transition / process_block: the
-        registry is read and written through the core's view: the mirrors,
-        the resident pubkeys, the device columns' dirty rows); a block
-        that carries a deposit or a transfer, and exit(), need the object
-        registry and are the standard entry's job.
+        whose operations are anything but transfers: attestations,
+        deposits, voluntary exits and proposer and attester slashings
+        (state_transition / process_block: the registry is read and
+        written through the core's view: the mirrors, the resident
+        pubkeys, the device columns' dirty and appended rows); a block
+        that carries a transfer, and exit(), need the object registry and
+        are the standard entry's job.
+
+        `capacity` is the rows of storage the registry is laid out with:
+        a deposit that appends a validator inside it changes no shape and
+        compiles nothing, one that would pass it re-lays the core out at
+        the next power of two. None given, it is the checkpoint's own
+        length, and the first new validator is such a re-layout. A core
+        resumed from `checkpoint_bytes()` with the live core's capacity
+        gives the live core's roots.
 
         Truncated or garbage bytes raise the TYPED `CheckpointCorrupt`
         (resilience/errors.py) up front — never an opaque struct/index
         error from deep inside the offset-grammar walkers — so the
         checkpoint store's generation fallback can branch on type."""
         with telemetry.span("resident.restore"):
-            return cls._from_checkpoint(spec, state_bytes, mesh)
+            return cls._from_checkpoint(spec, state_bytes, mesh, capacity)
 
     @classmethod
-    def _from_checkpoint(cls, spec, state_bytes, mesh) -> "ResidentCore":
+    def _from_checkpoint(cls, spec, state_bytes, mesh,
+                         capacity) -> "ResidentCore":
         if spec._insert_after_registry_updates or spec._insert_after_final_updates:
             raise NotImplementedError(
                 "resident mode covers the phase-0 fused epoch program; "
@@ -440,13 +520,13 @@ class ResidentCore:
         core._host_trees = {}
         core._light = True
         core._writes = None
+        core._capacity = int(capacity or 0)
         with telemetry.span("resident.restore.upload") as sp:
             core._enter(state, np_cols=np_cols)
             sp.fence(core.cols, core.pk_dev)    # the uploads have landed
         return core
 
     def _enter(self, state, np_cols: Optional[dict] = None) -> None:
-        import jax.numpy as jnp
         self.state = state
         if np_cols is None:
             np_cols = dict(columns_np_from_state(state))
@@ -458,35 +538,14 @@ class ResidentCore:
                 wc[i] = np.frombuffer(bytes(v.withdrawal_credentials), np.uint8)
             np_cols["pubkey"] = pk
             np_cols["withdrawal_credentials"] = wc
-        self.mirrors: Dict[str, np.ndarray] = {
-            f: np_cols[f].copy() for f in _MIRROR_FIELDS}
-        # _v is the LOGICAL validator count; under a serving mesh the
-        # device columns pad to the next mesh multiple with inert rows
+        # _v is the LOGICAL validator count; the storage has _capacity rows
+        # (the entry's argument, V at least), inert from V on
         self._v = int(np_cols["balance"].shape[0])
-        cols = ValidatorColumns(
-            **{f: jnp.asarray(np_cols[f]) for f in _ALL_FIELDS})
-        # identity columns never change while resident: keep host copies
-        # for the checkpoint WRITE path alongside the device uploads
-        self._pk_np = np.asarray(np_cols["pubkey"])
-        self._wc_np = np.asarray(np_cols["withdrawal_credentials"])
-        if self._mesh is not None:
-            import jax
-            vp = self._mesh.pad_rows(self._v)
-            self.cols = jax.device_put(
-                pad_validator_columns(cols, vp,
-                                      int(self.spec.FAR_FUTURE_EPOCH)),
-                self._mesh.shard_v)
-            pad = np.zeros((vp - self._v, 48), np.uint8)
-            self.pk_dev = jax.device_put(
-                jnp.asarray(np.concatenate([self._pk_np, pad])),
-                self._mesh.shard_v)
-            self.wc_dev = jax.device_put(
-                jnp.asarray(np.concatenate([self._wc_np, pad[:, :32]])),
-                self._mesh.shard_v)
-        else:
-            self.cols = cols
-            self.pk_dev = jnp.asarray(self._pk_np)
-            self.wc_dev = jnp.asarray(self._wc_np)
+        self._capacity = max(self._capacity, self._v)
+        self._place_host(np_cols)
+        self._upload(np_cols)
+        # pubkey -> row, built when a deposit first asks (_pubkey_lookup)
+        self._pubkey_index: Optional[dict] = None
         self._big_roots: Optional[tuple] = None
         # Per-column incremental Merkle forests (utils/ssz/incremental.py),
         # built lazily on the first root request; a fresh entry cannot reuse
@@ -499,6 +558,57 @@ class ResidentCore:
         # other than a served exit may have moved it
         self._exit_queue: Optional[list] = None
         self._install()
+
+    def _padded(self, field: str, rows: np.ndarray, total: int) -> np.ndarray:
+        """`rows` (the logical ones of one column or identity matrix)
+        with the inert tail up to `total` rows; itself when it has them."""
+        k = total - rows.shape[0]
+        if k == 0:
+            return rows
+        tail = (np.zeros((k,) + rows.shape[1:], rows.dtype)
+                if field in ("pubkey", "withdrawal_credentials")
+                else inert_column_tail(field, k,
+                                       int(self.spec.FAR_FUTURE_EPOCH)))
+        return np.concatenate([rows, tail.astype(rows.dtype)])
+
+    def _place_host(self, np_cols: Dict[str, np.ndarray]) -> None:
+        """The host's part of a layout, `_capacity` rows each: the mirrors
+        of the columns the spec's host logic reads, and the identity
+        copies (they serve the checkpoint WRITE path and the block path's
+        pubkey reads alongside the device uploads)."""
+        self.mirrors: Dict[str, np.ndarray] = {
+            f: self._padded(f, np_cols[f], self._capacity).copy()
+            for f in _MIRROR_FIELDS}
+        self._pk_np = self._padded("pubkey", np.asarray(np_cols["pubkey"]),
+                                   self._capacity)
+        self._wc_np = self._padded(
+            "withdrawal_credentials",
+            np.asarray(np_cols["withdrawal_credentials"]), self._capacity)
+
+    def _device_rows(self) -> int:
+        """The rows the device's columns have: the capacity, under a
+        serving mesh up to the next mesh multiple."""
+        return (self._capacity if self._mesh is None
+                else self._mesh.pad_rows(self._capacity))
+
+    def _put(self, tree):
+        """Arrays of `_device_rows` rows onto the device, where the
+        layout has them: the default device, or sharded by row."""
+        import jax.numpy as jnp
+        if self._mesh is None:
+            return jax.tree_util.tree_map(jnp.asarray, tree)
+        return jax.device_put(tree, self._mesh.shard_v)
+
+    def _upload(self, np_cols: Dict[str, np.ndarray]) -> None:
+        """The device's part of a layout: the seven columns and the
+        identity matrices at `_device_rows`, inert from the logical rows
+        on."""
+        rows = self._device_rows()
+        self.cols = self._put(ValidatorColumns(
+            **{f: self._padded(f, np_cols[f], rows) for f in _ALL_FIELDS}))
+        self.pk_dev = self._put(self._padded("pubkey", self._pk_np, rows))
+        self.wc_dev = self._put(self._padded("withdrawal_credentials",
+                                             self._wc_np, rows))
 
     def exit(self):
         """Materialize the device columns back into the object state and
@@ -523,17 +633,24 @@ class ResidentCore:
         return self.state
 
     def _write_back(self, np_cols: Dict[str, np.ndarray]) -> None:
-        """The columns into the object state's registry and balances.
-        `_apply_validator_columns` leaves `slashed` out (the epoch program
-        never writes it); a served slashing does, on the columns."""
+        """The columns into the object state's registry and balances: a
+        Validator for every row that served deposits appended, then the
+        numbers. `_apply_validator_columns` leaves `slashed` out (the
+        epoch program never writes it); a served slashing does, on the
+        columns."""
+        registry = self.state.validator_registry
+        for i in range(len(registry), self._v):
+            registry.append(self.spec.Validator(
+                pubkey=self._pk_np[i].tobytes(),
+                withdrawal_credentials=self._wc_np[i].tobytes()))
         _apply_validator_columns(self.state, ValidatorColumns(**np_cols))
         for i in np.nonzero(np_cols["slashed"])[0]:
             self.state.validator_registry[int(i)].slashed = True
 
     def _materialize_np_cols(self) -> Dict[str, np.ndarray]:
         """One download of the device columns as a host dict (sliced back
-        to the logical validator count — the inert padding rows of the
-        sharded layout never reach host consumers)."""
+        to the logical validator count — the inert rows of the capacity
+        and of the sharded layout never reach host consumers)."""
         cols = jax.device_get(self.cols)
         return {f: np.asarray(getattr(cols, f))[:self._v]
                 for f in _ALL_FIELDS}
@@ -550,8 +667,8 @@ class ResidentCore:
             with telemetry.span("resident.checkpoint_write.download"):
                 np_cols = self._materialize_np_cols()
             with telemetry.span("resident.checkpoint_write.assemble"):
-                np_cols["pubkey"] = self._pk_np
-                np_cols["withdrawal_credentials"] = self._wc_np
+                np_cols["pubkey"] = self._pk_np[:self._v]
+                np_cols["withdrawal_credentials"] = self._wc_np[:self._v]
                 return state_bytes_from_columns(self.state, np_cols,
                                                 self.spec)
 
@@ -576,10 +693,10 @@ class ResidentCore:
         longer includes a full re-Merkleization. Re-entry diffs the columns
         the block changed against the pre-block snapshot, scatters only
         those rows into the device columns, and re-hashes only the touched
-        validators' root paths in the incremental forests — a slashing or
-        exit that moves a handful of validators costs O(dirty * log V)
-        compressions, not the ~2M-leaf rebuild the old all-or-nothing
-        `_big_roots` cache forced."""
+        validators' root paths in the incremental forests — a transfer
+        that moves three balances costs O(dirty * log V) compressions,
+        not the ~2M-leaf rebuild the old all-or-nothing `_big_roots`
+        cache forced."""
         _BLOCK_FALLBACKS.inc()
         old_np = self._materialize_np_cols()
         try:
@@ -592,118 +709,41 @@ class ResidentCore:
     def _reenter_incremental(self, state, old_np: Dict[str, np.ndarray]) -> None:
         """Resume residency after an object-path block by diffing columns
         against the pre-block snapshot: changed rows scatter into the device
-        columns, appended validators (deposits) extend them, and the forests
-        invalidate at leaf granularity (append-grow included)."""
+        columns and the forests invalidate at leaf granularity. The block
+        carried transfers and no deposit the object path could take for a
+        new validator (those are served), so the registry is as long as it
+        was."""
         import jax.numpy as jnp
         self.state = state
         np_cols = dict(columns_np_from_state(state))
-        old_n = old_np["balance"].shape[0]
-        new_n = np_cols["balance"].shape[0]
-        grown = new_n - old_n
-        assert grown >= 0, "the registry never shrinks (spec invariant)"
-        if grown:
-            pk_new = np.zeros((grown, 48), np.uint8)
-            wc_new = np.zeros((grown, 32), np.uint8)
-            for i, v in enumerate(state.validator_registry[old_n:]):
-                pk_new[i] = np.frombuffer(bytes(v.pubkey), np.uint8)
-                wc_new[i] = np.frombuffer(bytes(v.withdrawal_credentials),
-                                          np.uint8)
-            self._pk_np = np.concatenate([self._pk_np, pk_new])
-            self._wc_np = np.concatenate([self._wc_np, wc_new])
-            # upload only the appended rows and concatenate ON DEVICE — a
-            # one-validator deposit must not re-upload the ~80 MB identity
-            # matrices of a 1M-validator registry. Under the serving mesh
-            # the rows SCATTER into the existing inert padding slots
-            # instead (zero upload beyond the rows themselves); only a
-            # capacity crossing concatenates and re-places.
-            if self._mesh is not None:
-                zeros = lambda k, w: np.zeros((k, w), np.uint8)  # noqa: E731
-                self.pk_dev = self._grow_sharded(
-                    self.pk_dev, pk_new, old_n, lambda k: zeros(k, 48))
-                self.wc_dev = self._grow_sharded(
-                    self.wc_dev, wc_new, old_n, lambda k: zeros(k, 32))
-            else:
-                self.pk_dev = jnp.concatenate(
-                    [self.pk_dev, jnp.asarray(pk_new)])
-                self.wc_dev = jnp.concatenate(
-                    [self.wc_dev, jnp.asarray(wc_new)])
-        far = int(self.spec.FAR_FUTURE_EPOCH)
+        assert np_cols["balance"].shape[0] == self._v, \
+            "an object-path block changed the registry's length"
         dirty: Dict[str, np.ndarray] = {}
         new_cols = {}
         for f in _ALL_FIELDS:
-            new = np_cols[f]
-            idx = np.nonzero(new[:old_n] != old_np[f])[0]
+            idx = np.nonzero(np_cols[f] != old_np[f])[0]
             dirty[f] = idx
             dev = getattr(self.cols, f)
             if idx.size:
                 dev = dev.at[jnp.asarray(idx.astype(np.int32))].set(
-                    jnp.asarray(new[idx]))
-            if grown:
-                if self._mesh is not None:
-                    dev = self._grow_sharded(
-                        dev, new[old_n:], old_n,
-                        lambda k, _f=f: inert_column_tail(_f, k, far))
-                else:
-                    dev = jnp.concatenate([dev, jnp.asarray(new[old_n:])])
+                    jnp.asarray(np_cols[f][idx]))
             new_cols[f] = dev
         self.cols = ValidatorColumns(**new_cols)
-        self._v = new_n
-        self.mirrors = {f: np_cols[f].copy() for f in _MIRROR_FIELDS}
+        for f in _MIRROR_FIELDS:
+            self.mirrors[f] = self._padded(f, np_cols[f], self._capacity).copy()
         self._active_idx_memo.clear()
         self._exit_queue = None
-        self._update_forests(np_cols, old_n, dirty)
+        self._update_forest_paths(
+            np.unique(np.concatenate([dirty[f] for f in self._LEAF_FIELDS])),
+            np.unique(dirty["balance"] // 4))
         self._big_roots = None
         self._install()
-
-    def _grow_sharded(self, dev, rows_np, old_n: int, tail_fn):
-        """Grow one padded sharded column from logical `old_n` to
-        `old_n + len(rows_np)`: scatter the new rows into the inert
-        padding slots; when the padded capacity itself must reach the
-        next mesh multiple, extend with `tail_fn(k)` inert rows and
-        re-place — the only step that re-lays-out, and it happens once
-        per mesh-multiple of growth, not per deposit."""
-        import jax
-        import jax.numpy as jnp
-        new_n = old_n + int(rows_np.shape[0])
-        vp_new = self._mesh.pad_rows(new_n)
-        if vp_new > int(dev.shape[0]):
-            tail = jnp.asarray(tail_fn(vp_new - int(dev.shape[0])))
-            dev = jax.device_put(jnp.concatenate([dev, tail]),
-                                 self._mesh.shard_v)
-        idx = jnp.asarray(np.arange(old_n, new_n, dtype=np.int32))
-        return dev.at[idx].set(jnp.asarray(rows_np))
 
     # registry-leaf fields: everything the Validator container Merkleizes
     # except the separate balances list (pubkey/wc never change in place)
     _LEAF_FIELDS = ("activation_eligibility_epoch", "activation_epoch",
                     "exit_epoch", "withdrawable_epoch", "slashed",
                     "effective_balance")
-
-    def _update_forests(self, np_cols: Dict[str, np.ndarray], old_n: int,
-                        dirty: Dict[str, np.ndarray]) -> None:
-        """Leaf-granularity forest invalidation after an object-path block:
-        the touched validators' leaves and balance chunks take the served
-        path's `_update_forest_paths` (from the device columns, which have
-        the block's rows already); leaves and chunks of registry growth are
-        appended — the append-grow path crosses padded powers of two
-        exactly like utils/ssz/incremental.py's tests."""
-        new_n = np_cols["balance"].shape[0]
-        chunks = dirty["balance"] // 4
-        if new_n > old_n and old_n % 4:
-            # growth refills the old partial tail chunk in place
-            chunks = np.concatenate([chunks, [old_n // 4]])
-        self._update_forest_paths(
-            np.unique(np.concatenate([dirty[f] for f in self._LEAF_FIELDS])),
-            np.unique(chunks))
-        if self._reg_forest is not None and new_n > old_n:
-            self._reg_forest.append(self._registry_leaf_words_np(
-                np_cols, np.arange(old_n, new_n)))
-        if self._bal_forest is not None:
-            old_c = max(1, -(-old_n // 4))
-            new_c = max(1, -(-new_n // 4))
-            if new_c > old_c:
-                self._bal_forest.append(_balance_chunk_words_np(
-                    np_cols["balance"], np.arange(old_c, new_c)))
 
     def _update_forest_paths(self, rows: np.ndarray,
                              chunks: np.ndarray) -> None:
@@ -713,8 +753,11 @@ class ResidentCore:
         and their root paths re-hashed, ONE program a forest
         (IncrementalMerkleTree.update_bucket). Each dirty set is padded to
         a bucket (`bucket_indices`), so whatever a block dirties meets the
-        programs the first block compiled. Nothing comes back: the next
-        root request fetches the roots as they then are."""
+        programs the first block compiled; rows a block's deposits
+        appended are dirty leaves like any other (every index is below
+        the registry's length as it now is, which the forests take as
+        their lists' new lengths). Nothing comes back: the next root
+        request fetches the roots as they then are."""
         unroll = jax.default_backend() != "cpu"     # sha256._unroll_for's reason
         if self._reg_forest is not None and len(rows):
             idx = bucket_indices(rows)
@@ -723,29 +766,14 @@ class ResidentCore:
                 self._pk_np[idx], self._wc_np[idx],
                 c.activation_eligibility_epoch, c.activation_epoch,
                 c.exit_epoch, c.withdrawable_epoch, c.slashed,
-                c.effective_balance, idx, unroll=unroll))
+                c.effective_balance, idx, unroll=unroll), logical_n=self._v)
             self._big_roots = None
         if self._bal_forest is not None and len(chunks):
             idx = bucket_indices(chunks)
             self._bal_forest.update_bucket(idx, _balance_chunks_at(
-                self.cols.balance, idx, np.int32(self._v)))
+                self.cols.balance, idx, np.int32(self._v)),
+                logical_n=-(-self._v // 4))
             self._big_roots = None
-
-    def _registry_leaf_words_np(self, np_cols: Dict[str, np.ndarray],
-                                idx: np.ndarray):
-        """[k, 8] word leaves (validator hash_tree_roots) for a small index
-        set, computed host-side from the post-block columns."""
-        from ...ops.sha256 import bytes_to_words
-        leaves = bulk.validator_leaf_chunks(
-            self._pk_np[idx], self._wc_np[idx],
-            np_cols["activation_eligibility_epoch"][idx],
-            np_cols["activation_epoch"][idx],
-            np_cols["exit_epoch"][idx],
-            np_cols["withdrawable_epoch"][idx],
-            np_cols["slashed"][idx],
-            np_cols["effective_balance"][idx])
-        roots = bulk.subtree_roots_batch(leaves)
-        return bytes_to_words(np.ascontiguousarray(roots))
 
     # -- the registry view's writes (a served block's operations) -------------
 
@@ -759,11 +787,15 @@ class ResidentCore:
                 "columns and the forests")
         return self._writes
 
-    def _mirror_set(self, writes: _BlockWrites, field: str, index: int,
-                    value) -> None:
+    def _writable_mirror(self, field: str) -> np.ndarray:
         mirror = self.mirrors[field]
         if not mirror.flags.writeable:      # a column as device_get left it
             mirror = self.mirrors[field] = mirror.copy()
+        return mirror
+
+    def _mirror_set(self, writes: _BlockWrites, field: str, index: int,
+                    value) -> None:
+        mirror = self._writable_mirror(field)
         writes.undo.append((field, index, mirror[index]))
         mirror[index] = value
 
@@ -819,18 +851,116 @@ class ResidentCore:
     def _move_balance(self, index: int, up: int, down: int) -> None:
         self._open_writes().balance_moves.append((index, up, down))
 
+    def _pubkey_lookup(self) -> dict:
+        """pubkey -> row (the first that holds it, as the spec's `.index`
+        finds it): built when a deposit first asks, never at entry (a
+        million 48-byte keys at every restore would sit inside
+        `restore_s`), and kept in step by appends and rollbacks."""
+        if self._pubkey_index is None:
+            with telemetry.span("resident.registry.pubkey_index") as sp:
+                v = self._v
+                keys = np.ascontiguousarray(self._pk_np[:v]).tobytes()
+                index = {keys[48 * i:48 * i + 48]: i
+                         for i in range(v - 1, -1, -1)}
+                self._pubkey_index = index
+                sp.note(rows=v, keys=len(index))
+        return self._pubkey_index
+
+    def _append_row(self, validator, amount: int) -> None:
+        """A deposit's new validator into the next free row of the host's
+        part of the layout (mirrors, identity copies, pubkey index), the
+        registry one longer at once: the block's next operation finds it.
+        The device's part follows with the block's other writes
+        (`_commit_writes`)."""
+        writes = self._open_writes()
+        row = self._v
+        if row == self._capacity:
+            self._grow_capacity(row + 1)
+        pubkey = bytes(validator.pubkey)
+        for field in _MIRROR_FIELDS:
+            self._writable_mirror(field)[row] = getattr(validator, field)
+        # (a free row is the identity copies' own: `_padded` made it)
+        self._pk_np[row] = np.frombuffer(pubkey, np.uint8)
+        self._wc_np[row] = np.frombuffer(
+            bytes(validator.withdrawal_credentials), np.uint8)
+        self._pubkey_lookup().setdefault(pubkey, row)
+        writes.appended.append(amount)
+        self._v = row + 1
+
+    def _grow_capacity(self, rows: int) -> None:
+        """Re-lay the core out with room for `rows`: the next power of two
+        of storage, the host's part padded where it stands (it may hold an
+        open block's writes), the device's columns and identity matrices
+        padded on the device and put where they lie, both forests dropped
+        (the next root request builds them at the new capacity, a power of
+        two crossed deepening them). Every program of the serving path
+        meets a new shape: one re-layout and its compiles, counted."""
+        import jax.numpy as jnp
+        _CAPACITY_GROWN.inc()
+        self._capacity = next_power_of_two(rows)
+        far = int(self.spec.FAR_FUTURE_EPOCH)
+        for f in _MIRROR_FIELDS:
+            self.mirrors[f] = self._padded(f, self.mirrors[f], self._capacity)
+        self._pk_np = self._padded("pubkey", self._pk_np, self._capacity)
+        self._wc_np = self._padded("withdrawal_credentials", self._wc_np,
+                                   self._capacity)
+        total = self._device_rows()
+        grow = total - int(self.pk_dev.shape[0])
+        self.cols = self._put(pad_validator_columns(self.cols, total, far))
+        self.pk_dev = self._put(jnp.concatenate(
+            [self.pk_dev, jnp.zeros((grow, 48), self.pk_dev.dtype)]))
+        self.wc_dev = self._put(jnp.concatenate(
+            [self.wc_dev, jnp.zeros((grow, 32), self.wc_dev.dtype)]))
+        self._reg_forest = self._bal_forest = self._big_roots = None
+        self._active_idx_memo.clear()
+        # a deliberate re-placement, reported by its own counter
+        for key in (f"{self._tkey}.epoch.cols", f"{self._tkey}.forest.reg.l0",
+                    f"{self._tkey}.forest.bal.l0"):
+            _watchdog.forget(key)
+
     def _roll_back_writes(self, writes: _BlockWrites) -> None:
-        """A rejected block: the mirrors and the exit queue as they were
-        (nothing had reached the device columns or the forests)."""
+        """A rejected block: the mirrors, the exit queue and the
+        registry's length as they were, the rows it appended inert again
+        and out of the pubkey index (nothing had reached the device
+        columns or the forests)."""
         for field, index, value in reversed(writes.undo):
             self.mirrors[field][index] = value
+        if self._v > writes.v0:
+            far = int(self.spec.FAR_FUTURE_EPOCH)
+            for row in range(writes.v0, self._v):
+                key = self._pk_np[row].tobytes()
+                if self._pubkey_index.get(key) == row:
+                    del self._pubkey_index[key]
+            k = self._v - writes.v0
+            for field in _MIRROR_FIELDS:
+                self.mirrors[field][writes.v0:self._v] = \
+                    inert_column_tail(field, k, far)
+            self._pk_np[writes.v0:self._v] = 0
+            self._wc_np[writes.v0:self._v] = 0
+            self._v = writes.v0
         self._exit_queue = writes.exit_queue
         self._active_idx_memo.clear()
 
     def _commit_writes(self, writes: _BlockWrites) -> None:
-        """The block's dirty rows into the device columns (the mirrors have
-        them), then the dirty leaves and chunks into both forests."""
+        """The block's appended and dirty rows into the device columns (the
+        mirrors have them), then the new and dirty leaves and chunks into
+        both forests."""
+        new_rows = list(range(writes.v0, self._v))
         with telemetry.span("resident.registry_write") as sp:
+            if new_rows:
+                # the free rows they take, one bucket, one dispatch for the
+                # seven columns and the identity matrices
+                idx = bucket_indices(np.asarray(new_rows, np.int64),
+                                     floor=_APPEND_BUCKET)
+                amounts = np.asarray(writes.appended, np.uint64)[idx - writes.v0]
+                self.pk_dev, self.wc_dev, self.cols = _append_rows(
+                    self.pk_dev, self.wc_dev, self.cols, idx,
+                    self._pk_np[idx], self._wc_np[idx],
+                    self.mirrors["effective_balance"][idx], amounts,
+                    np.uint64(int(self.spec.FAR_FUTURE_EPOCH)))
+                if self._mesh is not None:      # where they lie
+                    self.pk_dev, self.wc_dev, self.cols = self._put(
+                        (self.pk_dev, self.wc_dev, self.cols))
             c, new = self.cols, {}
             leaf_rows = sorted(writes.withdrawable)
             if leaf_rows:
@@ -855,14 +985,18 @@ class ResidentCore:
                 new = {f: jax.device_put(a, self._mesh.shard_v)
                        for f, a in new.items()}
             self.cols = c._replace(**new)
-            balance_rows = sorted({m[0] for m in writes.balance_moves})
-            sp.note(rows=len(set(leaf_rows) | set(balance_rows)))
+            balance_rows = sorted({m[0] for m in writes.balance_moves}
+                                  | set(new_rows))
+            leaf_rows = sorted(set(leaf_rows) | set(new_rows))
+            sp.note(rows=len(set(leaf_rows) | set(balance_rows)),
+                    appended_rows=len(new_rows))
         with telemetry.span("resident.forests.update") as sp:
             lanes0 = _FOREST_PAIR_LANES.value
             self._update_forest_paths(
                 np.asarray(leaf_rows, np.int64),
                 np.unique(np.asarray(balance_rows, np.int64) // 4))
             sp.note(registry_leaves=len(leaf_rows),
+                    appended_leaves=len(new_rows),
                     balance_chunks=len({r // 4 for r in balance_rows}),
                     pair_lanes=_FOREST_PAIR_LANES.value - lanes0)
 
@@ -989,13 +1123,16 @@ class ResidentCore:
         the balances column's length, dtype and placement, so that
         whatever it holds the programs that run are the balances
         forest's: the chunk program and one level build, sharded under a
-        mesh (level 0 from the mesh's placed chunk program, inert padding
-        rows being the SSZ pack's zero padding)."""
+        mesh (level 0 from the mesh's placed chunk program). Inert rows
+        hold balance 0, the SSZ pack's own zero padding, so the chunks
+        from the logical length on are zero chunks without a mask."""
+        logical_n = max(1, -(-self._v // 4))
         if self._mesh is not None:
             return ShardedIncrementalMerkleTree(
-                self._mesh.balances_forest_chunks(column, self._v),
-                self._mesh, logical_n=max(1, -(-self._v // 4)))
-        return IncrementalMerkleTree(bulk.balances_chunk_words_device(column))
+                self._mesh.balances_forest_chunks(column, self._capacity),
+                self._mesh, logical_n=logical_n)
+        return IncrementalMerkleTree(bulk.balances_chunk_words_device(column),
+                                     logical_n=logical_n)
 
     def _active_index_root(self, indices) -> bytes:
         """hash_tree_root(indices, List[uint64]) for the ascending active
@@ -1035,30 +1172,33 @@ class ResidentCore:
         lanes0 = _FOREST_PAIR_LANES.value
         if self._mesh is not None:
             # sharded forests: level 0 built by the mesh's placed leaf
-            # programs (inert padding rows masked to the SSZ virtual-zero
-            # rows), per-shard subtree levels resident on their shard
+            # programs (inert rows masked to the SSZ zero rows), per-shard
+            # subtree levels resident on their shard
             if self._reg_forest is None:
                 self._reg_forest = ShardedIncrementalMerkleTree(
                     self._mesh.registry_forest_leaves(
                         self.pk_dev, self.wc_dev,
                         c.activation_eligibility_epoch, c.activation_epoch,
                         c.exit_epoch, c.withdrawable_epoch, c.slashed,
-                        c.effective_balance, v_count=V),
+                        c.effective_balance, v_count=V,
+                        capacity=self._capacity),
                     self._mesh, logical_n=V)
-        else:
-            if self._reg_forest is None:
-                self._reg_forest = IncrementalMerkleTree(
-                    bulk.registry_leaf_words_device(
-                        self.pk_dev, self.wc_dev,
-                        c.activation_eligibility_epoch, c.activation_epoch,
-                        c.exit_epoch, c.withdrawable_epoch, c.slashed,
-                        c.effective_balance))
+        elif self._reg_forest is None:
+            # a core with no room has no inert row to mask: the leaf
+            # program of every validator, V a shape as it always was
+            leaves = (bulk.registry_leaf_words_device if self._capacity == V
+                      else lambda *cols: _masked_leaves(*cols, np.int32(V)))
+            self._reg_forest = IncrementalMerkleTree(
+                leaves(self.pk_dev, self.wc_dev,
+                       c.activation_eligibility_epoch, c.activation_epoch,
+                       c.exit_epoch, c.withdrawable_epoch, c.slashed,
+                       c.effective_balance), logical_n=V)
         if self._bal_forest is None:
             self._bal_forest = self._balances_forest(c.balance)
         # re-layout watchdog on the resident forests: per-slot root
         # requests must keep every level-0 buffer's placement (a rebuild
-        # at the same capacity reproduces it; only a deposit crossing the
-        # padded power of two legitimately re-places — and is reported)
+        # at the same capacity reproduces it; only a deposit that passes
+        # the capacity legitimately re-places: `_grow_capacity` reports it)
         _watchdog.layout_check(f"{self._tkey}.forest.reg.l0",
                                self._reg_forest.levels[0])
         _watchdog.layout_check(f"{self._tkey}.forest.bal.l0",
@@ -1067,8 +1207,9 @@ class ResidentCore:
 
     def _forest_roots(self) -> tuple:
         """(registry_root, balances_root) of the forests as they stand:
-        the wait for whatever is still queued on them, both top rows down
-        in one transfer, the lengths mixed in."""
+        the wait for whatever is still queued on them, both root levels
+        down in one transfer (the top rows, while the lists fill more
+        than half their trees), the lists' logical length mixed in."""
         if self._reg_forest is None or self._bal_forest is None:
             # degenerate metadata-only state: the numpy oracle short-circuit
             c = self.cols
@@ -1076,8 +1217,8 @@ class ResidentCore:
                 self.pk_dev, self.wc_dev, c.activation_eligibility_epoch,
                 c.activation_epoch, c.exit_epoch, c.withdrawable_epoch,
                 c.slashed, c.effective_balance, c.balance)
-        top = jax.device_get((self._reg_forest.levels[-1],
-                              self._bal_forest.levels[-1]))
+        top = jax.device_get((self._reg_forest.root_level(),
+                              self._bal_forest.root_level()))
         return tuple(
             ssz_impl.mix_in_length(words_to_bytes(t[0]).tobytes(), self._v)
             for t in top)
@@ -1169,10 +1310,9 @@ class ResidentCore:
 
     def _registry_operations(self, block) -> list:
         """The lists of the block's body that the resident state does not
-        serve and that are not empty: deposits (a new validator changes V,
-        the shape of every device column and forest) and transfers. None
-        when the block is header, randao, eth1 vote, attestations, exits
-        and slashings only, which a light core and an object-entered one
+        serve and that are not empty: transfers. None when the block is
+        header, randao, eth1 vote, attestations, deposits, exits and
+        slashings only, which a light core and an object-entered one
         serve alike. Any other needs the object registry
         (_fallback_block), which a checkpoint-resumed core deliberately
         never built: it is refused here by name, before anything is
@@ -1182,7 +1322,8 @@ class ResidentCore:
         if touched and self._light:
             raise NotImplementedError(
                 f"of the registry_operations a checkpoint-resumed (light) "
-                f"resident core serves voluntary exits and slashings only: "
+                f"resident core serves deposits, voluntary exits and "
+                f"slashings: "
                 f"the block at slot {int(block.slot)} carries "
                 f"{', '.join(touched)}, which need the object registry — "
                 f"resume via the standard ResidentCore entry")
@@ -1194,20 +1335,22 @@ class ResidentCore:
         through this core's view (helpers.registry_view). The operations
         run in the spec's order with the spec's every check: proposer and
         attester slashings (`resident.block.slashings`), attestations
-        (`.attestations`), voluntary exits (`.exits`). An exit or a
-        slashing writes the host mirrors at once, so that the next
-        operation of the block reads it (a second exit of one validator
-        is refused, the exit queue counts the first); when the last
-        operation has passed, the block's dirty rows go into the device
-        columns (`resident.registry_write`) and the dirty leaves and
+        (`.attestations`), deposits (`.deposits`), voluntary exits
+        (`.exits`). An exit, a slashing or a deposit's new validator
+        writes the host's part at once, so that the next operation of the
+        block reads it (a second exit of one validator is refused, the
+        exit queue counts the first, a second deposit of a new key tops
+        its row up); when the last operation has passed, the block's
+        appended and dirty rows go into the device columns
+        (`resident.registry_write`) and the new and dirty leaves and
         balance chunks into both forests (`resident.forests.update`), and
         the next slot's root takes the forests' roots as they then are. A
-        block the spec rejects is rejected with columns, mirrors and
-        forests as they were (the small fields it wrote before the check
-        that failed are the caller's to discard, as the spec discards
-        them). A block with a deposit or a transfer takes
-        `_fallback_block` on an object-entered core and is refused, before
-        anything is written, by a light one."""
+        block the spec rejects is rejected with the registry's length,
+        columns, mirrors, pubkey index and forests as they were (the
+        small fields it wrote before the check that failed are the
+        caller's to discard, as the spec discards them). A block with a
+        transfer takes `_fallback_block` on an object-entered core and is
+        refused, before anything is written, by a light one."""
         if self._registry_operations(block):
             self._fallback_block(state, block)
             return
@@ -1225,7 +1368,7 @@ class ResidentCore:
                 spec.process_randao(state, body)
             with telemetry.span("resident.block.eth1"):
                 spec.process_eth1_data(state, body)
-            writes = self._writes = _BlockWrites(self._exit_queue)
+            writes = self._writes = _BlockWrites(self._exit_queue, self._v)
             try:
                 # process_operations, list by list under the spans
                 spec.check_operations(state, body)
@@ -1240,9 +1383,18 @@ class ResidentCore:
                     spec.process_operation_list(state, body, "attestations")
                     sp_part.note(
                         plan_elements=bulk.PLAN_ELEMENTS.value - elements)
-                with telemetry.span("resident.block.exits") as sp_part:
-                    # deposits and transfers are empty here
+                with telemetry.span("resident.block.deposits") as sp_part:
+                    # each proves its branch against the state's deposit
+                    # root: DEPOSIT_CONTRACT_TREE_DEPTH pairs by hashlib
                     spec.process_operation_list(state, body, "deposits")
+                    new = self._v - writes.v0
+                    sp_part.note(
+                        new_validators=new,
+                        top_ups=len(body.deposits) - new,
+                        proof_pairs_hashed=len(body.deposits)
+                        * int(spec.DEPOSIT_CONTRACT_TREE_DEPTH))
+                with telemetry.span("resident.block.exits") as sp_part:
+                    # transfers are empty here
                     spec.process_operation_list(state, body, "voluntary_exits")
                     spec.process_operation_list(state, body, "transfers")
                     sp_part.note(exits=len(body.voluntary_exits))
@@ -1299,14 +1451,10 @@ class ResidentCore:
         sharded==single gate. Idempotent when already single-device."""
         if self._mesh is None:
             return
-        import jax.numpy as jnp
         with telemetry.span("resident.degrade_single_device"):
             np_cols = self._materialize_np_cols()
             self._mesh = None
-            self.cols = ValidatorColumns(
-                **{f: jnp.asarray(np_cols[f]) for f in _ALL_FIELDS})
-            self.pk_dev = jnp.asarray(self._pk_np)
-            self.wc_dev = jnp.asarray(self._wc_np)
+            self._upload(np_cols)
             self._reg_forest = None
             self._bal_forest = None
             self._big_roots = None
@@ -1323,6 +1471,7 @@ class ResidentCore:
         `epoch_shardings()`'s placement (no `[V]` fact is copied from chip
         to chip on its way to the program)."""
         if self._mesh is None:
+            # the facts have the mirrors' rows, which are the columns'
             import jax.numpy as jnp
             return (scalars_from_state(state),
                     jax.tree_util.tree_map(jnp.asarray, inp))
@@ -1413,10 +1562,11 @@ class ResidentCore:
 
     def _unstage_for_single_device(self, scal, inp) -> tuple:
         """Mesh-staged (scal, inp) -> the default device, the facts cut
-        back to the logical rows (the single-device rung's recovery)."""
+        back to the columns' rows there (the single-device rung's
+        recovery)."""
         import jax.numpy as jnp
         scal, inp = jax.device_get((scal, inp))
-        inp = inp._replace(**{f: getattr(inp, f)[:self._v]
+        inp = inp._replace(**{f: getattr(inp, f)[:self._capacity]
                               for f in inp._fields
                               if f not in REPLICATED_INPUT_FIELDS})
         return jax.tree_util.tree_map(jnp.asarray, (scal, inp))
@@ -1445,6 +1595,13 @@ class ResidentCore:
         spec = self.spec
         with telemetry.span("resident.stage"):
             with telemetry.span("resident.stage.distill") as sp_distill:
+                # the activation queue this boundary finds, counted on the
+                # device (the eligibility epoch has no mirror) while the
+                # host distils: read when the note is written
+                pending = _pending_activations(
+                    self.cols.activation_eligibility_epoch,
+                    self.cols.activation_epoch,
+                    np.uint64(int(spec.FAR_FUTURE_EPOCH)))
                 current_epoch = spec.get_current_epoch(state)
                 previous_epoch = spec.get_previous_epoch(state)
                 ctx = build_epoch_context(spec, state, dict(
@@ -1463,6 +1620,10 @@ class ResidentCore:
                     # blocks carry exits and slashings
                     active_validators=len(spec.get_active_validator_indices(
                         state, current_epoch)),
+                    # the registry's length, which deposits move, and the
+                    # rows with an eligibility epoch and no activation epoch
+                    registry_rows=self._v,
+                    pending_activations=int(pending),
                     # what distill's row slope is per
                     pending_rows=len(ctx.prev_atts) + len(ctx.curr_atts),
                     # Crosslink roots the three winner passes hashed one by
@@ -1512,11 +1673,10 @@ class ResidentCore:
             dispatched = (lanes, time.perf_counter())
             with telemetry.span("resident.refresh.download"):
                 new_scal, report = jax.device_get((dev_scal, dev_report))
-                # (the [:_v] slice drops the sharded layout's inert padding
-                # rows)
+                # (the slice drops the sharded layout's inert padding rows)
                 for f in mirrored:
                     self.mirrors[f] = np.asarray(
-                        jax.device_get(getattr(dev_cols, f)))[:self._v]
+                        jax.device_get(getattr(dev_cols, f)))[:self._capacity]
             with telemetry.span("resident.refresh.final_updates") as sp_fin:
                 lanes0 = _FOREST_PAIR_LANES.value
                 hashed0 = bulk.HOST_PAIRS_HASHED.value

@@ -75,6 +75,9 @@ class Phase0Spec:
         # resident core registered for the state it holds as columns; every
         # other state is answered by its own validator list
         self._registry_views: Dict[int, object] = {}
+        # helpers.ObjectRegistry's memo of the last object registry a
+        # deposit looked a pubkey up in
+        self._pubkey_index: list = [None, {}, 0]
 
         # Caches (reference epilogue: build_spec.py:78-105)
         self._hash_cache: Dict[bytes, bytes] = {}
@@ -93,6 +96,7 @@ class Phase0Spec:
     def clear_caches(self) -> None:
         self._hash_cache.clear()
         self._perm_cache.clear()
+        self._pubkey_index[:] = [None, {}, 0]
 
     def __repr__(self):
         return f"Phase0Spec(preset={self.name!r})"

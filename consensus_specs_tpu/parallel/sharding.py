@@ -104,8 +104,8 @@ def pow2_pad_rows(n: int, mesh_size: int) -> int:
     """The next power of two >= max(n, 1) — because the serving mesh size
     is itself a power of two, the result is a multiple of it whenever it
     is at least the mesh size. This is the row count the sharded forests
-    materialize per level and the append-grow target (ISSUE: the
-    append-grow pow2 padding must round to a multiple of the mesh size)."""
+    materialize per level: the capacity a sharded tree is laid out with
+    rounds to a multiple of the mesh size."""
     assert mesh_size & (mesh_size - 1) == 0, \
         f"mesh size must be a power of two, got {mesh_size}"
     return next_power_of_two(max(n, 1))
@@ -334,19 +334,21 @@ class ServingMesh:
     def registry_forest_leaves(self, pubkeys, withdrawal_credentials,
                                activation_eligibility_epoch, activation_epoch,
                                exit_epoch, withdrawable_epoch, slashed,
-                               effective_balance, v_count: int):
+                               effective_balance, v_count: int,
+                               capacity: int = None):
         """[P2, 8] sharded level-0 rows of the registry forest from padded
         `[Vp]` device columns: validator hash_tree_root words for rows
         below the LOGICAL count, zero rows (the SSZ virtual padding)
-        beyond — P2 = pow2_pad_rows(v_count), a multiple of the mesh size
-        whenever it reaches it. v_count rides as a traced scalar so a
-        deposit that grows the registry inside the same padding re-uses
+        beyond — P2 = pow2_pad_rows(capacity), the rows of storage the
+        registry has (its length when none is given), a multiple of the
+        mesh size whenever it reaches it. v_count rides as a traced scalar
+        so a deposit that grows the registry inside the capacity re-uses
         the compiled program."""
         import jax.numpy as jnp
         from ..utils.ssz.bulk import _registry_leaf_words
 
         vp = int(pubkeys.shape[0])
-        p2 = pow2_pad_rows(v_count, self.size)
+        p2 = pow2_pad_rows(capacity or v_count, self.size)
         key = ("regleaves", vp, p2)
         fn = self._jits.get(key)
         if fn is None:
@@ -372,9 +374,10 @@ class ServingMesh:
 
     def balances_forest_chunks(self, balances, v_count: int):
         """[P2c, 8] sharded level-0 rows of the balances forest from the
-        padded `[Vp]` balance column. Inert padding rows hold balance 0,
-        which IS the SSZ pack's virtual zero padding, so no masking is
-        needed — only the pow2 row padding."""
+        padded `[Vp]` balance column, `v_count` the registry's rows of
+        storage (its capacity). Inert padding rows hold balance 0, which
+        IS the SSZ pack's virtual zero padding, so no masking is needed —
+        only the pow2 row padding."""
         import jax.numpy as jnp
         from ..utils.ssz.bulk import _balances_chunk_words
 

@@ -42,8 +42,15 @@ ends in `resident.stage.distill.place`, and `.upload`),
 .final_updates`) and `resident.forests` (at a boundary the wait for the
 forests the refresh dispatched first); `resident.block` (a root of
 its own, `req` = the block's slot: the slot's root span has closed when
-`process_slots` returned) over `.header .randao .eth1 .attestations`;
-`resident.checkpoint_write`
+`process_slots` returned) over `.header .randao .eth1 .slashings
+.attestations .deposits .exits` (`.deposits` notes `new_validators`,
+`top_ups` and `proof_pairs_hashed`) and, for a block that wrote the
+registry, `resident.registry_write` (notes `rows`, `appended_rows`) and
+`resident.forests.update` (notes `registry_leaves`, `appended_leaves`,
+`balance_chunks`, `pair_lanes`); `resident.registry.pubkey_index` (the one
+build of a core's pubkey -> row index, at its first deposit);
+`resident.stage.distill` notes `registry_rows` and `pending_activations`
+beside `active_validators`; `resident.checkpoint_write`
 (`.download .assemble`) and `resident.restore` (`.decode .upload`)),
 `firehose.*` (streaming-verifier pipeline stages: stage/dispatch/flush,
 exit-only fences), `bench.*` / `followup.*` (harnesses); counters
@@ -54,7 +61,9 @@ launch occupancy), `shuffle.permutations_computed` (misses of the spec's
 permutation cache: shuffles really run), `firehose.*` (queue depth / batch occupancy /
 deadline misses — always-on: /healthz reads them), `watchdog.*`
 (retrace/re-layout events), `resident.block.fallbacks` (blocks that left
-the served path for the object model; always-on), `jax.backend_compiles` (global compile
+the served path for the object model; always-on),
+`resident.registry.capacity_grown` (re-layouts of a resident core at a
+larger registry capacity; always-on), `jax.backend_compiles` (global compile
 listener).
 """
 from .core import (Counter, Gauge, Histogram, Span, counter, enabled,

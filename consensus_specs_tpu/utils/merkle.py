@@ -28,9 +28,9 @@ def tree_depth(count: int) -> int:
     need no hashing, everything else pads up to next_power_of_two.
 
     Shared by merkleize_chunks and the incremental forest
-    (utils/ssz/incremental.py), whose append-grow must agree with the
-    padded depth here — a leaf count crossing a power of two deepens the
-    tree by exactly the levels this function adds."""
+    (utils/ssz/incremental.py), which takes a list's root at the level
+    this gives for its logical length — a leaf count crossing a power of
+    two inside the tree's capacity moves the root one level up."""
     return (next_power_of_two(count) - 1).bit_length()
 
 

@@ -292,15 +292,6 @@ class ChunkTreeHandle:
                          else np.zeros((0, 8), np.uint32))
         self._chunks[np.asarray(leaf_idx, np.int64)] = rows
 
-    def append(self, rows: np.ndarray) -> None:
-        """Grow the chunk matrix (crossing padded powers of two included)."""
-        from ...ops.sha256 import bytes_to_words
-        rows = np.asarray(rows, np.uint8).reshape(-1, 32)
-        self.invalidate_memo()
-        self.tree.append(bytes_to_words(rows) if rows.shape[0]
-                         else np.zeros((0, 8), np.uint32))
-        self._chunks = np.concatenate([self._chunks, rows])
-
     def invalidate_memo(self) -> None:
         """Evict every memo entry this handle inserted (its content is about
         to be superseded)."""

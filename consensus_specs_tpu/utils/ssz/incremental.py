@@ -11,13 +11,17 @@ root paths of updated leaves — one batched pair-hash launch per level, so an
 update costs O(dirty * log V) compressions instead of O(V).
 
 Semantics are exactly SSZ merkleize (specs/simple-serialize.md:139-147):
-the leaf count pads virtually to the next power of two with zero chunks.
-Stored level `d` holds ceil(n / 2**d) rows; rows beyond that are virtual and
-equal `zerohashes[d]`, so the padding is never materialized. `append` grows
-the tree past the padded power of two: levels extend with zerohash rows, new
-top levels appear as the padded depth deepens, and only the appended leaves'
-root paths re-hash (tests/test_incremental_merkle.py crosses the boundary
-both ways against the full-recompute oracle).
+the leaf count pads to the next power of two with zero chunks. A tree has a
+CAPACITY (the rows of level 0, fixed when it is built) and a LOGICAL leaf
+count `n` <= capacity: rows from `n` to the capacity are materialized zero
+chunks, rows beyond the capacity are virtual (stored level `d` holds
+ceil(capacity / 2**d) rows, the rest equal `zerohashes[d]`). A list that
+grows takes its new leaves by `update` / `update_bucket` at rows from `n` on
+with the new `logical_n`: no level changes shape, so no program compiles.
+The root of the `n` logical leaves is the first node of level
+`tree_depth(n)` (`root_level`), which is the top one while more than half of
+the padded capacity is in use. A tree whose capacity is passed is built anew
+by its owner (models/phase0/resident.py re-lays its whole core out).
 
 Level scatters donate the old level buffer (`donate_argnums`), so a dirty
 update rewrites rows in place instead of copying registry-scale arrays.
@@ -219,9 +223,10 @@ def _pad_pow2_indices(idx: np.ndarray) -> np.ndarray:
 class IncrementalMerkleTree:
     """All levels of one pow2-padded SSZ Merkle tree, device-resident.
 
-    build:  IncrementalMerkleTree(leaf_words)   [n, 8] uint32 big-endian words
+    build:  IncrementalMerkleTree(leaf_words)   [capacity, 8] uint32 big-endian words
+            (`logical_n` <= capacity: the rows from it on are zero chunks)
     update: tree.update(leaf_idx, rows_words)   O(dirty * log n) compressions
-    append: tree.append(rows_words)             grow, incl. past the padded pow2
+            (`logical_n=`: the list's new length, when rows from `n` on are written)
     root:   tree.root() -> 32 bytes             (the only device download)
 
     List-kind callers mix the length in themselves (impl.mix_in_length), the
@@ -233,10 +238,12 @@ class IncrementalMerkleTree:
     upload and stay valid).
     """
 
-    def __init__(self, leaf_words, pair_fn=None):
+    def __init__(self, leaf_words, pair_fn=None, logical_n: int = None):
         leaf_words = jnp.asarray(leaf_words, jnp.uint32)
         assert leaf_words.ndim == 2 and leaf_words.shape[1] == 8, \
             leaf_words.shape
+        self._set_logical_n(int(leaf_words.shape[0]) if logical_n is None
+                            else logical_n, int(leaf_words.shape[0]))
         self._pair_fn = pair_fn          # None = ops.sha256.pair_hash_words
         self.last_pairs_per_level: List[int] = []
         self.total_pairs_hashed = 0
@@ -246,11 +253,30 @@ class IncrementalMerkleTree:
 
     @property
     def n(self) -> int:
+        """The logical leaf count (the list's length)."""
+        return self._n
+
+    @property
+    def capacity(self) -> int:
         return int(self.levels[0].shape[0])
 
     @property
     def depth(self) -> int:
         return len(self.levels) - 1
+
+    def _set_logical_n(self, n: int, capacity: int) -> None:
+        assert 0 <= n <= capacity, \
+            f"logical length {n} outside a capacity of {capacity} rows"
+        self._n = int(n)
+
+    def _take_logical_n(self, idx: np.ndarray, logical_n) -> None:
+        """An update's range check: the written rows lie inside the
+        logical length, the new one where the update gives it."""
+        n = self._n if logical_n is None else int(logical_n)
+        assert n >= self._n, "a list's tree never shrinks"
+        assert 0 <= idx.min() and idx.max() < n, \
+            f"leaf index out of range (n={n}, capacity={self.capacity})"
+        self._set_logical_n(n, self.capacity)
 
     def _hash(self, pairs: jnp.ndarray) -> jnp.ndarray:
         fn = self._pair_fn if self._pair_fn is not None else pair_hash_words
@@ -293,10 +319,12 @@ class IncrementalMerkleTree:
 
     # -- incremental paths --------------------------------------------------
 
-    def update(self, leaf_idx, rows_words) -> None:
+    def update(self, leaf_idx, rows_words, logical_n: int = None) -> None:
         """Overwrite leaves and re-hash only their root paths.
 
-        leaf_idx: [k] unique in-range ints; rows_words: [k, 8] uint32."""
+        leaf_idx: [k] unique in-range ints; rows_words: [k, 8] uint32;
+        `logical_n`: the list's new length where leaves from `n` on are
+        among them (inside the capacity)."""
         idx = np.asarray(leaf_idx, dtype=np.int32).reshape(-1)
         rows = jnp.asarray(rows_words, jnp.uint32).reshape(-1, 8)
         assert idx.shape[0] == rows.shape[0], (idx.shape, rows.shape)
@@ -305,20 +333,23 @@ class IncrementalMerkleTree:
             return
         dirty = np.unique(idx)
         assert dirty.shape[0] == idx.shape[0], "duplicate leaf indices"
-        assert 0 <= dirty[0] and dirty[-1] < self.n, \
-            f"leaf index out of range (n={self.n}); grow via append()"
+        self._take_logical_n(dirty, logical_n)
         self.levels[0] = _scatter_rows(self.levels[0], jnp.asarray(idx), rows)
         self.last_pairs_per_level = []
         self._rehash_paths(dirty)
 
-    def update_bucket(self, leaf_idx, rows_words) -> None:
+    def update_bucket(self, leaf_idx, rows_words,
+                      logical_n: int = None) -> None:
         """`update` for a serving loop: `leaf_idx` is a `bucket_indices`
         bucket (in-range, repeats allowed where the rows repeat with
         them), `rows_words` the `[k, 8]` device leaves at them; the
         scatter and all the path levels are ONE dispatched program
         (`_update_bucket_traced`), k lanes a level, nothing comes back to
-        the host. The levels keep their placement."""
-        idx = jnp.asarray(np.asarray(leaf_idx, np.int32).reshape(-1))
+        the host. The levels keep their placement, and leaves appended
+        inside the capacity (`logical_n`) meet the same program."""
+        idx = np.asarray(leaf_idx, np.int32).reshape(-1)
+        self._take_logical_n(idx, logical_n)
+        idx = jnp.asarray(idx)
         rows = jnp.asarray(rows_words, jnp.uint32).reshape(-1, 8)
         assert idx.shape[0] == rows.shape[0], (idx.shape, rows.shape)
         # the pair hash's TPU form off the CPU (sha256._unroll_for's reason)
@@ -331,35 +362,6 @@ class IncrementalMerkleTree:
 
     def _update_bucket_fn(self):
         return _update_bucket_pd
-
-    def append(self, rows_words) -> None:
-        """Append leaves, growing past the padded power of two when needed:
-        every level extends with virtual-zero rows, new top levels appear as
-        the padded depth deepens, and only the appended leaves' root paths
-        re-hash (their ancestor chains cover every row whose value changes,
-        including the old odd tails that used to pair with a zerohash)."""
-        rows = jnp.asarray(rows_words, jnp.uint32).reshape(-1, 8)
-        k = int(rows.shape[0])
-        if k == 0:
-            self.last_pairs_per_level = []
-            return
-        old_n = self.n
-        new_n = old_n + k
-        self.levels[0] = (rows if old_n == 0
-                          else jnp.concatenate([self.levels[0], rows]))
-        for d in range(1, tree_depth(new_n) + 1):
-            n_d = (new_n + (1 << d) - 1) >> d
-            if d < len(self.levels):
-                short = n_d - self.levels[d].shape[0]
-                if short > 0:
-                    self.levels[d] = jnp.concatenate(
-                        [self.levels[d], _zero_rows(d, short)])
-            else:
-                # rows not on an appended leaf's root path cover only
-                # virtual zero leaves, for which zerohash[d] IS the value
-                self.levels.append(_zero_rows(d, n_d))
-        self.last_pairs_per_level = []
-        self._rehash_paths(np.arange(old_n, new_n, dtype=np.int32))
 
     def _rehash_paths(self, dirty: np.ndarray) -> None:
         """Re-hash the ancestor rows of `dirty` leaves, one batched pair-hash
@@ -384,31 +386,38 @@ class IncrementalMerkleTree:
 
     # -- root ---------------------------------------------------------------
 
+    def root_level(self) -> jnp.ndarray:
+        """The level whose first node is the root of the `n` logical
+        leaves: fetched whole by a serving loop (a row sliced on the
+        device is a program a level shape), one row while more than half
+        of the padded capacity is in use."""
+        return self.levels[tree_depth(self._n)]
+
     def root(self) -> bytes:
-        """The pow2-padded merkleize root — bit-identical to
-        bulk.merkleize_chunk_array over the equivalent chunk matrix."""
+        """The pow2-padded merkleize root of the logical leaves,
+        bit-identical to bulk.merkleize_chunk_array over the equivalent
+        chunk matrix."""
         if self.n == 0:
             return ZERO_BYTES32
-        return words_to_bytes(np.asarray(self.levels[-1][0])).tobytes()
+        return words_to_bytes(np.asarray(self.root_level())[0]).tobytes()
 
 
 class ShardedIncrementalMerkleTree(IncrementalMerkleTree):
     """The forest under a validator-axis ServingMesh (ROADMAP item 1):
     per-shard subtree levels stay RESIDENT ON THEIR SHARD, a tiny
-    replicated cap tree joins the per-shard roots, and update/append
-    scatter only into the owning shard (a scatter with replicated updates
-    into a sharded operand keeps the operand's placement — each device
-    rewrites its own rows).
+    replicated cap tree joins the per-shard roots, and updates scatter
+    only into the owning shard (a scatter with replicated updates into a
+    sharded operand keeps the operand's placement — each device rewrites
+    its own rows).
 
-    Layout contract vs the single-device tree: jax pins shard sizes at
-    placement time, so every level MATERIALIZES its pow2 padding (zerohash
-    rows) instead of keeping it virtual — capacity is always
-    next_power_of_two(logical n), which rounds to a multiple of the mesh
-    size by construction (both are powers of two), exactly the append-grow
-    contract. A level shards over "v" while its row count divides the mesh
-    and replicates above that (the cap). Padding rows equal the virtual
-    zerohash rows they replace, so every stored node — and the root — is
-    bit-identical to the single-device tree (tests/test_multichip.py).
+    The single-device tree's contract (a capacity, a logical `n`, zero
+    chunks between them) with one more condition: jax pins shard sizes at
+    placement time, so the capacity is a power of two (a multiple of the
+    mesh size by construction, both being powers of two) and no level has
+    a virtual tail. A level shards over "v" while its row count divides
+    the mesh and replicates above that (the cap). Every stored node that
+    both layouts hold, and the root, is bit-identical to the
+    single-device tree's (tests/test_multichip.py).
 
     `placement` is a parallel.sharding.ServingMesh (duck-typed: needs
     row_sharding / forest_build_jit / size).
@@ -430,9 +439,8 @@ class ShardedIncrementalMerkleTree(IncrementalMerkleTree):
                 leaf_words = jnp.concatenate(
                     [leaf_words, jnp.zeros((cap - rows, 8), jnp.uint32)])
         else:
-            assert rows == next_power_of_two(max(logical_n, 1)), \
-                (rows, logical_n)
-        self._n = int(logical_n)
+            assert rows == next_power_of_two(rows), (rows, logical_n)
+        self._set_logical_n(logical_n, int(leaf_words.shape[0]))
         level0 = jax.device_put(
             leaf_words, placement.row_sharding(int(leaf_words.shape[0])))
         self._pair_fn = pair_fn
@@ -441,12 +449,6 @@ class ShardedIncrementalMerkleTree(IncrementalMerkleTree):
         self.builds = 0
         self.levels = [level0]
         self._build()
-
-    @property
-    def n(self) -> int:
-        # logical leaf count: capacity is levels[0].shape[0]; update()'s
-        # range check and root()'s emptiness check both want the logical n
-        return self._n
 
     def _build(self) -> None:
         self.builds += 1
@@ -487,44 +489,8 @@ class ShardedIncrementalMerkleTree(IncrementalMerkleTree):
         return fn[1]
 
     # update() is inherited verbatim: with pow2-materialized levels the
-    # odd-tail/virtual-row branches of _rehash_paths never trigger, the
-    # level scatters preserve each level's placement, and the `n` property
-    # above keeps the range check at the logical leaf count.
-
-    def append(self, rows_words) -> None:
-        """Append leaves: scatter into the materialized padding while it
-        lasts; crossing the padded power of two grows every level with
-        zerohash rows (they cover only virtual zero leaves, whose value
-        zerohash[d] already is), re-places it on the mesh — the one step
-        that re-lays-out, and the new capacity rounds to a multiple of the
-        mesh size by pow2 construction — and deepens the cap."""
-        import jax.numpy as jnp
-        rows = jnp.asarray(rows_words, jnp.uint32).reshape(-1, 8)
-        k = int(rows.shape[0])
-        if k == 0:
-            self.last_pairs_per_level = []
-            return
-        old_n = self._n
-        new_n = old_n + k
-        cap = int(self.levels[0].shape[0])
-        if new_n > cap:
-            new_cap = next_power_of_two(new_n)
-            for d in range(len(self.levels)):
-                n_d = new_cap >> d
-                lvl = jnp.concatenate(
-                    [self.levels[d],
-                     _zero_rows(d, n_d - int(self.levels[d].shape[0]))])
-                self.levels[d] = jax.device_put(
-                    lvl, self._placement.row_sharding(n_d))
-            for d in range(len(self.levels), tree_depth(new_cap) + 1):
-                n_d = new_cap >> d
-                self.levels.append(jax.device_put(
-                    _zero_rows(d, n_d), self._placement.row_sharding(n_d)))
-        self._n = new_n
-        idx = np.arange(old_n, new_n, dtype=np.int32)
-        self.levels[0] = _scatter_rows(self.levels[0], jnp.asarray(idx), rows)
-        self.last_pairs_per_level = []
-        self._rehash_paths(idx)
+    # odd-tail/virtual-row branches of _rehash_paths never trigger and the
+    # level scatters preserve each level's placement.
 
 
 def tree_from_chunks(chunks: np.ndarray,

@@ -132,7 +132,8 @@ def test_distill_is_cut_into_its_parts_in_order(spec, spans):
 def test_a_boundary_writes_fifteen_records_more_and_a_slot_and_a_block_none(
         spec, spans):
     """The whole count: a non-boundary slot's tree is 7 records and a
-    block's 7, as before this cut; the boundary slot's was 15 and is 30,
+    block's 8 (7 before the deposit list got a span of its own); the boundary
+    slot's was 15 and is 30,
     and 31 since the refresh dispatches its forests in a span of its own."""
     spe = int(spec.SLOTS_PER_EPOCH)
     core = _sync_core(spec)
@@ -151,7 +152,7 @@ def test_a_boundary_writes_fifteen_records_more_and_a_slot_and_a_block_none(
                           "resident.block")}
     # a resumed core's first slot root builds the forests under its own span
     assert sizes["resident.slot"] == {7, 8}
-    assert sizes["resident.block"] == {7}
+    assert sizes["resident.block"] == {8}
     assert sizes["resident.boundary_slot"] == {31}
     assert sum(NEW_RECORDS.values()) == 15
     for root in roots:

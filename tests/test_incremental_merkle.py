@@ -5,8 +5,9 @@ The forest (utils/ssz/incremental.py) keeps every tree level resident and
 re-hashes only dirty root paths; every root here is checked against the
 full-recompute oracle bulk.merkleize_chunk_array (itself pinned to the
 recursive object-model Merkleizer in tests/test_bulk_htr.py). Patterns:
-single leaf, dense stripes, repeated updates to the same leaf, append-grow
-crossing a power-of-two boundary, and the all-dirty epoch-boundary shape —
+single leaf, dense stripes, repeated updates to the same leaf, a list that
+grows inside its tree's capacity (powers of two crossed), and the all-dirty
+epoch-boundary shape —
 on both pair-hash backends (CSTPU_MERKLE_BACKEND=xla|pallas; the Pallas
 kernel lowers for TPUs only, so the fixture swaps in its interpreter form
 and the scenario stays compact).
@@ -97,16 +98,36 @@ def test_repeated_updates_to_same_leaf():
     assert tree.root() == original
 
 
-def test_append_grow_crossing_power_of_two():
+def _with_room(chunks, capacity):
+    """A tree of `capacity` rows whose logical leaves are `chunks`: zero
+    chunks from there on, as a list with room to grow is laid out."""
+    rows = np.zeros((capacity, 32), np.uint8)
+    rows[:len(chunks)] = chunks
+    return IncrementalMerkleTree(bytes_to_words(rows), logical_n=len(chunks))
+
+
+@pytest.mark.parametrize("capacity", [271, 300, 512, 1000])
+def test_a_list_grows_inside_its_capacity_crossing_powers_of_two(capacity):
+    """Leaves appended by `update` at the rows from `n` on, with the new
+    logical length: no level changes shape, and the root is the first node
+    of the level that spans the logical leaves, the SSZ root of the list
+    as long as it now is."""
     rng = np.random.default_rng(4)
     chunks = _rand_chunks(rng, 5)
-    tree = tree_from_chunks(chunks)
+    tree = _with_room(chunks, capacity)
+    shapes = [level.shape for level in tree.levels]
+    _check(tree, chunks, "built")
     for k in (2, 1, 4, 9, 50, 200):          # crosses 8, 16, 64, 256
         rows = _rand_chunks(rng, k)
+        n = chunks.shape[0]
         chunks = np.concatenate([chunks, rows])
-        tree.append(bytes_to_words(rows))
+        tree.update(np.arange(n, n + k), bytes_to_words(rows), logical_n=n + k)
         _check(tree, chunks, k)
-        assert tree.depth == tree_depth(chunks.shape[0])
+        assert tree.n == n + k and tree.capacity == capacity
+        assert tree.root_level().shape[0] \
+            == -(-capacity // (1 << tree_depth(n + k)))
+    assert [level.shape for level in tree.levels] == shapes
+    assert tree.builds == 1
     # interleave: update old leaves after several growth steps
     idx = np.array([0, 6, 7, 8, 100, chunks.shape[0] - 1])
     rows = _rand_chunks(rng, idx.shape[0])
@@ -115,13 +136,46 @@ def test_append_grow_crossing_power_of_two():
     _check(tree, chunks)
 
 
-def test_append_from_empty():
+def test_a_list_grows_from_empty_and_never_past_its_capacity():
     rng = np.random.default_rng(5)
-    tree = tree_from_chunks(np.zeros((0, 32), np.uint8))
+    tree = _with_room(np.zeros((0, 32), np.uint8), 4)
     assert tree.root() == bulk.merkleize_chunk_array(np.zeros((0, 32), np.uint8))
     chunks = _rand_chunks(rng, 3)
-    tree.append(bytes_to_words(chunks))
+    tree.update(np.arange(3), bytes_to_words(chunks), logical_n=3)
     _check(tree, chunks)
+    with pytest.raises(AssertionError, match="capacity"):
+        tree.update([4], bytes_to_words(_rand_chunks(rng, 1)), logical_n=5)
+    with pytest.raises(AssertionError, match="out of range"):
+        tree.update([3], bytes_to_words(_rand_chunks(rng, 1)))  # no new length
+    with pytest.raises(AssertionError, match="never shrinks"):
+        tree.update([0], bytes_to_words(_rand_chunks(rng, 1)), logical_n=2)
+    _check(tree, chunks)
+
+
+@pytest.mark.parametrize("n,capacity", [(5, 64), (100, 128), (97, 1000), (1000, 1024)])
+def test_bucket_update_appends_inside_the_capacity(n, capacity):
+    """The serving loop's program takes new leaves like dirty ones: rows
+    from `n` on in the bucket, the new logical length beside them, every
+    stored level what a from-scratch build of the longer list gives."""
+    from consensus_specs_tpu.utils.ssz.incremental import bucket_indices
+    rng = np.random.default_rng(capacity)
+    chunks = _rand_chunks(rng, n)
+    tree = _with_room(chunks, capacity)
+    for k in (12, 1, 16):
+        if n + k > capacity:
+            break
+        dirty = np.unique(rng.choice(n, 4, replace=False))
+        new = _rand_chunks(rng, k)
+        chunks[dirty] = _rand_chunks(rng, len(dirty))
+        chunks = np.concatenate([chunks, new])
+        idx = bucket_indices(np.concatenate([dirty, np.arange(n, n + k)]))
+        n += k
+        tree.update_bucket(idx, bytes_to_words(chunks[idx]), logical_n=n)
+        _check(tree, chunks, k)
+        rebuilt = _with_room(chunks, capacity)
+        for got, want in zip(tree.levels, rebuilt.levels):
+            assert (np.asarray(got) == np.asarray(want)).all(), (n, k)
+    assert tree.builds == 1
 
 
 def test_all_dirty_epoch_boundary_shape():
@@ -137,12 +191,15 @@ def test_randomized_mixed_patterns():
     rng = np.random.default_rng(7)
     chunks = _rand_chunks(rng, 41)
     tree = tree_from_chunks(chunks)
+    tree = _with_room(chunks, 128)
     for trial in range(30):
         if rng.random() < 0.25:              # grow
             k = int(rng.integers(1, 8))
             rows = _rand_chunks(rng, k)
+            n = chunks.shape[0]
             chunks = np.concatenate([chunks, rows])
-            tree.append(bytes_to_words(rows))
+            tree.update(np.arange(n, n + k), bytes_to_words(rows),
+                        logical_n=n + k)
         else:                                # scattered dirty set
             k = int(rng.integers(1, min(16, chunks.shape[0]) + 1))
             idx = rng.choice(chunks.shape[0], k, replace=False)
@@ -192,12 +249,12 @@ def test_update_work_is_dirty_log_v():
 
 def test_backend_scenario_bit_exact(backend):
     """One build + scattered update + same-leaf rewrite + pow2-crossing
-    append per backend, each against the full-recompute oracle (the oracle
+    growth per backend, each against the full-recompute oracle (the oracle
     itself hashes through the selected backend only above its device
     threshold, so this also cross-checks pallas against hashlib)."""
     rng = np.random.default_rng(10)
     chunks = _rand_chunks(rng, 6)
-    tree = tree_from_chunks(chunks)
+    tree = _with_room(chunks, 12)
     _check(tree, chunks, backend)
     idx = np.array([0, 3, 5])
     rows = _rand_chunks(rng, 3)
@@ -210,7 +267,7 @@ def test_backend_scenario_bit_exact(backend):
     _check(tree, chunks, backend)
     rows = _rand_chunks(rng, 4)                 # 6 -> 10 crosses 8
     chunks = np.concatenate([chunks, rows])
-    tree.append(bytes_to_words(rows))
+    tree.update(np.arange(6, 10), bytes_to_words(rows), logical_n=10)
     _check(tree, chunks, backend)
 
 
@@ -240,10 +297,6 @@ def test_chunk_tree_handle_matches_oracle():
     rows = _rand_chunks(rng, 3)
     handle.update(idx, rows)
     chunks[idx] = rows
-    assert handle.root() == bulk.merkleize_chunk_array(chunks)
-    rows = _rand_chunks(rng, 70)                 # 200 -> 270 crosses 256
-    handle.append(rows)
-    chunks = np.concatenate([chunks, rows])
     assert handle.root() == bulk.merkleize_chunk_array(chunks)
 
 

@@ -137,8 +137,8 @@ def serving_mesh():
 def test_sharded_forest_matches_single(serving_mesh):
     """The incremental forest under the ServingMesh: per-shard subtree
     levels sharded over "v", replicated cap tree, and every root — build,
-    scattered update, append-grow crossing both the padded power of two
-    AND a shard boundary — bit-identical to the single-device tree, at
+    scattered update, a list that grows inside the capacity across both a
+    power of two AND a shard boundary — bit-identical to the single-device tree, at
     the same O(dirty·log V) pair-lane bound."""
     import jax.numpy as jnp
     from consensus_specs_tpu.utils.ssz.incremental import (
@@ -183,16 +183,28 @@ def test_sharded_forest_matches_single(serving_mesh):
         for level, was in zip(shard.levels, placed):
             assert level.sharding.is_equivalent_to(was, 2)
 
-    # append-grow: 100 -> 140 crosses the 128 pow2 (and, at 8 devices,
-    # the per-shard row boundary); the new capacity 256 rounds to a mesh
-    # multiple by construction
-    rows2 = rng.integers(0, 2 ** 32, (40, 8), dtype=np.uint32)
-    single.append(rows2.copy())
-    shard.append(rows2)
+    # a list that grows inside the capacity: 100 -> 140 crosses the 128
+    # pow2 (and, at 8 devices, a per-shard row boundary) inside trees laid
+    # out with 256 rows of room; the bucket program takes the new leaves
+    # with the new logical length, no level changes shape or placement
+    room = np.zeros((256, 8), np.uint32)
+    room[:V] = np.asarray(single.levels[0])[:V]
+    single = IncrementalMerkleTree(room.copy(), logical_n=V)
+    shard = ShardedIncrementalMerkleTree(jnp.asarray(room), mesh, logical_n=V)
     assert shard.root() == single.root()
+    placed = [level.sharding for level in shard.levels]
+    bucket = bucket_indices(np.arange(100, 140))
+    rows2 = rng.integers(0, 2 ** 32, (len(bucket), 8), dtype=np.uint32)
+    rows2[40:] = rows2[39]
+    single.update_bucket(bucket, rows2.copy(), logical_n=140)
+    shard.update_bucket(bucket, rows2, logical_n=140)
+    room[100:140] = rows2[:40]
+    assert shard.root() == single.root() \
+        == IncrementalMerkleTree(room[:140].copy()).root()
     assert shard.n == single.n == 140
     assert shard.levels[0].shape == (256, 8)
-    assert shard.levels[0].sharding.is_equivalent_to(mesh.shard_v, 2)
+    for level, was in zip(shard.levels, placed):
+        assert level.sharding.is_equivalent_to(was, 2)
     assert shard.builds == single.builds == 1   # never a full rebuild
 
 

@@ -100,13 +100,18 @@ def test_resident_serves_a_registry_mutating_block(spec):
     assert serialize(ref, spec.BeaconState) == serialize(res, spec.BeaconState)
 
 
-def test_fallback_is_incremental_and_grows_forest(spec):
+def test_a_slashing_and_a_deposit_are_served_and_a_full_core_grows(spec):
     """A registry-mutating block must NOT throw the registry-scale trees
     away: a slashing is served on the same incremental forests (one bucket
-    of path lanes), and a deposit block, which falls back, append-grows them
-    across the padded power-of-two boundary — roots bit-equal to the
-    object model throughout."""
+    of path lanes). A deposit is served too, with no fallback; on a core
+    that was given no capacity its new validator passes the registry's
+    rows of storage, and the core re-lays itself out at the next power of
+    two, once (V is one here: both trees a level deeper) — roots bit-equal
+    to the object model throughout."""
+    from consensus_specs_tpu import telemetry
     from consensus_specs_tpu.utils.merkle import tree_depth
+    fallbacks = telemetry.counter("resident.block.fallbacks", always=True)
+    grown = telemetry.counter("resident.registry.capacity_grown", always=True)
 
     state = factories.seed_genesis_state(spec, 4 * spec.SLOTS_PER_EPOCH)
     factories.advance_slots(spec, state, 2)
@@ -135,7 +140,8 @@ def test_fallback_is_incremental_and_grows_forest(spec):
         assert f_reg.last_pairs_per_level == [32] * f_reg.depth
         assert hash_tree_root(ref) == core._state_root(res)
 
-        # -- deposit: grows V -> V+1 across the padded power of two ----------
+        # -- deposit: grows V -> V+1 past the storage, a power of two ---------
+        before = fallbacks.value, grown.value
         with core.suspended():
             # stage the deposit BEFORE building the block: it plants eth1
             # data into the state, and empty_block seals the parent header
@@ -150,10 +156,14 @@ def test_fallback_is_incremental_and_grows_forest(spec):
             spec.process_slots(ref, block.slot)
             spec.process_block(ref, block)
         core.state_transition(res, block)
-        assert core._reg_forest is f_reg and f_reg.n == V + 1
-        assert f_reg.depth == tree_depth(V + 1) > tree_depth(V)
-        assert len(core._pk_np) == V + 1         # identity columns grew too
+        assert (fallbacks.value, grown.value) == (before[0], before[1] + 1)
         assert hash_tree_root(ref) == core._state_root(res)
+        assert core._v == V + 1 and core._capacity == 2 * V
+        assert core._reg_forest is not f_reg and core._reg_forest.n == V + 1
+        assert core._reg_forest.depth == tree_depth(V + 1) > tree_depth(V)
+        # the storage grew everywhere, inert beyond the new row
+        assert len(core._pk_np) == int(core.cols.balance.shape[0]) == 2 * V
+        assert not core._pk_np[V + 1:].any()
     finally:
         core.exit()
     assert serialize(ref, spec.BeaconState) == serialize(res, spec.BeaconState)
@@ -224,23 +234,29 @@ def test_overrides_delegate_for_foreign_state(spec):
         core.exit()
 
 
-def test_light_core_refuses_a_deposit_before_any_write_and_serves_an_exit(spec):
+def test_light_core_serves_a_deposit_and_an_exit(spec):
     """A checkpoint-resumed (light) core takes blocks whose operations are
-    attestations, exits and slashings (tests/test_resident_blocks.py,
-    tests/test_resident_operations.py); one that carries a deposit needs
-    the object registry the light entry deliberately never built, and must
-    fail loudly BEFORE process_slots mutates state, naming the cut. An
-    exit is served: at genesis the spec's own check rejects it
-    (PERSISTENT_COMMITTEE_PERIOD), after process_slots has run."""
+    anything but transfers (tests/test_resident_blocks.py,
+    tests/test_resident_operations.py, tests/test_resident_deposits.py). A
+    deposit is served on the columns, into the room the entry was given:
+    the state it leaves is the object model's. An exit is served: at
+    genesis the spec's own check rejects it (PERSISTENT_COMMITTEE_PERIOD),
+    after process_slots has run."""
     state = factories.seed_genesis_state(spec, 2 * spec.SLOTS_PER_EPOCH)
+    V = len(state.validator_registry)
+    deposit = factories.stage_deposit(spec, state, V, spec.MAX_EFFECTIVE_BALANCE)
     data = serialize(state, spec.BeaconState)
-    core = ResidentCore.from_checkpoint(spec, data)
+    core = ResidentCore.from_checkpoint(spec, data, capacity=V + 8)
     try:
-        block = spec.BeaconBlock(slot=int(state.slot) + 1)
-        block.body.deposits.append(spec.Deposit())
-        with pytest.raises(NotImplementedError, match="registry_operations"):
-            core.state_transition(core.state, block)
-        assert core.checkpoint_bytes() == data      # nothing mutated
+        with core.suspended():
+            block = factories.empty_block_next(spec, state)
+            block.body.deposits.append(deposit)
+            spec.state_transition(state, block)
+        core.state_transition(core.state, block)
+        assert core._v == V + 1 and core._capacity == V + 8
+        assert core.checkpoint_bytes() == serialize(state, spec.BeaconState)
+        assert core._state_root(core.state) == hash_tree_root(state)
+        data = core.checkpoint_bytes()
         with core.suspended():
             block = factories.empty_block_next(spec, state)
         block.body.voluntary_exits.append(spec.VoluntaryExit())
@@ -324,13 +340,13 @@ def test_resident_sharded_serving_loop(spec, serving_mesh):
     assert serialize(ref, spec.BeaconState) == serialize(res, spec.BeaconState)
 
 
-def test_resident_sharded_fallback_and_deposit_growth(spec, serving_mesh):
+def test_resident_sharded_slashing_and_deposit_growth(spec, serving_mesh):
     """Under sharding, a slashing is served where the columns lie (same
     forests, the dirty rows and paths alone, every column and level on the
-    placement it had), a deposit block re-enters INCREMENTALLY and
-    append-grows the padded columns and forests across a shard boundary
-    (V 32 -> 33: columns 32 -> 40 rows, forest capacity 32 -> 64), all
-    bit-equal to the object model."""
+    placement it had), and a deposit is served too: on a core with no room
+    it re-lays the padded columns and the forests out at the next power of
+    two (V 32 -> 33: columns and forest capacity 32 -> 64 rows, sharded as
+    they were), all bit-equal to the object model."""
     from consensus_specs_tpu.utils.merkle import tree_depth
 
     mesh = serving_mesh
@@ -375,15 +391,17 @@ def test_resident_sharded_fallback_and_deposit_growth(spec, serving_mesh):
             spec.process_slots(ref, block.slot)
             spec.process_block(ref, block)
         core.state_transition(res, block)
-        assert core._v == V + 1
-        # columns padded to the next mesh multiple with inert rows
-        assert int(core.cols.balance.shape[0]) == mesh.pad_rows(V + 1)
-        assert core.cols.balance.sharding.is_equivalent_to(mesh.shard_v, 1)
-        assert core._reg_forest is f_reg and f_reg.n == V + 1
-        assert f_reg.depth == tree_depth(V + 1) > tree_depth(V)
-        assert f_reg.builds == 1                  # grew, did not rebuild
-        assert len(core._pk_np) == V + 1
+        assert core._v == V + 1 and core._capacity == 2 * V
         assert hash_tree_root(ref) == core._state_root(res)
+        # columns at the new capacity (a mesh multiple) with inert rows
+        assert int(core.cols.balance.shape[0]) == mesh.pad_rows(2 * V)
+        assert core.cols.balance.sharding.is_equivalent_to(mesh.shard_v, 1)
+        assert core.pk_dev.sharding.is_equivalent_to(mesh.shard_v, 2)
+        assert core._reg_forest is not f_reg and core._reg_forest.n == V + 1
+        assert core._reg_forest.depth == tree_depth(V + 1) > tree_depth(V)
+        assert core._reg_forest.levels[0].sharding.is_equivalent_to(
+            mesh.shard_v, 2)
+        assert len(core._pk_np) == 2 * V
 
         # -- and the next epoch boundary still runs sharded ------------------
         target = spec.get_epoch_start_slot(spec.get_current_epoch(ref) + 1)

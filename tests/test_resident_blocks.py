@@ -221,22 +221,41 @@ def _advanced(spec, state):
 
 # -- what the light core refuses, and what it serves ------------------------------
 
-@pytest.mark.parametrize("name,operation", [
-    ("deposits", "Deposit"), ("transfers", "Transfer")])
-def test_a_block_with_a_deposit_or_a_transfer_is_refused_before_anything_is_written(
-        minimal, name, operation):
+def test_a_block_with_a_transfer_is_refused_before_anything_is_written(minimal):
     spec, _, data = minimal
     core = ResidentCore.from_checkpoint(spec, data, mesh=None)
     try:
         block = spec.BeaconBlock(slot=int(core.state.slot) + 3)
-        getattr(block.body, name).append(getattr(spec, operation)())
+        block.body.transfers.append(spec.Transfer())
         with pytest.raises(NotImplementedError, match="registry_operations") as exc:
             core.state_transition(core.state, block)
-        assert name in str(exc.value)
+        assert "transfers" in str(exc.value)
         assert core.checkpoint_bytes() == data
         with pytest.raises(NotImplementedError, match="registry_operations"):
             core.process_block(core.state, block)
         assert core.checkpoint_bytes() == data
+    finally:
+        core._uninstall()
+
+
+def test_a_block_with_a_deposit_is_served_by_the_light_core(minimal):
+    """What the light core refused by name until deposits were served: the
+    block is taken on the columns and leaves the object model's state."""
+    spec, state, _ = minimal
+    ref = deepcopy(state)
+    V = len(ref.validator_registry)
+    deposit = factories.stage_deposit(spec, ref, V, spec.MAX_EFFECTIVE_BALANCE)
+    core = ResidentCore.from_checkpoint(
+        spec, serialize(ref, spec.BeaconState), mesh=None, capacity=2 * V)
+    try:
+        block = factories.empty_block_next(spec, ref)
+        block.body.deposits.append(deposit)
+        with core.suspended():
+            spec.state_transition(ref, block)
+        core.state_transition(core.state, block)
+        assert core._v == V + 1
+        assert core.checkpoint_bytes() == serialize(ref, spec.BeaconState)
+        assert core._state_root(core.state) == hash_tree_root(ref)
     finally:
         core._uninstall()
 
@@ -433,7 +452,8 @@ def test_eighths_partition_the_committee(size, parts):
 # -- spans and the fallback counter -----------------------------------------------
 
 # a block that dirties nothing has no registry_write / forests.update child
-BLOCK_PARTS = ("header", "randao", "eth1", "slashings", "attestations", "exits")
+BLOCK_PARTS = ("header", "randao", "eth1", "slashings", "attestations",
+               "deposits", "exits")
 
 
 def test_a_block_leaves_one_span_tree_and_counts_no_fallback(minimal):
@@ -513,18 +533,20 @@ def test_a_blocks_container_roots_go_through_the_plans_and_are_hashed_every_bloc
         telemetry.set_enabled(None)
 
 
-def test_a_slashing_on_an_object_entered_core_is_served_and_a_deposit_falls_back(
+def test_a_slashing_and_a_deposit_on_an_object_entered_core_are_served(
         minimal):
     """One path for an operation's write: a proposer slashing takes the
     served path on an object-entered core too (no fallback counted, the
-    block's span tree gains the registry write and the forests' update);
-    a deposit still leaves for the object model and is counted."""
+    block's span tree gains the registry write and the forests' update),
+    and so does a deposit: no block of the preset leaves for the object
+    model any more."""
     spec, state, _ = minimal
     telemetry.set_enabled(True)
     telemetry.reset()
     fallbacks = telemetry.counter("resident.block.fallbacks", always=True)
     ref = deepcopy(state)
-    core = ResidentCore(spec, state, mesh=None)
+    core = ResidentCore(spec, state, mesh=None,
+                        capacity=len(state.validator_registry) + 4)
     try:
         before = fallbacks.value
         with core.suspended():
@@ -544,8 +566,10 @@ def test_a_slashing_on_an_object_entered_core_is_served_and_a_deposit_falls_back
         notes = {c["name"]: c["args"] for c in children if c["args"]}
         assert notes["resident.block.slashings"] == {"slashed": 1}
         assert notes["resident.block.exits"] == {"exits": 0}
+        assert notes["resident.block.deposits"] == {
+            "new_validators": 0, "top_ups": 0, "proof_pairs_hashed": 0}
         # the slashed validator and the proposer it pays
-        assert notes["resident.registry_write"] == {"rows": 2}
+        assert notes["resident.registry_write"] == {"rows": 2, "appended_rows": 0}
         assert notes["resident.forests.update"]["registry_leaves"] == 1
         assert 1 <= notes["resident.forests.update"]["balance_chunks"] <= 2
         with core.suspended():
@@ -556,8 +580,15 @@ def test_a_slashing_on_an_object_entered_core_is_served_and_a_deposit_falls_back
             block.body.deposits.append(deposit)
             spec.state_transition(ref, block)
         core.state_transition(state, block)
-        assert fallbacks.value == before + 1
+        assert fallbacks.value == before
         assert hash_tree_root(ref) == core._state_root(state)
+        notes = {r["name"]: r["args"] for r in telemetry.ring()}
+        assert notes["resident.block.deposits"] == {
+            "new_validators": 1, "top_ups": 0,
+            "proof_pairs_hashed": int(spec.DEPOSIT_CONTRACT_TREE_DEPTH)}
+        assert notes["resident.registry_write"] == {"rows": 1, "appended_rows": 1}
+        assert notes["resident.forests.update"]["appended_leaves"] == 1
     finally:
-        core.exit()
+        assert serialize(core.exit(), spec.BeaconState) \
+            == serialize(ref, spec.BeaconState)
         telemetry.set_enabled(None)

@@ -228,15 +228,20 @@ def test_a_rejected_block_leaves_columns_mirrors_queue_and_forests_untouched(
     assert _same(was, _served_state(core))
 
 
-def test_a_deposit_is_still_refused_by_name_before_anything_is_written(served):
+def test_a_deposit_the_chain_does_not_owe_is_rejected_by_the_spec(served):
+    """What was refused by name until deposits were served: the block now
+    reaches the spec's own check (the chain of the mature seed owes no
+    deposit, so a block that carries one is invalid), which rejects it
+    with everything as it stood."""
     spec, core, generator = served
     was = _served_state(core)
     checkpoint = core.checkpoint_bytes()
     block = generator.block(core.state)
     block.body.deposits.append(spec.Deposit())
-    with pytest.raises(NotImplementedError, match="registry_operations") as exc:
+    kept = spoiled_operations.keep(spec, core.state)
+    with pytest.raises(AssertionError):
         core.process_block(core.state, block)
-    assert "deposits" in str(exc.value)
+    spoiled_operations.put_back(core.state, *kept)
     assert core.checkpoint_bytes() == checkpoint and _same(was, _served_state(core))
 
 
