@@ -6,6 +6,12 @@ per-type max counts.
 """
 from __future__ import annotations
 
+import numpy as np
+
+from ... import telemetry
+from ...utils.ssz import bulk
+from ...utils.ssz.root_plan import plan_for
+
 
 def process_block(spec, state, block) -> None:
     spec.process_block_header(state, block)
@@ -58,8 +64,10 @@ def process_eth1_data(spec, state, body) -> None:
 
 # The six operation lists of a block's body in the spec's fixed order: the
 # body's list -> (the preset's per-block maximum, the per-operation handler).
-# The attestation list is processed as a family (process_attestations_batched
-# collapses its signature checks into one device pipeline).
+# The attestation list is processed as a family (process_attestations_batched:
+# one pass over the block's attestations, and with BLS on their signature
+# checks as one device pipeline); `process_attestation` is the spec's handler
+# for one, and the family's loop.
 OPERATIONS = {
     "proposer_slashings": ("MAX_PROPOSER_SLASHINGS", "process_proposer_slashing"),
     "attester_slashings": ("MAX_ATTESTER_SLASHINGS", "process_attester_slashing"),
@@ -79,18 +87,18 @@ def check_operations(spec, state, body) -> None:
     assert len(body.transfers) == len(set(body.transfers))
 
 
-def process_operation_list(spec, state, body, name: str) -> None:
+def process_operation_list(spec, state, body, name: str):
     """One of the body's six lists (`OPERATIONS`), whole: its per-block
-    maximum, then every operation through its handler."""
+    maximum, then every operation through its handler; the attestations as
+    a family, whose account of what it did is handed back."""
     max_name, handler = OPERATIONS[name]
     operations = getattr(body, name)
     assert len(operations) <= getattr(spec, max_name)
     if name == "attestations":
-        process_attestations_batched(spec, state, operations)
-    else:
-        handle = getattr(spec, handler)
-        for operation in operations:
-            handle(state, operation)
+        return process_attestations_batched(spec, state, operations)
+    handle = getattr(spec, handler)
+    for operation in operations:
+        handle(state, operation)
 
 
 def process_extra_operations(spec, state, body) -> None:
@@ -114,25 +122,161 @@ _batching_enabled = True
 
 
 def set_attestation_batching(enabled: bool) -> None:
-    """Test hook: force the sequential per-attestation verify path."""
+    """Test hook: force the sequential per-attestation loop."""
     global _batching_enabled
     _batching_enabled = enabled
 
 
-def process_attestations_batched(spec, state, attestations) -> None:
-    """The block's attestation family with signature checks collapsed into
-    ONE grouped device pipeline (BASELINE config 3; 0_beacon-chain.md
-    :1625-1645, :1692-1727).
+# Families that ran the per-attestation loop (`process_attestation` an
+# attestation) and not the one pass: a family with a failing check, which
+# the loop then rejects at the spec's place; the test hook above; BLS on a
+# backend without a batch verify. Counted where the choice is made, before
+# the loop can raise, whichever state the block meets: a deployment's blocks
+# meet a resident core's, whose `resident.block.attestations` span notes what
+# its own family added here as `sequential`.
+SEQUENTIAL_FAMILIES = telemetry.counter(
+    "resident.block.attestations.sequential", always=True)
 
-    Each process_attestation runs all its host-side checks and state writes
-    in reference order, but validate_indexed_attestation defers its pairing
-    check into a sink (helpers.py); the collected block is then verified by
-    the backend's verify_indexed_batch — batched G1 aggregation, G2
-    decompression, hash_to_G2, and one grouped pairing program. A failed
-    verdict raises the same AssertionError the sequential path raises (the
-    reference discards half-mutated state on failure either way,
-    :1204-1219). Backends without batch support (the bignum oracle) and
-    crypto-off runs take the unchanged sequential path."""
+
+class _Committee:
+    """What a block's attestations of one (target epoch, shard) share: the
+    slot and the committee they name, the FFG triple and the crosslink
+    lineage the state demands of them, the state's list they join, and
+    their places in the block. Each check that reads nothing else of an
+    attestation is made here, once."""
+    __slots__ = ("slot", "members", "ffg", "parent", "end_epoch",
+                 "parent_root", "pending", "rows")
+
+    def __init__(self, spec, state, data):
+        epoch, shard = data.target_epoch, data.crosslink.shard
+        current_epoch = spec.get_current_epoch(state)
+        assert epoch in (spec.get_previous_epoch(state), current_epoch)
+        self.slot = spec.get_attestation_data_slot(state, data)
+        assert self.slot + spec.MIN_ATTESTATION_INCLUSION_DELAY <= state.slot \
+            <= self.slot + spec.SLOTS_PER_EPOCH
+        self.members = spec.get_crosslink_committee_array(state, epoch, shard)
+        if epoch == current_epoch:
+            self.ffg = (state.current_justified_epoch, state.current_justified_root, epoch)
+            self.parent = state.current_crosslinks[shard]
+            self.pending = state.current_epoch_attestations
+        else:
+            self.ffg = (state.previous_justified_epoch, state.previous_justified_root, epoch)
+            self.parent = state.previous_crosslinks[shard]
+            self.pending = state.previous_epoch_attestations
+        self.end_epoch = min(epoch, self.parent.end_epoch + spec.MAX_EPOCHS_PER_CROSSLINK)
+        self.parent_root = None     # hashed with the block's other parents
+        self.rows = []
+
+
+def _checked_family(spec, state, attestations):
+    """Every check `process_attestation` makes, of every attestation of a
+    block, with nothing written. What the attestations of one committee
+    share is resolved and checked once a committee (`_Committee`: inside
+    the family the only state writes are PendingAttestation appends, so
+    eight aggregates of one committee meet one state, as `_proposer_memo`
+    has it); the parent crosslinks are rooted from the state as it stands,
+    one batch of the Crosslink root plan, nothing kept past the call; the
+    bitfields are checked as one array a committee size. The members of a
+    committee are distinct and `convert_to_indexed` sorts the attesting
+    ones, with no custody bit beside them, so the indexed attestation's
+    sortedness and disjointness hold by construction and none is built.
+
+    Returns the number of committees and, in list order, each
+    attestation's committee and (with `bls_active`) its sorted attesting
+    indices. A failing check raises AssertionError or
+    IndexError; WHICH attestation fails first, and with what, is the
+    loop's to say (process_attestations_batched)."""
+    committees, of = {}, []
+    for row, attestation in enumerate(attestations):
+        data = attestation.data
+        key = (data.target_epoch, data.crosslink.shard)
+        committee = committees.get(key)
+        if committee is None:
+            committee = committees[key] = _Committee(spec, state, data)
+        committee.rows.append(row)
+        of.append(committee)
+    if committees:
+        roots = bulk.plan_roots(plan_for(spec.Crosslink),
+                                [c.parent for c in committees.values()])
+        for i, committee in enumerate(committees.values()):
+            committee.parent_root = roots[32 * i:32 * i + 32]
+
+    # once an attestation, scalars only
+    for attestation, committee in zip(attestations, of):
+        data = attestation.data
+        link = data.crosslink
+        assert committee.ffg == (data.source_epoch, data.source_root, data.target_epoch)
+        assert link.start_epoch == committee.parent.end_epoch
+        assert link.end_epoch == committee.end_epoch
+        assert link.parent_root == committee.parent_root
+        assert link.data_root == spec.ZERO_HASH  # [to be removed in phase 1]
+
+    # the bitfields, one array a committee size
+    registry_size = len(spec.registry_view(state))
+    indices = [None] * len(of)
+    by_size = {}
+    for committee in committees.values():
+        by_size.setdefault(len(committee.members), []).append(committee)
+    for size, same in by_size.items():
+        rows = [row for committee in same for row in committee.rows]
+        n_bytes = (size + 7) // 8
+        no_custody_bit = bytes(n_bytes)
+        fields = []
+        for row in rows:
+            # verify_bitfield's length for both; of the custody bits none
+            # may be set [phase 0], padding or not
+            assert attestations[row].custody_bitfield == no_custody_bit
+            fields.append(attestations[row].aggregation_bitfield)
+            assert len(fields[-1]) == n_bytes
+        bits = np.unpackbits(
+            np.frombuffer(b"".join(fields), np.uint8).reshape(len(rows), n_bytes),
+            axis=1, bitorder="little").view(bool)
+        assert not bits[:, size:].any()
+        bits = bits[:, :size]
+        counts = bits.sum(axis=1)
+        assert counts.max() <= spec.MAX_INDICES_PER_ATTESTATION
+        # an attestation's bits are its committee's members, and each names
+        # a validator (validate_indexed_attestation's IndexError)
+        members = np.stack([committee.members for committee in same])
+        of_row = np.repeat(np.arange(len(same)), [len(c.rows) for c in same])
+        at_row, at_bit = np.nonzero(bits)
+        attesting = members[of_row[at_row], at_bit]
+        if attesting.size and int(attesting.max()) >= registry_size:
+            raise IndexError(f"validator index {int(attesting.max())} outside "
+                             f"a registry of {registry_size}")
+        if spec.bls.bls_active:
+            for row, part in zip(rows, np.split(attesting, np.cumsum(counts)[:-1])):
+                indices[row] = np.sort(part)
+    return len(committees), of, indices
+
+
+def process_attestations_batched(spec, state, attestations) -> dict:
+    """The block's attestation family, all or nothing: ONE pass over the
+    family (`_checked_family`) makes every check of the spec's
+    `process_attestation` (0_beacon-chain.md:1692-1727) of every
+    attestation, once a committee what the attestations of a committee
+    share, and only when all have passed are the PendingAttestations built
+    and appended, in list order. A family with a failing check has written
+    nothing by then and is run again by the loop, `process_attestation` an
+    attestation, so that the first failing attestation raises what the
+    spec raises, at the spec's place, over the state half written as the
+    spec leaves it. Which of the two runs is read off the block, not off a
+    switch; `set_attestation_batching(False)` forces the loop for tests.
+
+    With BLS on, the signature checks collapse into ONE grouped device
+    pipeline (BASELINE config 3; :1625-1645): the pass, or in the loop
+    validate_indexed_attestation, puts each attestation's check into a
+    sink (helpers.attestation_signature_check), and the collected block
+    is then verified by the backend's verify_indexed_batch: batched G1
+    aggregation, G2 decompression, hash_to_G2, and one grouped pairing
+    program. A failed verdict raises the same AssertionError the inline
+    verify raises (the reference discards half-mutated state on failure
+    either way, :1204-1219). A backend without batch support (the bignum
+    oracle) verifies inline, an attestation at a time, which is the loop.
+
+    Returns what it did: `committees`, the committee resolutions made (one
+    a distinct (target epoch, shard) in the pass, one an attestation in
+    the loop), and `sequential`, 1 when the loop ran."""
     batch = (getattr(spec.bls.get_backend(), "verify_indexed_batch", None)
              if spec.bls.bls_active and _batching_enabled else None)
     # streaming firehose (ISSUE 15): when a StreamingVerifier is
@@ -143,31 +287,56 @@ def process_attestations_batched(spec, state, attestations) -> None:
     # (tests/test_streaming.py), so failure semantics are unchanged.
     streaming = (getattr(spec, "_streaming_verifier", None)
                  if batch is not None else None)
-    # Within this loop the only state mutations are PendingAttestation
+    # Within the family the only state mutations are PendingAttestation
     # appends, so the slot's proposer index is invariant: pin it for the
-    # scope (each process_attestation consults it; up to 128 rejection-
-    # sampling recomputations collapse to one)
+    # scope (each process_attestation of the loop consults it; up to 128
+    # rejection-sampling recomputations collapse to one)
     if len(attestations) > 1:
         state._proposer_memo = (
             (int(state.slot), len(spec.registry_view(state))),
             spec.get_beacon_proposer_index(state))
+    outer = spec._att_verify_sink
+    # where the signature checks go: a sink already installed, else this
+    # family's own when the backend verifies a batch, else nowhere (BLS
+    # off, or each verified inline)
+    sink = outer if outer is not None else [] if batch is not None else None
     try:
-        if batch is None or spec._att_verify_sink is not None:
-            for attestation in attestations:
-                spec.process_attestation(state, attestation)
-            return
-        sink = []
-        spec._att_verify_sink = sink
-        try:
-            for attestation in attestations:
-                spec.process_attestation(state, attestation)
-        finally:
-            spec._att_verify_sink = None
-        if sink:
+        family = None
+        if _batching_enabled and not (spec.bls.bls_active and sink is None):
+            try:
+                family = _checked_family(spec, state, attestations)
+            except (AssertionError, IndexError):
+                pass
+        if family is None:
+            SEQUENTIAL_FAMILIES.inc()
+            spec._att_verify_sink = sink
+            try:
+                for attestation in attestations:
+                    spec.process_attestation(state, attestation)
+            finally:
+                spec._att_verify_sink = outer
+            work = {"committees": len(attestations), "sequential": 1}
+        else:
+            committees, of, indices = family
+            proposer_index = spec.get_beacon_proposer_index(state)
+            for attestation, committee, attesting in zip(attestations, of, indices):
+                committee.pending.append(spec.PendingAttestation(
+                    data=attestation.data,
+                    aggregation_bitfield=attestation.aggregation_bitfield,
+                    inclusion_delay=state.slot - committee.slot,
+                    proposer_index=proposer_index,
+                ))
+                if attesting is not None:
+                    sink.append(spec.attestation_signature_check(
+                        state, attestation.data, attesting, attesting[:0],
+                        attestation.signature))
+            work = {"committees": committees, "sequential": 0}
+        if sink and outer is None:
             if streaming is not None:
                 assert all(streaming.verdicts_for(sink))
             else:
                 assert all(batch(sink))
+        return work
     finally:
         if len(attestations) > 1:
             state._proposer_memo = None

@@ -602,26 +602,39 @@ def validate_indexed_attestation(spec, state, indexed_attestation) -> None:
         # nothing reads the pubkey sets or the message hashes: every
         # verify answers True unread (crypto/bls.py)
         return
-    pubkey_sets = [registry.pubkeys(bit_0_indices.tolist()), registry.pubkeys(bit_1_indices.tolist())]
-    message_hashes = [
-        spec.hash_tree_root(spec.AttestationDataAndCustodyBit(data=indexed_attestation.data, custody_bit=False)),
-        spec.hash_tree_root(spec.AttestationDataAndCustodyBit(data=indexed_attestation.data, custody_bit=True)),
-    ]
-    domain = spec.get_domain(state, spec.DOMAIN_ATTESTATION, indexed_attestation.data.target_epoch)
+    check = spec.attestation_signature_check(
+        state, indexed_attestation.data, bit_0_indices, bit_1_indices,
+        indexed_attestation.signature)
     sink = spec._att_verify_sink
     if sink is not None:
         # Deferred: process_operations collects the whole block's checks
         # into one grouped device pipeline (block.py) — the verdict is
         # asserted there, with identical failure semantics.
-        sink.append((pubkey_sets, message_hashes,
-                     bytes(indexed_attestation.signature), domain))
+        sink.append(check)
         return
+    pubkey_sets, message_hashes, _, domain = check
     assert spec.bls.bls_verify_multiple(
         pubkeys=[spec.bls.bls_aggregate_pubkeys(s) for s in pubkey_sets],
         message_hashes=message_hashes,
         signature=indexed_attestation.signature,
         domain=domain,
     )
+
+
+def attestation_signature_check(spec, state, data, bit_0_indices: np.ndarray,
+                                bit_1_indices: np.ndarray, signature) -> tuple:
+    """What the verify of an indexed attestation reads, as the deferred
+    sink holds it (block.process_attestations_batched): the pubkey sets
+    of the two custody bits, their two message hashes, the signature and
+    the domain."""
+    registry = spec.registry_view(state)
+    pubkey_sets = [registry.pubkeys(bit_0_indices.tolist()), registry.pubkeys(bit_1_indices.tolist())]
+    message_hashes = [
+        spec.hash_tree_root(spec.AttestationDataAndCustodyBit(data=data, custody_bit=False)),
+        spec.hash_tree_root(spec.AttestationDataAndCustodyBit(data=data, custody_bit=True)),
+    ]
+    domain = spec.get_domain(state, spec.DOMAIN_ATTESTATION, data.target_epoch)
+    return pubkey_sets, message_hashes, bytes(signature), domain
 
 
 def is_slashable_attestation_data(spec, data_1, data_2) -> bool:

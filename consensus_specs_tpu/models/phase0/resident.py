@@ -106,6 +106,7 @@ from ...utils.ssz.incremental import (IncrementalMerkleTree,
                                       ShardedIncrementalMerkleTree,
                                       bucket_indices)
 from ...utils.ssz.typing import Vector
+from . import block as block_mod
 from . import helpers as helpers_mod
 from .epoch_soa import (REPLICATED_INPUT_FIELDS, EpochConfig,
                         ValidatorColumns, build_epoch_context,
@@ -147,6 +148,10 @@ _SLOT_ROOT_NOTES = {"pairs_hashed": bulk.HOST_PAIRS_HASHED,
 
 # Blocks that left the served path for the object model (_fallback_block).
 _BLOCK_FALLBACKS = telemetry.counter("resident.block.fallbacks", always=True)
+# Attestation families that left the one pass for the per-attestation loop
+# (`resident.block.attestations.sequential`, always on): the family counts
+# them where it chooses (block.process_attestations_batched).
+_FAMILY_LOOPS = block_mod.SEQUENTIAL_FAMILIES
 # The lists of a block's body that the resident state does not serve: a
 # transfer is cut from the preset (MAX_TRANSFERS 0). A block that carries
 # one takes the object model on an object-entered core; a
@@ -1378,11 +1383,18 @@ class ResidentCore:
                     sp_part.note(slashed=sum(
                         field == "slashed" for field, _, _ in writes.undo))
                 with telemetry.span("resident.block.attestations") as sp_part:
-                    # one parent crosslink's root an attestation
+                    # one parent crosslink's root a committee of the block;
+                    # `sequential` 1 for a family the loop took, noted
+                    # whether or not the loop then raised
                     elements = bulk.PLAN_ELEMENTS.value
-                    spec.process_operation_list(state, body, "attestations")
-                    sp_part.note(
-                        plan_elements=bulk.PLAN_ELEMENTS.value - elements)
+                    loops = _FAMILY_LOOPS.value
+                    try:
+                        family = spec.process_operation_list(state, body, "attestations")
+                        sp_part.note(committees=family["committees"])
+                    finally:
+                        sp_part.note(
+                            plan_elements=bulk.PLAN_ELEMENTS.value - elements,
+                            sequential=_FAMILY_LOOPS.value - loops)
                 with telemetry.span("resident.block.deposits") as sp_part:
                     # each proves its branch against the state's deposit
                     # root: DEPOSIT_CONTRACT_TREE_DEPTH pairs by hashlib

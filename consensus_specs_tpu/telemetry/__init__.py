@@ -43,8 +43,11 @@ ends in `resident.stage.distill.place`, and `.upload`),
 forests the refresh dispatched first); `resident.block` (a root of
 its own, `req` = the block's slot: the slot's root span has closed when
 `process_slots` returned) over `.header .randao .eth1 .slashings
-.attestations .deposits .exits` (`.deposits` notes `new_validators`,
-`top_ups` and `proof_pairs_hashed`) and, for a block that wrote the
+.attestations .deposits .exits` (`.attestations` notes `plan_elements`,
+`committees` and `sequential`: the parent crosslinks rooted, the
+committees resolved, and 1 for a family the per-attestation loop took;
+`.deposits` notes `new_validators`, `top_ups` and `proof_pairs_hashed`)
+and, for a block that wrote the
 registry, `resident.registry_write` (notes `rows`, `appended_rows`) and
 `resident.forests.update` (notes `registry_leaves`, `appended_leaves`,
 `balance_chunks`, `pair_lanes`); `resident.registry.pubkey_index` (the one
@@ -62,6 +65,8 @@ permutation cache: shuffles really run), `firehose.*` (queue depth / batch occup
 deadline misses — always-on: /healthz reads them), `watchdog.*`
 (retrace/re-layout events), `resident.block.fallbacks` (blocks that left
 the served path for the object model; always-on),
+`resident.block.attestations.sequential` (attestation families that left
+the one pass for the per-attestation loop; always-on),
 `resident.registry.capacity_grown` (re-layouts of a resident core at a
 larger registry capacity; always-on), `jax.backend_compiles` (global compile
 listener).

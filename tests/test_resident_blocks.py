@@ -23,10 +23,12 @@ REPO = Path(__file__).resolve().parents[1]
 if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
 
+from benchmark import spoiled_blocks  # noqa: E402
 from benchmark.block_generator import BlockGenerator, eighths  # noqa: E402
 from consensus_specs_tpu import telemetry  # noqa: E402
 from consensus_specs_tpu.crypto import bls  # noqa: E402
 from consensus_specs_tpu.models import phase0  # noqa: E402
+from consensus_specs_tpu.models.phase0 import block as block_mod  # noqa: E402
 from consensus_specs_tpu.models.phase0 import helpers  # noqa: E402
 from consensus_specs_tpu.models.phase0.resident import ResidentCore  # noqa: E402
 from consensus_specs_tpu.testing import factories  # noqa: E402
@@ -219,6 +221,43 @@ def _advanced(spec, state):
     return fresh
 
 
+@pytest.mark.parametrize("position", ["first", "middle", "last"])
+@pytest.mark.parametrize("spoil", spoiled_blocks.SPOILS, ids=lambda f: f.__name__)
+def test_a_spoiled_family_falls_to_the_loop_and_leaves_the_core_as_it_was(
+        odd_committees, spoil, position):
+    """The sync cell's four spoils, wherever in the family the spoiled
+    attestation stands: the pass writes nothing, the loop raises what the
+    object model raises where it raises it, the always-on counter counts
+    the family, no block leaves the served path, the registry's columns,
+    mirrors and forests stand as they stood, and the slot's sound block is
+    then taken by the pass."""
+    spec, state, data = odd_committees
+    loops = telemetry.counter("resident.block.attestations.sequential", always=True)
+    fallbacks = telemetry.counter("resident.block.fallbacks", always=True)
+    core = ResidentCore.from_checkpoint(spec, data, mesh=None)
+    res = core.state
+    try:
+        core.process_slots(res, int(res.slot) + 1)
+        generator = BlockGenerator(spec, SEED, 8)
+        n = len(generator.block(res).body.attestations)
+        block = spoil(spec, generator, res,
+                      {"first": 0, "middle": n // 2, "last": n - 1}[position])
+        with core.suspended():
+            want = _where_it_raises(
+                lambda: spec.process_block(_advanced(spec, state), block))
+        assert want is not None, "the object model takes the spoiled block"
+        was, kept = _served_state(core), spoiled_blocks.keep(spec, res)
+        before = loops.value, fallbacks.value
+        assert _where_it_raises(lambda: core.process_block(res, block)) == want
+        assert (loops.value, fallbacks.value) == (before[0] + 1, before[1])
+        assert _same_served_state(was, _served_state(core))
+        spoiled_blocks.put_back(res, *kept)
+        core.process_block(res, generator.block(res))
+        assert loops.value == before[0] + 1
+    finally:
+        core._uninstall()
+
+
 # -- what the light core refuses, and what it serves ------------------------------
 
 def test_a_block_with_a_transfer_is_refused_before_anything_is_written(minimal):
@@ -353,9 +392,12 @@ def test_a_resident_cores_view_answers_from_its_columns(minimal, light):
     assert spec._registry_views == {}
 
 
-def test_the_proposer_memo_keys_on_the_views_length(minimal):
+@pytest.mark.parametrize("family", ["loop", "pass"])
+def test_the_proposer_memo_keys_on_the_views_length(minimal, family):
     """On a light core `len(state.validator_registry)` is 0; the memo's key
-    takes V from the view, and is dropped when the family returns."""
+    takes V from the view, and is dropped when the family returns. The
+    loop reads it once an attestation; the pass asks for the proposer once
+    and calls no `process_attestation` at all."""
     spec, _, data = minimal
     core = ResidentCore.from_checkpoint(spec, data, mesh=None)
     res = core.state
@@ -369,12 +411,17 @@ def test_the_proposer_memo_keys_on_the_views_length(minimal):
             seen.append(state._proposer_memo)
             return real(state, attestation)
         spec.process_attestation = watching
+        block_mod.set_attestation_batching(family == "pass")
         try:
             core.process_block(res, block)
         finally:
+            block_mod.set_attestation_batching(True)
             spec.process_attestation = real
         proposer = spec.get_beacon_proposer_index(res)
-        assert seen and all(m == ((int(res.slot), 64), proposer) for m in seen)
+        assert len(seen) == (len(block.body.attestations) if family == "loop" else 0)
+        assert all(m == ((int(res.slot), 64), proposer) for m in seen)
+        assert [int(p.proposer_index) for p in res.current_epoch_attestations[
+            -len(block.body.attestations):]] == [proposer] * len(block.body.attestations)
         assert res._proposer_memo is None
     finally:
         core._uninstall()
@@ -488,7 +535,7 @@ def test_a_block_leaves_one_span_tree_and_counts_no_fallback(minimal):
 def test_a_blocks_container_roots_go_through_the_plans_and_are_hashed_every_block(
         odd_committees):
     """The header step roots the body with its attestations through their
-    plan, the attestation step one parent crosslink an attestation: both
+    plan, the attestation step one parent crosslink a committee: both
     noted on their spans, hashed anew for each block, the root written
     into the header the oracle's; and the check they feed still refuses."""
     spec, state, data = odd_committees
@@ -520,7 +567,13 @@ def test_a_blocks_container_roots_go_through_the_plans_and_are_hashed_every_bloc
             # block before
             assert header["plan_elements"] == n + 1
             assert header["pairs_hashed"] >= 19 * n - 1
-            assert family == {"plan_elements": n}
+            # the family as one pass: a parent crosslink's root a committee
+            # of the block, not one an attestation
+            distinct = len({(a.data.target_epoch, a.data.crosslink.shard)
+                            for a in block.body.attestations})
+            assert 1 <= distinct < n
+            assert family == {"plan_elements": distinct, "committees": distinct,
+                              "sequential": 0}
         assert headers[0]["pairs_hashed"] == headers[1]["pairs_hashed"]
         # a third block whose first attestation names another parent root
         core.process_slots(res, int(res.slot) + 1)
@@ -528,6 +581,11 @@ def test_a_blocks_container_roots_go_through_the_plans_and_are_hashed_every_bloc
         _wrong_crosslink_parent_root(spec, res, spoiled)
         assert _where_it_raises(lambda: core.process_block(res, spoiled))[:2] \
             == ("AssertionError", "process_attestation")
+        # the loop took that family, and its span says so though it raised:
+        # the pass's one parent and the loop's first before the check failed
+        assert [r["args"] for r in telemetry.ring()
+                if r["name"] == "resident.block.attestations"][-1] \
+            == {"plan_elements": 2, "sequential": 1}
     finally:
         core._uninstall()
         telemetry.set_enabled(None)
