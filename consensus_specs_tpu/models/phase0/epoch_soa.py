@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import itertools
 import operator
-import threading
 from functools import partial
 from typing import NamedTuple
 
@@ -711,36 +710,49 @@ class EpochContext(NamedTuple):
     prev_parts: list         # [len(prev_atts)] np.ndarray participant indices
     curr_parts: list
     cl_roots: dict           # content tuple -> hash_tree_root(Crosslink)
+    eff_shuffled: dict       # epoch -> [A] int64 effective_balance[lay.shuffled]
+    winner_groups: dict      # epoch -> _WinnerGroups
+
+
+def _crosslink_key(c) -> tuple:
+    """A Crosslink's content: what keys its root and its candidate group."""
+    return (int(c.shard), int(c.start_epoch), int(c.end_epoch),
+            bytes(c.parent_root), bytes(c.data_root))
 
 
 def _crosslink_root(spec, ctx: "EpochContext", c) -> bytes:
     """hash_tree_root(Crosslink) through a content-keyed cache.
 
-    _crosslink_winners runs three times per transition (two epochs in
-    process_crosslinks + the deltas pass re-selecting against the updated
-    records, mirroring process_epoch's ordering :1251-1262) and most
-    candidates repeat — without the cache these tiny-container merkleizations
-    are >half of the 1M-validator distill wall-clock. build_epoch_context
-    pre-fills the cache in one vectorized batch (_prefill_crosslink_roots);
-    this per-record path is the fallback for records created mid-pass."""
-    key = (int(c.shard), int(c.start_epoch), int(c.end_epoch),
-           bytes(c.parent_root), bytes(c.data_root))
+    build_epoch_context pre-fills the cache in one vectorized batch
+    (_prefill_crosslink_roots: without it these tiny-container
+    merkleizations were >half of the 1M-validator distill wall-clock);
+    this per-record path is the fallback for a record the batch did not
+    hold."""
+    key = _crosslink_key(c)
     r = ctx.cl_roots.get(key)
     if r is None:
         r = ctx.cl_roots[key] = spec.hash_tree_root(c)
     return r
 
 
-def _prefill_crosslink_roots(spec, ctx: "EpochContext", state) -> None:
-    """Batch every Crosslink merkleization the winner-selection passes will
+def _prefill_crosslink_roots(spec, ctx: "EpochContext", state) -> tuple:
+    """Batch every Crosslink merkleization the winner selection will
     query — the state's records + each attestation's candidate + the
     default — into ONE [N, 8, 32] subtree_roots_batch call instead of ~2k
     recursive per-container hash_tree_root walks (those were ~1.2 s of the
     1M-validator distill). Chunk layout per container Merkleization rules
     (simple-serialize.md:134-145): 5 field leaves (three uint64, two
-    Bytes32) padded to the next power of two."""
+    Bytes32) padded to the next power of two.
+
+    Returns (contents, prev_ids, curr_ids): the distinct contents the walk
+    met, in the order it met them, and for every attestation of
+    `ctx.prev_atts` and of `ctx.curr_atts` the place of its crosslink's
+    content among them — what the candidate groups are keyed by, so that
+    no selection builds an attestation's key again."""
     from ...utils.ssz import bulk
-    keys = {}
+    ids = {}
+    walked = []
+    # (the key inline: a call an attestation is a tenth of this walk)
     for c in itertools.chain(
             state.current_crosslinks,
             (a.data.crosslink for a in ctx.prev_atts),
@@ -748,22 +760,28 @@ def _prefill_crosslink_roots(spec, ctx: "EpochContext", state) -> None:
             (spec.Crosslink(),)):
         key = (int(c.shard), int(c.start_epoch), int(c.end_epoch),
                bytes(c.parent_root), bytes(c.data_root))
-        if key not in keys and key not in ctx.cl_roots:
-            keys[key] = None
-    if not keys:
-        return
-    ks = list(keys)
-    n = len(ks)
-    leaves = np.zeros((n, 8, 32), dtype=np.uint8)
-    u64s = np.array([(k[0], k[1], k[2]) for k in ks], dtype="<u8")
-    leaves[:, 0:3, :8] = u64s.view(np.uint8).reshape(n, 3, 8)
-    leaves[:, 3, :] = np.frombuffer(b"".join(k[3] for k in ks),
-                                    np.uint8).reshape(n, 32)
-    leaves[:, 4, :] = np.frombuffer(b"".join(k[4] for k in ks),
-                                    np.uint8).reshape(n, 32)
-    roots = bulk.subtree_roots_batch(leaves)
-    for i, k in enumerate(ks):
-        ctx.cl_roots[k] = roots[i].tobytes()
+        i = ids.get(key)
+        if i is None:
+            i = ids[key] = len(ids)
+        walked.append(i)
+    n_records = len(state.current_crosslinks)
+    prev_ids, curr_ids = np.split(
+        np.array(walked[n_records:-1], dtype=np.int64), [len(ctx.prev_atts)])
+    contents = list(ids)
+    ks = [k for k in contents if k not in ctx.cl_roots]
+    if ks:
+        n = len(ks)
+        leaves = np.zeros((n, 8, 32), dtype=np.uint8)
+        u64s = np.array([(k[0], k[1], k[2]) for k in ks], dtype="<u8")
+        leaves[:, 0:3, :8] = u64s.view(np.uint8).reshape(n, 3, 8)
+        leaves[:, 3, :] = np.frombuffer(b"".join(k[3] for k in ks),
+                                        np.uint8).reshape(n, 32)
+        leaves[:, 4, :] = np.frombuffer(b"".join(k[4] for k in ks),
+                                        np.uint8).reshape(n, 32)
+        roots = bulk.subtree_roots_batch(leaves)
+        for i, k in enumerate(ks):
+            ctx.cl_roots[k] = roots[i].tobytes()
+    return contents, prev_ids, curr_ids
 
 
 def _committee_count_for_active(spec, active_count: int) -> int:
@@ -809,17 +827,30 @@ def _epoch_layout(spec, state, np_cols: dict, epoch: int) -> _Layout:
                    start_shard=_start_shard_np(spec, state, np_cols, epoch))
 
 
-def _decode_participants(spec, layouts: dict, atts) -> list:
+class _Bitfields(NamedTuple):
+    """One attestation list's aggregation bitfields, unpacked once:
+    attestation j's bits are allbits[starts[j]:starts[j] + sizes[j]], over
+    the positions lo[j]... of its target epoch's layout."""
+    shards: np.ndarray       # [n] int64
+    epochs: np.ndarray       # [n] int64 target epoch
+    allbits: np.ndarray      # [sum(8 * len(bitfield))] bool
+    starts: np.ndarray       # [n + 1] int64
+    sizes: np.ndarray        # [n] int64 committee sizes
+    lo: np.ndarray           # [n] int64 the committee's first position
+
+
+def _decode_participants(spec, layouts: dict, atts) -> tuple:
     """Per attestation: participant validator indices
     (get_attesting_indices :905-917; order is irrelevant downstream, so the
-    reference's sorted() is dropped).
+    reference's sorted() is dropped), and the unpacked bitfields they were
+    read from (None for an empty list).
 
     Batched: every aggregation bitfield decodes through ONE concatenated
     unpackbits and the committee bounds resolve as one vectorized pass per
     epoch — at a full mainnet epoch (~2k attestations) the per-attestation
     loop below does only the two ragged ops (slice + boolean gather)."""
     if not atts:
-        return []
+        return [], None
     n = len(atts)
     shards = np.fromiter((int(a.data.crosslink.shard) for a in atts),
                          np.int64, n)
@@ -849,14 +880,16 @@ def _decode_participants(spec, layouts: dict, atts) -> list:
         lay = layouts[int(epochs[j])]
         bits = allbits[starts[j]:starts[j] + sizes[j]]
         parts.append(lay.shuffled[lo[j]:hi[j]][bits])
-    return parts
+    return parts, _Bitfields(shards=shards, epochs=epochs, allbits=allbits,
+                             starts=starts, sizes=sizes, lo=lo)
 
 
 def build_epoch_context(spec, state, np_cols: dict = None) -> EpochContext:
-    """The epoch's layouts, decoded participants and crosslink roots, under
-    "distill.context" with a span a part (".layouts", which notes the
-    permutations the shuffle really computed inside it, ".participants",
-    ".crosslink_roots")."""
+    """The epoch's layouts, decoded participants, crosslink roots and
+    candidate crosslink groups, under "distill.context" with a span a part
+    (".layouts", which notes the permutations the shuffle really computed
+    inside it, ".participants", ".crosslink_roots", ".winner_groups", which
+    notes the groups it formed and the committees with more than one)."""
     with telemetry.span("distill.context"):
         np_cols = np_cols if np_cols is not None else columns_np_from_state(state)
         current_epoch = spec.get_current_epoch(state)
@@ -874,8 +907,8 @@ def build_epoch_context(spec, state, np_cols: dict = None) -> EpochContext:
             # the counter is the process's: one host thread is assumed
             sp_lay.note(shuffles=PERMUTATIONS_COMPUTED.value - computed0)
         with telemetry.span("distill.participants"):
-            prev_parts = _decode_participants(spec, layouts, prev_atts)
-            curr_parts = _decode_participants(spec, layouts, curr_atts)
+            prev_parts, prev_bits = _decode_participants(spec, layouts, prev_atts)
+            curr_parts, curr_bits = _decode_participants(spec, layouts, curr_atts)
         ctx = EpochContext(
             # column length, not len(validator_registry): identical for object
             # states, and checkpoint-resumed resident states keep the registry
@@ -883,10 +916,23 @@ def build_epoch_context(spec, state, np_cols: dict = None) -> EpochContext:
             n=len(np_cols["slashed"]), np_cols=np_cols, layouts=layouts,
             prev_atts=prev_atts, curr_atts=curr_atts,
             prev_parts=prev_parts, curr_parts=curr_parts,
-            cl_roots={},
+            cl_roots={}, eff_shuffled={}, winner_groups={},
         )
         with telemetry.span("distill.crosslink_roots"):
-            _prefill_crosslink_roots(spec, ctx, state)
+            contents, prev_ids, curr_ids = _prefill_crosslink_roots(
+                spec, ctx, state)
+        with telemetry.span("distill.winner_groups") as sp_groups:
+            # (in the genesis epoch the two epochs, and the two lists, are one)
+            for epoch in dict.fromkeys((previous_epoch, current_epoch)):
+                ctx.winner_groups[epoch] = _winner_groups(
+                    spec, ctx, epoch, contents,
+                    *((curr_atts, curr_parts, curr_ids, curr_bits)
+                      if epoch == current_epoch else
+                      (prev_atts, prev_parts, prev_ids, prev_bits)))
+            formed = ctx.winner_groups.values()
+            sp_groups.note(
+                groups=sum(g.formed for g in formed),
+                multi_group_committees=sum(len(g.more) for g in formed))
     return ctx
 
 
@@ -925,90 +971,220 @@ def _attestation_data_slot(spec, lay: _Layout, data) -> int:
             + off // (lay.count // spec.SLOTS_PER_EPOCH))
 
 
-def _crosslink_winners(spec, state, ctx: EpochContext, epoch: int):
-    """Per committee offset of `epoch`: (winning_crosslink,
-    unslashed_attesting_indices, attesting_balance) — the vectorized
-    get_winning_crosslink_and_attesting_indices (:1308-1322), evaluated
-    against the CURRENT state.current_crosslinks (callers control ordering
-    vs record mutation, exactly like the reference's sequential loops)."""
-    with telemetry.span("distill.winners"):
-        current_epoch = spec.get_current_epoch(state)
-        atts = ctx.curr_atts if epoch == current_epoch else ctx.prev_atts
-        parts = ctx.curr_parts if epoch == current_epoch else ctx.prev_parts
+def _eff_shuffled(ctx: EpochContext, lay: _Layout) -> np.ndarray:
+    """[A] effective balances along the layout, gathered once a layout
+    and kept on the context: the committees' sums and the candidate
+    groups' sums are segment sums of it."""
+    eff = ctx.eff_shuffled.get(lay.epoch)
+    if eff is None:
+        # (uint64 Gwei, far below 2**63: read in place as the sums' type;
+        # `clip`: with `raise` numpy gathers into a temporary of its own,
+        # and a layout's rows are this registry's by construction)
+        eff = ctx.eff_shuffled[lay.epoch] = np.take(
+            ctx.np_cols["effective_balance"].view(np.int64), lay.shuffled,
+            mode="clip")
+    return eff
+
+
+def _segment_sums(values: np.ndarray, bounds: np.ndarray) -> np.ndarray:
+    """[count] int64 sums of values[bounds[k]:bounds[k + 1]], exact; an
+    empty segment sums to 0 (`reduceat` alone gives it the element it
+    starts at, and has nothing to start at in an empty array)."""
+    if len(values) == 0:
+        return np.zeros(len(bounds) - 1, np.int64)
+    sums = np.add.reduceat(values, bounds[:-1], dtype=np.int64)
+    sums[bounds[1:] == bounds[:-1]] = 0
+    return sums
+
+
+class _Group(NamedTuple):
+    """One candidate crosslink of one shard with its attestations' facts:
+    what get_winning_crosslink_and_attesting_indices (:1308-1322) computes
+    a candidate, none of which depends on state.current_crosslinks."""
+    crosslink: object        # the first attestation's record
+    root: bytes
+    parent_root: bytes
+    data_root: bytes
+    balance: int             # get_attesting_balance: max(sum, 1)
+    # unslashed attesting indices; None: the committee's positions set in
+    # `_WinnerGroups.attested` (see `_group_indices`)
+    indices: np.ndarray
+
+
+class _WinnerGroups(NamedTuple):
+    """One epoch's candidate groups, by committee offset, in the order the
+    shard's attestations first name them."""
+    first: list              # [count] _Group or None
+    more: dict               # off -> [_Group, ...]: a shard's further candidates
+    attested: np.ndarray     # [A] bool over lay.shuffled: unslashed attesters
+    #                          of every first group in array form
+
+    @property
+    def formed(self) -> int:
+        return (sum(g is not None for g in self.first)
+                + sum(map(len, self.more.values())))
+
+
+# Participant unions (with their balance sums) really computed: one a
+# candidate group, all inside "distill.winner_groups"; a selection pass
+# notes the delta over itself, which is 0.
+UNIONS_COMPUTED = telemetry.counter("distill.winner_unions_computed")
+
+
+def _winner_groups(spec, ctx: EpochContext, epoch: int, contents: list,
+                   atts, parts, ids: np.ndarray,
+                   bits: _Bitfields) -> _WinnerGroups:
+    """The candidate groups of every shard of `epoch`: the attestations of
+    one list grouped by their crosslink's content (`ids` into `contents`),
+    each group's unslashed attesters and attesting balance. A committee's
+    first group is taken in position space: its members' bitfields are
+    masks over the committee's slice of the layout, so the union is an OR
+    into one [A] mask, and the balances of all of them one masked segment
+    sum. A shard's further groups (and a group holding an attestation of
+    another target epoch, whose bits lie over another layout) go group by
+    group through the index arrays."""
+    lay = ctx.layouts[epoch]
+    first = [None] * lay.count
+    more: dict = {}
+    attested = np.zeros(len(lay.shuffled), dtype=bool)
+    if not atts:
+        return _WinnerGroups(first, more, attested)
+
+    # groups numbered in the order the list first names them
+    content, first_j, inverse = np.unique(ids, return_index=True,
+                                          return_inverse=True)
+    by_first = np.argsort(first_j)
+    n_groups = len(by_first)
+    number = np.empty(n_groups, np.int64)
+    number[by_first] = np.arange(n_groups)
+    gids = number[inverse]
+    content, first_j = content[by_first], first_j[by_first]
+    # a group's members, ascending: by_group[cuts[g]:cuts[g + 1]]
+    by_group = np.argsort(gids, kind="stable")
+    cuts = np.concatenate([[0], np.cumsum(np.bincount(gids))])
+    offs = (bits.shards[first_j] + spec.SHARD_COUNT
+            - lay.start_shard) % spec.SHARD_COUNT
+    # (a shard the epoch has no committee for is never selected for)
+    asked = offs < lay.count
+    first_of = np.full(lay.count, -1, np.int64)
+    backwards = np.flatnonzero(asked)[::-1]
+    first_of[offs[backwards]] = backwards    # the last write is the first group
+    is_first = np.zeros(n_groups, dtype=bool)
+    is_first[first_of[first_of >= 0]] = True
+    in_array_form = is_first.copy()
+    in_array_form[gids[bits.epochs != epoch]] = False
+    # members that lie one after the other in the list are rows of one
+    # block of `allbits` (one committee, so one bitfield length)
+    in_a_row = (by_group[cuts[1:] - 1] - first_j == np.diff(cuts) - 1).tolist()
+
+    allbits = bits.allbits
+    starts, sizes, lo, first_j, members_of = (x.tolist() for x in (
+        bits.starts, bits.sizes, bits.lo, first_j, np.diff(cuts)))
+    for g in np.flatnonzero(in_array_form).tolist():
+        j, members = first_j[g], members_of[g]
+        size = sizes[j]
+        if members == 1:
+            union = allbits[starts[j]:starts[j] + size]
+        elif in_a_row[g]:
+            union = allbits[starts[j]:starts[j + members]].reshape(
+                members, -1).any(axis=0)[:size]
+        else:
+            union = allbits[starts[j]:starts[j] + size].copy()
+            for k in by_group[cuts[g] + 1:cuts[g + 1]].tolist():
+                union |= allbits[starts[k]:starts[k] + size]
+        attested[lo[j]:lo[j] + size] = union
+    if in_array_form.any():
+        slashed = ctx.np_cols["slashed"]
+        if slashed.any():
+            attested &= ~slashed[lay.shuffled]
+        balances = np.maximum(_segment_sums(
+            _eff_shuffled(ctx, lay) * attested, lay.bounds), 1).tolist()
+        UNIONS_COMPUTED.inc(int(np.count_nonzero(in_array_form)))
+
+    content, offs = content.tolist(), offs.tolist()
+    for g in np.flatnonzero(asked).tolist():
+        key, off = contents[content[g]], offs[g]
+        c = atts[first_j[g]].data.crosslink
+        if in_array_form[g]:
+            balance, idx = balances[off], None
+        else:
+            idx = _unslashed_union(ctx, [
+                parts[j] for j in by_group[cuts[g]:cuts[g + 1]].tolist()])
+            balance = _balance_of(ctx, idx)
+            UNIONS_COMPUTED.inc()
+        root = ctx.cl_roots.get(key) or _crosslink_root(spec, ctx, c)
+        group = _Group(c, root, key[3], key[4], balance, idx)
+        if is_first[g]:
+            first[off] = group
+        else:
+            more.setdefault(off, []).append(group)
+    return _WinnerGroups(first, more, attested)
+
+
+def _group_indices(ctx: EpochContext, epoch: int, off: int,
+                   group: _Group) -> np.ndarray:
+    """A selected group's unslashed attesting indices
+    (get_unslashed_attesting_indices :1294-1300, unsorted); `None`, the
+    default crosslink with no attestation of its own, has none."""
+    if group is None:
+        return np.empty(0, dtype=np.int64)
+    if group.indices is not None:
+        return group.indices
+    lay = ctx.layouts[epoch]
+    lo, hi = lay.bounds[off], lay.bounds[off + 1]
+    return lay.shuffled[lo:hi][ctx.winner_groups[epoch].attested[lo:hi]]
+
+
+def _crosslink_winners(spec, state, ctx: EpochContext, epoch: int) -> list:
+    """Per committee offset of `epoch`: (winning_crosslink, its group or
+    None, attesting_balance) — the selection of
+    get_winning_crosslink_and_attesting_indices (:1308-1322) over the
+    context's candidate groups, evaluated against the CURRENT
+    state.current_crosslinks (callers control ordering vs record mutation,
+    exactly like the reference's sequential loops; it runs three times a
+    transition, mirroring process_epoch's ordering :1251-1262, and only the
+    filter and the max can differ between the three). `_group_indices`
+    gives a group's unslashed attesting indices."""
+    with telemetry.span("distill.winners") as sp:
+        unions0 = UNIONS_COMPUTED.value
         lay = ctx.layouts[epoch]
-
-        def htr(c):
-            return _crosslink_root(spec, ctx, c)
-
+        groups = ctx.winner_groups[epoch]
         default_cl = spec.Crosslink()
-        default_root = htr(default_cl)
-
-        by_shard: dict = {}
-        for j, a in enumerate(atts):
-            by_shard.setdefault(int(a.data.crosslink.shard), []).append(j)
-
+        default_root = _crosslink_root(spec, ctx, default_cl)
         out = []
-        for off in range(lay.count):
-            shard = (lay.start_shard + off) % spec.SHARD_COUNT
-            js = by_shard.get(shard, ())
-            current_root = htr(state.current_crosslinks[shard])
-            # Candidate crosslinks grouped by root, first-occurrence order; the
-            # root filter is `current_root in (c.parent_root, hash_tree_root(c))`
-            groups: dict = {}
-            order = []
-            cl_of = {}
-            for j in js:
-                c = atts[j].data.crosslink
-                r = htr(c)
-                if current_root != bytes(c.parent_root) and current_root != r:
-                    continue
-                if r not in groups:
-                    groups[r] = []
-                    order.append(r)
-                    cl_of[r] = c
-                groups[r].append(j)
-            if not order:
-                # max(..., default=Crosslink()): the default still collects
-                # attestations whose crosslink equals it (:1318-1321)
-                win_js = [j for j in js if htr(atts[j].data.crosslink) == default_root]
-                win_idx = _unslashed_union(ctx, [parts[j] for j in win_js])
-                out.append((default_cl, win_idx, _balance_of(ctx, win_idx)))
+        for off, group in enumerate(groups.first):
+            if group is None:
+                out.append((default_cl, None, 1))
                 continue
+            shard = (lay.start_shard + off) % spec.SHARD_COUNT
+            current_root = _crosslink_root(spec, ctx,
+                                           state.current_crosslinks[shard])
+            candidates = (group, *groups.more.get(off, ()))
+            # the root filter is `current_root in (c.parent_root,
+            # hash_tree_root(c))`; strict >: the first maximum wins, like max()
             best = None
-            for r in order:
-                idx = _unslashed_union(ctx, [parts[j] for j in groups[r]])
-                key = (_balance_of(ctx, idx), bytes(cl_of[r].data_root))
-                if best is None or key > best[0]:  # strict: first max wins, like max()
-                    best = (key, cl_of[r], idx)
-            out.append((best[1], best[2], best[0][0]))
+            for g in candidates:
+                if current_root != g.parent_root and current_root != g.root:
+                    continue
+                if best is None or ((g.balance, g.data_root)
+                                    > (best.balance, best.data_root)):
+                    best = g
+            if best is not None:
+                out.append((best.crosslink, best, best.balance))
+                continue
+            # max(..., default=Crosslink()): the default still collects
+            # attestations whose crosslink equals it (:1318-1321)
+            own = next((g for g in candidates if g.root == default_root), None)
+            out.append((default_cl, own, 1 if own is None else own.balance))
+        sp.note(unions_computed=UNIONS_COMPUTED.value - unions0)
         return out
 
 
-_cumsum_scratch = threading.local()
-
-
 def _committee_balances(ctx: EpochContext, lay: _Layout) -> np.ndarray:
-    """[count] committee effective-balance sums via one cumsum (>=1 each).
-
-    The gather and the cumsum run in ONE int64 buffer that the thread
-    keeps: four fresh V-row temporaries a call (gather, cast, cumsum,
-    concatenate; three calls a boundary) were given back to the system
-    and faulted in again or not as the heap happened to lie, 5 ms a call
-    or 12.5 at 1M, which made `epoch_boundary_s` read in steps."""
+    """[count] committee effective-balance sums (>=1 each): segment sums
+    of the layout's gathered balances."""
     with telemetry.span("distill.committee_balances"):
-        # (uint64 Gwei, far below 2**63: read in place as the sums' type)
-        eff = ctx.np_cols["effective_balance"].view(np.int64)
-        n = len(lay.shuffled)
-        cs = getattr(_cumsum_scratch, "buf", None)
-        if cs is None or len(cs) <= n:
-            cs = _cumsum_scratch.buf = np.empty(n + 1, np.int64)
-        cs = cs[:n + 1]
-        cs[0] = 0
-        # (`clip`: with `raise` numpy gathers into a temporary of its own;
-        # a layout's rows are this registry's by construction)
-        np.take(eff, lay.shuffled, out=cs[1:], mode="clip")
-        np.cumsum(cs[1:], out=cs[1:])
-        return np.maximum(cs[lay.bounds[1:]] - cs[lay.bounds[:-1]],
+        return np.maximum(_segment_sums(_eff_shuffled(ctx, lay), lay.bounds),
                           1).astype(np.uint64)
 
 
@@ -1027,11 +1203,11 @@ def process_crosslinks_vectorized(spec, state, ctx: EpochContext) -> None:
         for epoch in (spec.get_previous_epoch(state),
                       spec.get_current_epoch(state)):
             lay = ctx.layouts[epoch]
-            comm_bal = _committee_balances(ctx, lay)
+            comm_bal = _committee_balances(ctx, lay).tolist()
             winners = _crosslink_winners(spec, state, ctx, epoch)
             for off, (winner, _, att_bal) in enumerate(winners):
                 shard = (lay.start_shard + off) % spec.SHARD_COUNT
-                if 3 * att_bal >= 2 * int(comm_bal[off]):
+                if 3 * att_bal >= 2 * comm_bal[off]:
                     state.current_crosslinks[shard] = winner
 
 
@@ -1093,16 +1269,26 @@ def build_epoch_inputs_np(spec, state,
         shards = ((prev_lay.start_shard + np.arange(prev_lay.count))
                   % spec.SHARD_COUNT).astype(np.int32)
         v_shard[prev_lay.shuffled] = np.repeat(shards, np.diff(prev_lay.bounds))
-        in_winning = np.zeros(n, dtype=bool)
         shard_att_balance = np.ones(spec.SHARD_COUNT, dtype=np.uint64)
         shard_comm_balance = np.ones(spec.SHARD_COUNT, dtype=np.uint64)
-        comm_bal = _committee_balances(ctx, prev_lay)
+        shard_comm_balance[shards] = _committee_balances(ctx, prev_lay)
         winners = _crosslink_winners(spec, state, ctx, previous_epoch)
-        for off, (_, win_idx, att_bal) in enumerate(winners):
-            shard = int(shards[off])
-            in_winning[win_idx] = True
-            shard_att_balance[shard] = att_bal
-            shard_comm_balance[shard] = comm_bal[off]
+        shard_att_balance[shards] = np.array(
+            [att_bal for _, _, att_bal in winners], dtype=np.uint64)
+        # the winners' attesters: the array-form groups' positions, one
+        # scatter through the layout, then the few kept as index arrays
+        in_array_form = np.fromiter(
+            (g is not None and g.indices is None for _, g, _ in winners),
+            bool, prev_lay.count)
+        attested = ctx.winner_groups[previous_epoch].attested
+        if not in_array_form.all():
+            attested = attested & np.repeat(in_array_form,
+                                            np.diff(prev_lay.bounds))
+        in_winning = np.zeros(n, dtype=bool)
+        in_winning[prev_lay.shuffled] = attested
+        for _, g, _ in winners:
+            if g is not None and g.indices is not None:
+                in_winning[g.indices] = True
 
         # every value att_proposer holds is some attestation's proposer_index
         proposer_table, proposer_rows = proposer_table_np(

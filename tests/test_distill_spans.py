@@ -3,11 +3,12 @@
 `process_epoch_soa`'s `epoch.distill`).
 
 The builders of `epoch_soa` open their own spans (`distill.context` over
-`.layouts`, `.participants`, `.crosslink_roots`; `distill.crosslinks`;
+`.layouts`, `.participants`, `.crosslink_roots`, `.winner_groups`;
+`distill.crosslinks`;
 `distill.inputs` over `.flags`, `.inclusion`; `distill.winners` and
 `distill.committee_balances` once a pass), the core opens
 `resident.stage.distill.place`, and `resident.stage.distill` notes
-`pending_rows` and `crosslink_roots_hashed_singly`: fifteen records a
+`pending_rows` and `crosslink_roots_hashed_singly`: sixteen records a
 boundary, none in a slot or a block, none at all with telemetry off.
 """
 import sys
@@ -36,7 +37,7 @@ SEED = 2**31 + 38
 DISTILL = "resident.stage.distill"
 PLACE = "resident.stage.distill.place"
 CONTEXT_PARTS = ["distill.layouts", "distill.participants",
-                 "distill.crosslink_roots"]
+                 "distill.crosslink_roots", "distill.winner_groups"]
 # what a boundary closes that it did not close before, with the count of each
 NEW_RECORDS = {
     "distill.context": 1, **dict.fromkeys(CONTEXT_PARTS, 1),
@@ -93,7 +94,7 @@ def _sync_core(spec):
 
 def test_distill_is_cut_into_its_parts_in_order(spec, spans):
     """`resident.stage.distill` has four children in order and
-    `distill.context` three; every part lies inside its parent by `ts` and
+    `distill.context` four; every part lies inside its parent by `ts` and
     `dur`, siblings do not overlap and sum to no more than the parent; the
     winner and committee-balance passes close three times under the parents
     the table names; every record carries the boundary slot's `req`."""
@@ -129,12 +130,13 @@ def test_distill_is_cut_into_its_parts_in_order(spec, spans):
                        for a, b in zip(parts, parts[1:]))
 
 
-def test_a_boundary_writes_fifteen_records_more_and_a_slot_and_a_block_none(
+def test_a_boundary_writes_sixteen_records_more_and_a_slot_and_a_block_none(
         spec, spans):
     """The whole count: a non-boundary slot's tree is 7 records and a
     block's 8 (7 before the deposit list got a span of its own); the boundary
     slot's was 15 and is 30,
-    and 31 since the refresh dispatches its forests in a span of its own."""
+    31 since the refresh dispatches its forests in a span of its own, and 32
+    since the candidate crosslink groups are formed in one."""
     spe = int(spec.SLOTS_PER_EPOCH)
     core = _sync_core(spec)
     generator = BlockGenerator(spec, SEED, aggregates=8)
@@ -153,8 +155,8 @@ def test_a_boundary_writes_fifteen_records_more_and_a_slot_and_a_block_none(
     # a resumed core's first slot root builds the forests under its own span
     assert sizes["resident.slot"] == {7, 8}
     assert sizes["resident.block"] == {8}
-    assert sizes["resident.boundary_slot"] == {31}
-    assert sum(NEW_RECORDS.values()) == 15
+    assert sizes["resident.boundary_slot"] == {32}
+    assert sum(NEW_RECORDS.values()) == 16
     for root in roots:
         new = Counter(r["name"] for r in tree(root)
                       if r["name"] in NEW_RECORDS)
@@ -243,8 +245,9 @@ def test_a_crosslink_the_prefill_was_not_given_is_counted(
     default = (0, 0, 0, bytes(32), bytes(32))
 
     def prefill_without_the_default(spec, ctx, state):
-        real(spec, ctx, state)
+        att_keys = real(spec, ctx, state)
         del ctx.cl_roots[default]
+        return att_keys
     monkeypatch.setattr(epoch_soa, "_prefill_crosslink_roots",
                         prefill_without_the_default)
     core, state = _replay_core(spec)

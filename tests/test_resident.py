@@ -527,7 +527,8 @@ def test_one_epoch_leaves_one_span_tree_a_slot(spec, spans):
         "distill.context", "distill.crosslinks", "distill.inputs",
         "resident.stage.distill.place"]
     assert [k["name"] for k in _children(records, parts[0])] == [
-        "distill.layouts", "distill.participants", "distill.crosslink_roots"]
+        "distill.layouts", "distill.participants", "distill.crosslink_roots",
+        "distill.winner_groups"]
     for parent in (distill, parts[0]):
         kids = _children(records, parent)
         assert sum(k["dur"] for k in kids) <= parent["dur"]

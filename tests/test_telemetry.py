@@ -251,7 +251,8 @@ def test_profiler_session_holds_the_resident_spans(spec, tmp_path):
     parts = [e.name for e in reduce.annotations(planes, prefix="distill.")]
     assert sorted(parts) == sorted([
         "distill.context", "distill.layouts", "distill.participants",
-        "distill.crosslink_roots", "distill.crosslinks", "distill.inputs",
+        "distill.crosslink_roots", "distill.winner_groups",
+        "distill.crosslinks", "distill.inputs",
         "distill.inputs.flags", "distill.inputs.inclusion"]
         + ["distill.winners", "distill.committee_balances"] * 3)
     assert sum(e.name == "resident.slot_root" for e in events) == spe
